@@ -24,7 +24,11 @@ def main(argv):
     import tensor2robot_tpu.config.defaults  # registers the surface
 
     from tensor2robot_tpu import config as cfg
+    from tensor2robot_tpu.utils.compile_cache import enable_compile_cache
 
+    # Before the first compile: a cold process re-running the same step
+    # reads it back instead of recompiling (utils/compile_cache.py).
+    enable_compile_cache()
     cfg.parse_config_files_and_bindings(FLAGS.gin_configs, FLAGS.gin_bindings)
     train_eval_model = cfg.get_configurable("train_eval_model")
     train_eval_model()

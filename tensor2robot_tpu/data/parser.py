@@ -114,6 +114,13 @@ def _load_jpeg_native_locked(ctypes, os, subprocess):
     return _jpeg_lib
 
 
+def native_jpeg_loaded() -> bool:
+    """True when jpeg decode runs through native/jpeg_decode.cc; False
+    means this process decodes with PIL (the build failed or no toolchain
+    exists). Loads the decoder if nothing has yet."""
+    return _load_jpeg_native() is not None
+
+
 def decode_image_into_native(data: bytes, out: np.ndarray) -> bool:
     """Decodes a jpeg directly INTO `out` (uint8, HxWx3, C-contiguous).
 

@@ -106,6 +106,13 @@ def _load_native() -> Optional[ctypes.CDLL]:
     return _lib
 
 
+def native_loaded() -> bool:
+    """True when record framing/CRC runs through the C++ codec; False
+    means this process is on the pure-Python fallback (the build failed
+    or no toolchain exists). Loads the codec if nothing has yet."""
+    return _load_native() is not None
+
+
 # -- pure-python fallback CRC32-C ---------------------------------------------
 
 _CRC_TABLE: Optional[np.ndarray] = None

@@ -6,7 +6,7 @@ will ever dispatch (`warmup_batch_sizes`). What every consumer still
 pays per process is the XLA *compile* of each bucket: replica boots,
 autoscaler scale-ups, and every learner-publish rolling swap re-lower
 the same program for the same shapes on the same hardware. The
-persistent compile cache (serving/compile_cache.py) only amortizes that
+persistent compile cache (utils/compile_cache.py) only amortizes that
 across boots on one host; this module removes it from the consumer
 entirely, the full-AOT thesis of arXiv:1810.09868 applied to serving:
 compile once, at export time, and ship the executables.
@@ -73,6 +73,7 @@ __all__ = [
     "AOTCorrupt",
     "AOTKeyMismatch",
     "aot_relpath",
+    "serving_device",
     "device_topology",
     "digest",
     "artifact_fingerprint",
@@ -113,15 +114,26 @@ def aot_relpath(regime: str, bucket: int) -> str:
     return os.path.join(AOT_DIR, f"exec_{regime}_b{int(bucket)}.bin")
 
 
-def device_topology() -> Dict[str, Any]:
-    """The topology key of THIS process: an executable lowered here runs
-    only on a host presenting the identical triple."""
+def serving_device():
+    """The ONE device an exported program is compiled for and executes
+    on: uncommitted (numpy) request batches land on jax's default
+    device, so that is where every bucket executable lives — on a
+    multi-chip host too."""
     import jax
 
-    devices = jax.devices()
+    return jax.local_devices()[0]
+
+
+def device_topology() -> Dict[str, Any]:
+    """The topology key of THIS process: an executable lowered here runs
+    only on a host presenting the identical triple. Platform and kind
+    are those of `serving_device()`, the device the executable runs on."""
+    import jax
+
+    device = serving_device()
     return {
-        "platform": str(jax.default_backend()),
-        "device_kind": str(devices[0].device_kind),
+        "platform": str(device.platform),
+        "device_kind": str(device.device_kind),
         "device_count": int(jax.device_count()),
     }
 
@@ -259,8 +271,15 @@ def load_executable(
     _check_key(header, expect_fingerprint, expect_topology)
     try:
         serialized, in_tree, out_tree = pickle.loads(payload)
+        # jax 0.9 defaults execution_devices to EVERY device of the
+        # backend; a one-device executable loaded that way expects one
+        # argument shard per device and fails its first call on any
+        # multi-device host.
         compiled = serialize_executable.deserialize_and_load(
-            serialized, in_tree, out_tree
+            serialized,
+            in_tree,
+            out_tree,
+            execution_devices=[serving_device()],
         )
     except AOTError:
         raise
@@ -346,45 +365,17 @@ def build_bucket_executables(
     # deserialize_and_load with "Symbols not found" — even in the
     # process that exported it. A warm cache (any process that compiled
     # this program before, e.g. a bench re-run or a serving replica
-    # that re-exports) would corrupt every bucket. Toggling
-    # jax_enable_compilation_cache alone is NOT enough: jax memoizes
-    # cache engagement at the first compile and folds config state into
-    # the cache KEY, so a flag flip just re-keys the entries — the
-    # first build under the flipped flag WRITES them and every later
-    # build HITS them. Clearing the cache directory + reset_cache()
-    # makes reads and writes both no-op for the build; both are
-    # restored after, and the round-trip check below backstops it all.
-    # The config is process-GLOBAL: an unrelated compile in another
-    # thread during this window skips the persistent cache too (a
-    # performance miss, never a correctness one — no in-tree process
-    # serves and exports concurrently; exporters run between legs /
-    # in the learner, serving compiles in replicas).
-    prev_enabled = bool(jax.config.jax_enable_compilation_cache)
-    prev_dir = jax.config.jax_compilation_cache_dir
+    # that re-exports) would corrupt every bucket; the round-trip check
+    # below backstops the bypass. No in-tree process serves and exports
+    # concurrently: exporters run between legs / in the learner,
+    # serving compiles in replicas.
+    from tensor2robot_tpu.utils.compile_cache import compile_cache_bypass
 
-    def _reset_cache_state():
-        try:
-            from jax._src import compilation_cache as _compilation_cache
-        except ImportError:  # pragma: no cover - future jax relayout
-            return
-        reset = getattr(_compilation_cache, "reset_cache", None)
-        if reset is not None:
-            reset()
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    if prev_dir is not None:
-        jax.config.update("jax_compilation_cache_dir", None)
-    _reset_cache_state()
-    try:
+    with compile_cache_bypass():
         with concurrent.futures.ThreadPoolExecutor(
             max_workers=workers
         ) as pool:
             compiled_buckets = list(pool.map(compile_one, batches))
-    finally:
-        jax.config.update("jax_enable_compilation_cache", prev_enabled)
-        if prev_dir is not None:
-            jax.config.update("jax_compilation_cache_dir", prev_dir)
-        _reset_cache_state()
     # Serialization runs AFTER the pool drains, sequentially: XLA's
     # executable serialization snapshots process-global compiled-symbol
     # state, and serializing while another bucket's compile is in

@@ -156,16 +156,6 @@ _declare(
     choices=("none", "fp16", "int8", "fp8_e4m3", "fp8_e5m2"),
 )
 _declare(
-    "T2R_COMPILE_CACHE_DIR",
-    _STR,
-    None,
-    "JAX persistent compilation cache directory for serving processes "
-    "(serving/compile_cache.py): replica boot and hot-swap prewarm "
-    "compiles are served from disk on the second boot. Unset = no "
-    "persistent cache.",
-    "tensor2robot_tpu/serving/compile_cache.py",
-)
-_declare(
     "T2R_DECODE_CACHE_MB",
     _INT,
     512,

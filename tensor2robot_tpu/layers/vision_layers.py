@@ -159,7 +159,8 @@ class ImagesToFeaturesHighResNet(nn.Module):
         block_outs.append(nn.Conv(32, (1, 1), name="conv2_1x1")(net))
         for i in range(1, self.num_blocks):
             # Non-overlapping pool: backend-dispatched backward
-            # (ops/pooling.py; SelectAndScatter on TPU per DIAG_STEP_r05).
+            # (ops/pooling.py; SelectAndScatter on TPU per the round-5
+            # on-chip A/B, not re-measured).
             net = pooling.max_pool(net, (2, 2), "VALID")
             net = nn.Conv(
                 32,

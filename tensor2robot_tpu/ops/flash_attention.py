@@ -14,7 +14,9 @@ invocation can compute one (q-shard × k-shard) tile of a longer sequence
 
 Dispatch: the Pallas path runs on TPU (or anywhere with interpret=True,
 which tests use); other backends and non-divisible block shapes fall back
-to the einsum reference. Gradients: jax.custom_vjp with a FLASH backward —
+to the einsum reference. A sequence whose whole-K/V blocks cannot fit the
+chip's VMEM is refused with an error naming the shape (`_vmem_kwargs`),
+never quietly rerouted. Gradients: jax.custom_vjp with a FLASH backward —
 two Pallas kernels (dq; dk+dv) recompute attention probabilities tile by
 tile from the forward's saved row statistics L = m + log(l) and
 D = rowsum(dO*O), so the backward is also O(S·D) HBM (the
@@ -63,12 +65,6 @@ def attention_contraction_override(impl):
     finally:
         _CONTRACTION_OVERRIDE.reset(token)
 
-try:  # jax with varying-manual-axes tracking accepts vma annotations
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    _SDS_HAS_VMA = True
-except TypeError:  # older jax: no tracking, the annotation is a no-op
-    _SDS_HAS_VMA = False
-
 # Row statistics (l, m, lse, delta) cross the pallas_call boundary stored
 # with a trailing broadcast dim of _STATS_LANES so their blocks satisfy
 # Mosaic's (8, 128) tile constraint; a [block_q]-shaped block would need a
@@ -80,6 +76,48 @@ except TypeError:  # older jax: no tracking, the annotation is a no-op
 # needs an in-kernel sublane->lane transpose — worth exploring only after
 # this layout is validated on hardware.
 _STATS_LANES = 128
+
+# Every kernel keeps some operands WHOLE in VMEM per grid step: the
+# forward and dq kernels a (1, S_k, D) K and V block, the dk/dv kernel a
+# (1, S_q, ...) Q, dO and two row-stat blocks. Measured on TPU v5 lite
+# (libtpu 0.0.34, 128 MiB of VMEM): under the compiler's default scoped
+# limit the forward stops compiling once K+V pass ~14 MiB (bf16 D=128:
+# S=28672 compiles, S=32768 is RESOURCE_EXHAUSTED in vmem; f32: 12288 vs
+# 16384); with `vmem_limit_bytes` raised, a kernel compiles while the sum
+# of its whole-sequence blocks (lane-padded) stays under the limit minus
+# a few MiB (at a 100 MiB limit: 96 MiB compiles, 128 MiB does not, for
+# forward and backward alike). So small shapes keep the default, larger
+# ones ask for 25/32 of the chip's VMEM, and a shape beyond that is
+# refused HERE, by name — the alternative is an XLA allocation failure
+# deep inside whatever program contains the call. Blocking K/V through
+# the grid instead (no whole-sequence operand) is ROADMAP A4.
+_VMEM_DEFAULT_BUDGET = 12 << 20
+_VMEM_MARGIN = 4 << 20
+
+
+def _vmem_kwargs(kernel: str, whole_blocks, interpret: bool, shapes: str):
+    """pallas_call kwargs sizing `kernel`'s scoped VMEM for its
+    whole-sequence blocks [(rows, cols, dtype), ...]; raises ValueError
+    naming the shapes when they cannot fit the chip."""
+    if interpret:
+        return {}  # the interpreter has no VMEM
+    need = sum(
+        rows * -(-cols // 128) * 128 * jnp.dtype(dtype).itemsize
+        for rows, cols, dtype in whole_blocks
+    )
+    if need <= _VMEM_DEFAULT_BUDGET:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    limit = pltpu.get_tpu_info().vmem_capacity_bytes * 25 // 32
+    if need > limit - _VMEM_MARGIN:
+        raise ValueError(
+            f"{kernel} keeps whole-sequence blocks of {need >> 20} MiB in "
+            f"VMEM for {shapes}, over the {(limit - _VMEM_MARGIN) >> 20} MiB "
+            "this chip can give one kernel; shard the sequence "
+            "(parallel/ring_attention.py) or shorten it"
+        )
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
 # Auto-dispatch crossover shared by every attention entry point
@@ -339,7 +377,7 @@ def flash_attention_tile(
     )
 
     def out_struct(shape):
-        if vma is not None and _SDS_HAS_VMA:
+        if vma is not None:
             return jax.ShapeDtypeStruct(shape, jnp.float32, vma=frozenset(vma))
         return jax.ShapeDtypeStruct(shape, jnp.float32)
 
@@ -369,6 +407,11 @@ def flash_attention_tile(
             pl.BlockSpec((1, bq, _STATS_LANES), lambda b, i: (b, i, 0)),
         ),
         interpret=interpret,
+        **_vmem_kwargs(
+            "flash_attention_tile",
+            [(s_k, dim, k.dtype), (s_k, dim, v.dtype)],
+            interpret, f"k/v {k.shape} {k.dtype}",
+        ),
     )(offsets, fold(q), fold(k), fold(v))
     o = jnp.transpose(o.reshape(batch, heads, s_q, dim), (0, 2, 1, 3))
     l = l[..., 0].reshape(batch, heads, s_q)
@@ -425,6 +468,11 @@ def _flash_attention_fwd_impl(
         ],
         out_specs=pl.BlockSpec((1, block_q, dim), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        **_vmem_kwargs(
+            "flash_attention",
+            [(s_k, dim, k.dtype), (s_k, dim, v.dtype)],
+            interpret, f"k/v {k.shape} {k.dtype}",
+        ),
     )(offsets, qf, kf, vf)
     return jnp.transpose(out.reshape(batch, heads, s_q, dim), (0, 2, 1, 3))
 
@@ -685,7 +733,7 @@ def flash_attention_bwd_tile(
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(bh, x.shape[1], dim)
 
     def out_struct(shape, dtype=jnp.float32):
-        if vma is not None and _SDS_HAS_VMA:
+        if vma is not None:
             return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
         return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -716,6 +764,11 @@ def flash_attention_bwd_tile(
         ],
         out_specs=pl.BlockSpec((1, bq, dim), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        **_vmem_kwargs(
+            "flash_attention_bwd_tile (dq)",
+            [(s_k, dim, k.dtype), (s_k, dim, v.dtype)],
+            interpret, f"k/v {k.shape} {k.dtype}",
+        ),
     )(offsets, qf, kf, vf, dof, lsef, deltaf)
 
     dk, dv = pl.pallas_call(
@@ -742,6 +795,15 @@ def flash_attention_bwd_tile(
             pl.BlockSpec((1, bk, dim), lambda b, j: (b, j, 0)),
         ),
         interpret=interpret,
+        **_vmem_kwargs(
+            "flash_attention_bwd_tile (dk/dv)",
+            [
+                (s_q, dim, q.dtype), (s_q, dim, do.dtype),
+                (s_q, _STATS_LANES, jnp.float32),
+                (s_q, _STATS_LANES, jnp.float32),
+            ],
+            interpret, f"q/do {q.shape} {q.dtype}",
+        ),
     )(offsets, qf, kf, vf, dof, lsef, deltaf)
 
     def unfold(x, s):

@@ -13,14 +13,12 @@ research/qtopt/networks.py:446,460,540):
 
 Which one wins is a HARDWARE question, and the two measurements disagree:
 on CPU the scatter-free VJP removed the top non-gather op of the step
-(round-4 HLO census), but the round-5 on-chip A/B at the stem activation
-size (DIAG_STEP_r05.json, TPU v5e, bs64 236x236x64: scatterfree 55.7 ms
-vs SelectAndScatter 41.7 ms against a shared ~34 ms readback floor, i.e.
-~22 ms vs ~8 ms of compute) shows TPU's native SelectAndScatter pool
-gradient beating the reshape/mask formulation ~3x. `max_pool` therefore
-dispatches on the backend at trace time: native on TPU, scatter-free
-elsewhere; `T2R_POOL_BACKWARD=scatterfree|native` forces either path
-(the bench A/B uses this).
+(round-4 HLO census), but TPU's native SelectAndScatter pool gradient
+beat the reshape/mask formulation ~3x at the stem activation size
+(bs64 236x236x64; round-5 on-chip A/B, not re-measured). `max_pool`
+therefore dispatches on the platform each lowering targets: native on
+TPU, scatter-free elsewhere; `T2R_POOL_BACKWARD=scatterfree|native`
+forces either path (the bench A/B uses this).
 
 The forward stays `lax.reduce_window` (already optimal on TPU); only the
 VJP is replaced via `jax.custom_vjp`.
@@ -62,7 +60,7 @@ def resolve_backward_mode() -> str:
     execution: `max_pool`'s auto mode dispatches via
     `lax.platform_dependent`, so the VJP is selected by each lowering's
     actual platform and an AOT export compiled for a different backend
-    gets THAT backend's path, not this process's (ADVICE round-5). The
+    gets THAT backend's path, not this process's. The
     forced modes bake the named path in at trace time on every platform.
     """
     mode = flags.get_enum("T2R_POOL_BACKWARD")
@@ -102,7 +100,7 @@ def max_pool(
     path everywhere.
     """
     mode = flags.get_enum("T2R_POOL_BACKWARD")
-    if mode == "auto" and hasattr(lax, "platform_dependent"):
+    if mode == "auto":
         return lax.platform_dependent(
             x,
             tpu=lambda x: _native_pool(x, window, padding),

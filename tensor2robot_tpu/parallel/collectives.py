@@ -39,10 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # jax >= 0.7 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 
 from tensor2robot_tpu import flags
 
@@ -72,32 +69,18 @@ __all__ = [
 # collective in train/ + parallel/ routes through ONE importable, greppable,
 # lintable module. They accept pytrees wherever jax.lax does.
 
-# jax renamed shard_map's replication-checking knob check_rep -> check_vma;
-# the registry translates whichever spelling the caller used to whatever
-# the installed jax accepts, so callers never version-guard it themselves.
-_SHARD_MAP_PARAMS = frozenset(
-    __import__("inspect").signature(_shard_map).parameters
-)
+# `shard_map` itself is jax's, re-exported so manual-collective programs
+# import it from the one lintable module.
 
 
-def shard_map(fn, *args, **kwargs):
-    for ours, theirs in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if ours in kwargs and ours not in _SHARD_MAP_PARAMS:
-            if theirs in _SHARD_MAP_PARAMS:
-                kwargs[theirs] = kwargs.pop(ours)
-            else:  # pragma: no cover - jax without the knob
-                kwargs.pop(ours)
-    return _shard_map(fn, *args, **kwargs)
-
-
-def smap(fn, mesh, in_specs, out_specs, check_rep: bool = False):
+def smap(fn, mesh, in_specs, out_specs, check_vma: bool = False):
     """`shard_map` with the trainer's defaults (replication checking off:
     the quantized update produces replicated outputs by construction —
     psum'd metrics, identically-computed params — which the static
     checker cannot always prove through all_to_all/gather chains)."""
     return shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_rep,
+        check_vma=check_vma,
     )
 
 

@@ -42,6 +42,28 @@ MIN_WEIGHT_SIZE = 2 ** 14
 PIPE_STAGES_KEY = "pipe_stages"
 
 
+def require_devices() -> Sequence[jax.Device]:
+    """`jax.devices()` for bring-up and measurement paths: raises unless
+    the platform is `tpu` or the CPU was asked for explicitly
+    (`JAX_PLATFORMS=cpu` in the environment).
+
+    The system is written for the chip, and a run that quietly lands on
+    another backend reports numbers nobody deploys. No probe, no retry,
+    no fallback: a machine whose chip cannot be opened fails inside
+    `jax.devices()` itself.
+    """
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu" and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            f"jax found platform {platform!r} "
+            f"({devices[0].device_kind!r} x{len(devices)}), not 'tpu'. "
+            "This path runs on the chip; for a CPU run ask for it "
+            "explicitly with JAX_PLATFORMS=cpu."
+        )
+    return devices
+
+
 def initialize_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,

@@ -143,20 +143,6 @@ def pipeline_apply(
         mesh=mesh,
         in_specs=(spec_params, x_spec),
         out_specs=x_spec,
-        # Replication checking OFF for the pipeline program: jax's
-        # varying-manual-axes tracking loses the carry annotations when
-        # this shard_map's inner scan is differentiated under
-        # jax.checkpoint (partial-eval extends the scan carry with
-        # residual/tangent slots whose initializers are born unvarying,
-        # while the body emits them varying) — "Scan carry input and
-        # output got mismatched replication types", and jax's own error
-        # text prescribes check_rep=False as the workaround. Correctness
-        # does not lean on the static check here: tests/test_pipeline.py
-        # pins forward AND gradient equality against the sequential
-        # model, and tests/test_transformer_models.py pins the composed
-        # remat+grad_accum step. Minimal repro of the upstream bug:
-        # tests/test_pipeline.py::TestShardMapRematScanVma.
-        check_rep=False,
     )
     out = shard_mapped(stacked_params, micro)
     return jnp.reshape(out, (batch,) + out.shape[2:])
@@ -216,14 +202,12 @@ def _pipeline_shard(stacked_params, micro, *, stage_fn, num_stages,
     # The body makes the carry vary over the pipe axis (stage_idx masks,
     # ppermute) and over the batch axis when the input is data-sharded;
     # mark the initial carry the same way for shard_map's varying-manual-
-    # axes tracking (guarded like ring_attention's pvary: older jax has
-    # neither the tracking nor the op).
-    if hasattr(lax, "pcast"):
-        axes = tuple(varying_axes or (axis_name,))
-        resident0, out0 = jax.tree_util.tree_map(
-            lambda leaf: lax.pcast(leaf, axes, to="varying"),
-            (resident0, out0),
-        )
+    # axes tracking.
+    axes = tuple(varying_axes or (axis_name,))
+    resident0, out0 = jax.tree_util.tree_map(
+        lambda leaf: lax.pcast(leaf, axes, to="varying"),
+        (resident0, out0),
+    )
     (_, out_acc), _ = lax.scan(
         tick, (resident0, out0), jnp.arange(num_ticks)
     )

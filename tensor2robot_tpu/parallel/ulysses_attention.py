@@ -172,7 +172,7 @@ def ulysses_attention(
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if use_flash is None:
         # Same dispatch policy as ring_attention and single-device
-        # attention (VERDICT r4 item 4): XLA einsum path by default on
+        # attention: XLA einsum path by default on
         # the r3 on-chip evidence, flash above the auto threshold.
         # Ulysses' local attention runs over the FULL sequence (heads
         # are what the all_to_all splits), so the threshold compares

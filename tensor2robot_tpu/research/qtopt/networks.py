@@ -165,8 +165,8 @@ class Grasping44(nn.Module):
         net = nn.relu(net)
         # Non-overlapping pools dispatch the backward on the backend:
         # SelectAndScatter on TPU, scatter-free elsewhere (ops/pooling.py;
-        # on-chip A/B in DIAG_STEP_r05.json). Forward is bit-identical to
-        # nn.max_pool either way.
+        # round-5 on-chip A/B, not re-measured). Forward is bit-identical
+        # to nn.max_pool either way.
         net = pooling.max_pool(net, (3, 3))
 
         for i in range(self.num_convs[0]):

@@ -62,8 +62,7 @@ _EXPORTS = {
     "PolicyUnknown": "policies",
     "PolicyEvicted": "policies",
     "PolicyLoadFailed": "policies",
-    # compile_cache.py — persistent XLA compile cache for replicas.
-    "enable_compile_cache": "compile_cache",
+    # compile_cache.py — restore-time compile-cache engagement.
     "enable_compile_cache_for": "compile_cache",
     # gateway.py — the multi-tenant front door over router pools.
     "Gateway": "gateway",
@@ -127,7 +126,6 @@ if TYPE_CHECKING:  # pragma: no cover — static analyzers only
         ReplicaLink,
     )
     from tensor2robot_tpu.serving.compile_cache import (  # noqa: F401
-        enable_compile_cache,
         enable_compile_cache_for,
     )
     from tensor2robot_tpu.serving.gateway import (  # noqa: F401

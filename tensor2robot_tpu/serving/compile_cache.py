@@ -1,66 +1,15 @@
-"""JAX persistent compilation cache for serving processes.
-
-Replica boot cost is dominated by per-bucket XLA compiles: a policy
-server prewarms every warmup bucket before it reports started, and a
-hot-swap prewarms them again on the incoming version. None of that work
-changes between boots of the same artifact on the same topology — it is
-exactly what jax's persistent compilation cache deduplicates. This
-module is the serving-side switch for it, behind the central
-`T2R_COMPILE_CACHE_DIR` flag: replica N's first boot pays the compiles
-and writes the cache; every later boot (respawns after a chaos kill,
-rolling-deploy restarts, fleet scale-ups on the same host image)
-deserializes instead of compiling.
-
-With serialized AOT executables in the artifact (export/aot.py) this
-cache is the SECOND tier of the restore ladder: AOT executable ->
-persistent compile cache -> fresh trace. `enable_compile_cache_for`
-is the restore-time entry point: a version whose warmup ladder is
-fully covered by deserialized executables will never compile, so the
-cache round-trip (config update + latched-state reset) is skipped for
-that swap — re-entering it per swap was pure overhead on AOT-hit
-boots.
+"""Restore-time engagement of the persistent compile cache
+(utils/compile_cache.py) for one loaded export version: the second tier
+of the restore ladder AOT executable -> persistent cache -> fresh trace.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
-from tensor2robot_tpu import flags as t2r_flags
+from tensor2robot_tpu.utils.compile_cache import engage_compile_cache
 
-__all__ = ["enable_compile_cache", "enable_compile_cache_for"]
-
-
-def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Points jax's persistent compilation cache at a directory.
-
-    Resolution: explicit `cache_dir` argument > `T2R_COMPILE_CACHE_DIR`
-    flag > disabled (returns None, no config touched — the bit-exact
-    default path). Returns the directory in effect. Every compile is
-    cacheable (min compile time 0): a replica fleet re-boots the same
-    buckets, so even sub-second entries pay for themselves by the second
-    process.
-    """
-    if cache_dir is None:
-        cache_dir = t2r_flags.get_str("T2R_COMPILE_CACHE_DIR")
-    if not cache_dir:
-        return None
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    # jax memoizes the cache's enabled/disabled state at the FIRST
-    # compile: a process that compiled anything before this call (model
-    # init, an eager export) has latched "disabled" and would silently
-    # ignore the config update. reset_cache() drops the memo so the next
-    # compile re-reads the directory we just set.
-    try:
-        from jax._src import compilation_cache as _compilation_cache
-    except ImportError:  # pragma: no cover - future jax relayout
-        _compilation_cache = None
-    reset = getattr(_compilation_cache, "reset_cache", None)
-    if reset is not None:
-        reset()
-    return cache_dir
+__all__ = ["enable_compile_cache_for"]
 
 
 def enable_compile_cache_for(loaded) -> Optional[str]:
@@ -69,14 +18,13 @@ def enable_compile_cache_for(loaded) -> Optional[str]:
     When the version will serve EVERY bucket of its resolved ladder
     (T2R_SERVE_BUCKETS override included, `serving/buckets.py`
     resolution) from deserialized AOT executables, no compile will
-    happen for it — skip the cache round-trip entirely (returns None;
-    an already-enabled cache is left as is, this only skips
-    re-entering). Otherwise behaves exactly like
-    `enable_compile_cache()`: a compile tier is live for this version
-    and the cache must engage BEFORE its first compile (the prewarm
-    that follows restore). A server constructed with an explicit
-    `batch_buckets` ladder is invisible from here; `PolicyServer`
-    re-engages at start() for any bucket outside the AOT table.
+    happen for it — skip the engagement entirely (returns None).
+    Otherwise behaves exactly like `engage_compile_cache()`: a compile
+    tier is live for this version and the cache must engage BEFORE its
+    first compile (the prewarm that follows restore). A server
+    constructed with an explicit `batch_buckets` ladder is invisible
+    from here; `PolicyServer` re-engages at start() for any bucket
+    outside the AOT table.
     """
     if loaded is not None and getattr(loaded, "aot_covered", False):
         from tensor2robot_tpu.serving import buckets as buckets_lib
@@ -90,4 +38,4 @@ def enable_compile_cache_for(loaded) -> Optional[str]:
             ladder = ()
         if ladder and all(bucket in table for bucket in ladder):
             return None
-    return enable_compile_cache()
+    return engage_compile_cache()

@@ -658,7 +658,7 @@ class _StoreClient:
         reply = self._channel.result(pending, timeout_s=self._timeout_s)
         if reply[1] == "ok":
             return reply[2]
-        # Rehydrate the store's own error taxonomy: a server-side
+        # Rehydrate the store's own error classes: a server-side
         # ArtifactCorrupt / PolicyNotFound stays THAT type on this
         # host, so mirror callers branch on it exactly as local ones.
         error_cls = getattr(store_lib, reply[2], None)

@@ -49,6 +49,7 @@ import logging
 import os
 import queue
 import socket
+import sys
 import time
 from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
@@ -69,6 +70,7 @@ __all__ = [
     "multi_policy_store_factory",
     "mock_server_factory",
     "multi_policy_mock_factory",
+    "check_one_process_per_chip",
 ]
 
 
@@ -90,6 +92,58 @@ class ReplicaSpec:
     factory_kwargs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     env: Dict[str, str] = dataclasses.field(default_factory=dict)
     scope: Optional[str] = None  # chaos scope override (default r<index>)
+
+
+def check_one_process_per_chip(specs) -> None:
+    """Refuses a fleet whose replica processes would contend for the
+    accelerator, instead of letting them fail or hang at boot.
+
+    A chip belongs to one process at a time, and nothing in `serving/`
+    binds a replica to a device: a jax-backed replica opens EVERY local
+    chip. So more than one such replica cannot share a one-chip host,
+    on a four-chip host each would claim all four, and a single one
+    still collides with a parent that has already opened the device.
+    A replica whose environment asks for the CPU explicitly
+    (`JAX_PLATFORMS=cpu`, inherited or in `spec.env`) needs no chip;
+    mock backends never import jax. Per-replica chip binding is
+    ROADMAP A3/B5 — until then these fleets are "not brought up" on
+    the chip."""
+    needing = [
+        spec
+        for spec in specs
+        if spec.factory in (policy_server_factory, multi_policy_store_factory)
+        and spec.env.get("JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS"))
+        != "cpu"
+    ]
+    if not needing:
+        return
+    reason = None
+    if len(needing) > 1:
+        reason = (
+            f"{len(needing)} jax-backed replica processes would each open "
+            "every local accelerator"
+        )
+    else:
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            from jax._src import xla_bridge
+
+            if (
+                xla_bridge.backends_are_initialized()
+                and jax.default_backend() != "cpu"
+            ):
+                reason = (
+                    "this process already holds the accelerator "
+                    f"({jax.default_backend()!r}) its replica process needs"
+                )
+    if reason is not None:
+        raise RuntimeError(
+            f"refusing to start the fleet: {reason}. A chip belongs to one "
+            "process at a time and replicas have no per-process chip "
+            "binding yet (ROADMAP A3/B5). For the CPU proxy ask for it "
+            "explicitly with JAX_PLATFORMS=cpu; on the chip, serve "
+            "in-process with PolicyServer."
+        )
 
 
 def _apply_env(env: Mapping[str, str]) -> None:
@@ -431,7 +485,7 @@ def policy_server_factory(
     )
     from tensor2robot_tpu.serving.server import PolicyServer
 
-    # Persistent compilation cache (T2R_COMPILE_CACHE_DIR): engaged by
+    # Persistent compilation cache (utils/compile_cache.py): engaged by
     # the predictor's restore path per incoming version, BEFORE that
     # version's first compile (enable_compile_cache_for) — and skipped
     # there when the artifact's AOT executables cover every warmup

@@ -60,7 +60,11 @@ from tensor2robot_tpu import flags as t2r_flags
 from tensor2robot_tpu.net import codec as wire_codec
 from tensor2robot_tpu.serving import transport
 from tensor2robot_tpu.serving.metrics import percentile
-from tensor2robot_tpu.serving.replica import ReplicaSpec, replica_main
+from tensor2robot_tpu.serving.replica import (
+    ReplicaSpec,
+    check_one_process_per_chip,
+    replica_main,
+)
 from tensor2robot_tpu.utils.backoff import Backoff, poll_loop
 from tensor2robot_tpu.utils.errors import best_effort
 
@@ -396,6 +400,7 @@ class FleetRouter:
         warming in the background and join the pool when ready."""
         if self._started:
             raise RuntimeError("FleetRouter.start() called twice")
+        check_one_process_per_chip(self._specs)
         if self._transport_mode == "socket":
             # Cross-host fabric: replicas are independent process groups
             # on the CRC-framed wire. No mp context, no shared-memory
@@ -1193,6 +1198,9 @@ class FleetRouter:
                 raise RouterClosed("router is not running")
             replica = _Replica(
                 len(self._replicas), spec if spec is not None else self._specs[0]
+            )
+            check_one_process_per_chip(
+                [r.spec for r in self._replicas] + [replica.spec]
             )
             self._replicas.append(replica)
             self._metrics.count("scale_ups")

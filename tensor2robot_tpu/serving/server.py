@@ -368,15 +368,15 @@ class PolicyServer:
         (enable_compile_cache_for) only sees the artifact's own ladder;
         an explicit `batch_buckets` constructor ladder can be wider, and
         its extra buckets must not compile uncached just because the
-        warmup ladder happened to be AOT-covered. No-op when the cache
-        flag is unset."""
+        warmup ladder happened to be AOT-covered. No-op when no cache
+        directory has been placed (utils/compile_cache.py)."""
         table = getattr(loaded, "aot_executables", None) or {}
         if any(bucket not in table for bucket in self._buckets):
-            from tensor2robot_tpu.serving.compile_cache import (
-                enable_compile_cache,
+            from tensor2robot_tpu.utils.compile_cache import (
+                engage_compile_cache,
             )
 
-            enable_compile_cache()
+            engage_compile_cache()
 
     def _record_prewarm_sources(self, loaded) -> None:
         """Per-bucket restore tier of `loaded` + the aot_hits/aot_misses
@@ -385,7 +385,9 @@ class PolicyServer:
         back — the loud, counted fallback contract."""
         table = getattr(loaded, "aot_executables", None) or {}
         aot_requested = bool(getattr(loaded, "aot_enabled", False))
-        cache_on = bool(t2r_flags.get_str("T2R_COMPILE_CACHE_DIR"))
+        from tensor2robot_tpu.utils.compile_cache import placed_cache_dir
+
+        cache_on = placed_cache_dir() is not None
         sources: Dict[int, str] = {}
         hits = misses = 0
         for bucket in self._buckets:

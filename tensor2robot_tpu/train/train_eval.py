@@ -48,6 +48,7 @@ from tensor2robot_tpu.models.tpu_model_wrapper import TPUT2RModelWrapper
 from tensor2robot_tpu.parallel import collectives
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 from tensor2robot_tpu.parallel import planner as planner_lib
+from tensor2robot_tpu.utils.compile_cache import compile_cache_bypass
 from tensor2robot_tpu.specs import TensorSpecStruct, make_example_args
 from tensor2robot_tpu.testing import chaos
 from tensor2robot_tpu.train import durability, infeed
@@ -277,43 +278,6 @@ def plan_probe_compile_count() -> int:
     return _PLAN_PROBE_COMPILES
 
 
-def _reset_compile_cache_state() -> None:
-    # jax memoizes the persistent compilation cache's enabled state at
-    # the first compile; reset_cache() drops the memo so the config
-    # flip below actually takes (serving/compile_cache.py documents the
-    # latch).
-    try:
-        from jax._src import compilation_cache as _compilation_cache
-    except ImportError:  # pragma: no cover - future jax relayout
-        return
-    reset = getattr(_compilation_cache, "reset_cache", None)
-    if reset is not None:
-        reset()
-
-
-@contextlib.contextmanager
-def _plan_probe_compile_cache_bypass():
-    """Disables jax's persistent compilation cache around a plan-search
-    compile (the export/aot.py build-side discipline): a cache HIT hands
-    back an executable with no fresh object code and near-zero compile
-    time, which poisons both the timing and the compile counter the
-    search ranks and audits with. Restores the prior config — and resets
-    the latched cache state again — on the way out."""
-    prev_enabled = bool(jax.config.jax_enable_compilation_cache)
-    prev_dir = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_enable_compilation_cache", False)
-    if prev_dir:
-        jax.config.update("jax_compilation_cache_dir", None)
-    _reset_compile_cache_state()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", prev_enabled)
-        if prev_dir:
-            jax.config.update("jax_compilation_cache_dir", prev_dir)
-        _reset_compile_cache_state()
-
-
 def _executable_memory(executable):
     """compiled.memory_analysis() -> (total per-device bytes, fields).
 
@@ -370,7 +334,10 @@ def measure_plan_candidate(
     except ValueError as err:
         record["skipped"] = str(err)
         return record
-    with _plan_probe_compile_cache_bypass():
+    # A cache HIT hands back an executable with near-zero compile time,
+    # which poisons both the timing and the compile counter the search
+    # ranks and audits with.
+    with compile_cache_bypass():
         try:
             mesh = plan.build_mesh()
             compiled = CompiledModel(

@@ -572,12 +572,9 @@ class TestCompileTierEngagement:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", previous_min
         )
-        try:
-            from jax._src import compilation_cache
+        from jax._src import compilation_cache
 
-            compilation_cache.reset_cache()
-        except ImportError:  # pragma: no cover - future jax relayout
-            pass
+        compilation_cache.reset_cache()
 
     def test_flag_ladder_beyond_aot_engages_cache(
         self, export_root, tmp_path, monkeypatch
@@ -588,7 +585,7 @@ class TestCompileTierEngagement:
 
         loaded = ExportedModel(latest_export_dir(export_root))
         assert loaded.aot_covered
-        monkeypatch.setenv("T2R_COMPILE_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
         # Resolved ladder == warmup ladder, fully AOT-covered -> skip.
         assert enable_compile_cache_for(loaded) is None
         # T2R_SERVE_BUCKETS adds a bucket with no executable -> the
@@ -599,7 +596,7 @@ class TestCompileTierEngagement:
     def test_explicit_server_ladder_beyond_aot_engages_cache(
         self, export_root, tmp_path, monkeypatch
     ):
-        monkeypatch.setenv("T2R_COMPILE_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
         predictor = ExportedSavedModelPredictor(export_dir=export_root)
         assert predictor.restore()
         with PolicyServer(
@@ -634,12 +631,9 @@ class TestWarmCompileCacheBuild:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", previous_min
         )
-        try:
-            from jax._src import compilation_cache
+        from jax._src import compilation_cache
 
-            compilation_cache.reset_cache()
-        except ImportError:  # pragma: no cover - future jax relayout
-            pass
+        compilation_cache.reset_cache()
 
     def test_build_under_warm_cache_round_trips(self, export_root, tmp_path):
         from jax import export as jax_export
@@ -648,8 +642,8 @@ class TestWarmCompileCacheBuild:
             STABLEHLO_DIR,
             STABLEHLO_FILENAME,
         )
-        from tensor2robot_tpu.serving.compile_cache import (
-            enable_compile_cache,
+        from tensor2robot_tpu.utils.compile_cache import (
+            engage_compile_cache,
         )
 
         with open(
@@ -661,7 +655,9 @@ class TestWarmCompileCacheBuild:
         ) as f:
             program_bytes = f.read()
         cache_dir = str(tmp_path / "jaxcache")
-        enable_compile_cache(cache_dir)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        assert engage_compile_cache() == cache_dir
         # Warm the cache with this exact program/bucket OUTSIDE the
         # build — the position every re-exporting process is in.
         batch = _example(2)
@@ -692,6 +688,41 @@ class TestWarmCompileCacheBuild:
         # The bypass is scoped to the builds: the cache is back on.
         assert jax.config.jax_enable_compilation_cache
         assert jax.config.jax_compilation_cache_dir == cache_dir
+
+
+def test_one_device_executable_restores_on_a_multi_device_host():
+    """jax 0.9's deserialize_and_load defaults `execution_devices` to
+    EVERY device of the backend, so a one-device executable restored
+    that way expects one argument shard per device and fails its first
+    call ("Expected args to execute_sharded_on_local_devices to have 8
+    shards, got [1]") on any multi-device host. The restore names the
+    one serving device instead, and the topology key describes it."""
+    assert jax.device_count() == 8
+    batch = {"x": np.arange(6, dtype=np.float32).reshape(2, 3)}
+    compiled = jax.jit(lambda b: {"y": b["x"] * 2.0}).lower(batch).compile()
+    blob = aot_lib.serialize_compiled(
+        compiled,
+        {
+            "format_version": aot_lib.AOT_FORMAT_VERSION,
+            "jax": jax.__version__,
+            "topology": aot_lib.device_topology(),
+        },
+    )
+    restored, _ = aot_lib.load_executable(
+        blob, expect_topology=aot_lib.device_topology()
+    )
+    np.testing.assert_array_equal(restored(batch)["y"], batch["x"] * 2.0)
+    device = aot_lib.serving_device()
+    assert {
+        d
+        for sharding in jax.tree_util.tree_leaves(restored.output_shardings)
+        for d in sharding.device_set
+    } == {device}
+    assert aot_lib.device_topology() == {
+        "platform": device.platform,
+        "device_kind": device.device_kind,
+        "device_count": 8,
+    }
 
 
 class TestFlagsDeclared:

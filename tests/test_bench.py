@@ -10,6 +10,7 @@ import json
 import os
 import subprocess
 import sys
+import tempfile
 
 import pytest
 
@@ -21,13 +22,17 @@ def _run_bench(*args, env_extra=None, timeout=420):
     env["PYTHONPATH"] = REPO_ROOT + ":" + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
     env.update(env_extra or {})
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py"), *args],
-        capture_output=True,
-        text=True,
-        env=env,
-        timeout=timeout,
-    )
+    # bench.py is an entry point and places the compile cache; keep the
+    # test's entries out of <checkout>/.jax_cache.
+    with tempfile.TemporaryDirectory(prefix="bench_jax_cache_") as cache_dir:
+        env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO_ROOT, "bench.py"), *args],
+            capture_output=True,
+            text=True,
+            env=env,
+            timeout=timeout,
+        )
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = proc.stdout.strip().splitlines()[-1]
     return json.loads(line)
@@ -36,12 +41,12 @@ def _run_bench(*args, env_extra=None, timeout=420):
 @pytest.mark.slow
 def test_bench_mfu_contract():
     """The headline MFU path, on the CPU-proxy branch (reduced tower)."""
-    payload = _run_bench(env_extra={"BENCH_BACKEND_WAIT": "60"})
+    payload = _run_bench()
     assert payload["metric"] == "qtopt_critic_train_mfu_cpu_proxy"
     assert payload["unit"] == "fraction_of_peak"
     assert 0 < payload["value"] <= 1.0
     assert "error" not in payload
-    # CPU-proxy payloads must self-describe (VERDICT r4 weak #6): the
+    # CPU-proxy payloads must self-describe: the
     # top-level proxy flag and the vs_baseline disclaimer, not just a
     # detail-channel backend note.
     assert payload["proxy"] is True
@@ -94,7 +99,7 @@ def test_overlap_fields_clamp():
 
 
 def test_last_onchip_pointer():
-    """Unit-pins _last_onchip (VERDICT r5 next #7): the pointer finds the
+    """Unit-pins _last_onchip: the pointer finds the
     newest committed real-hardware artifact of a metric family, skips
     proxies/failures, and degrades to None for unknown families."""
     sys.path.insert(0, REPO_ROOT)
@@ -198,7 +203,6 @@ def test_bench_auc_contract():
         env_extra={
             "BENCH_AUC_STEPS": "4",
             "BENCH_AUC_BATCH": "8",
-            "BENCH_BACKEND_WAIT": "60",
         },
     )
     # On the CPU backend the metric self-describes as a proxy (the real
@@ -208,7 +212,7 @@ def test_bench_auc_contract():
     assert payload["unit"] == "auc_delta"
     assert 0.0 <= payload["value"] <= 1.0
     assert "error" not in payload
-    # Budget-delta metrics name their ratio honestly (VERDICT r5 weak #6):
+    # Budget-delta metrics name their ratio honestly:
     # fraction_of_budget == vs_baseline == value / budget, budget explicit.
     assert payload["budget"] == 0.02
     assert payload["fraction_of_budget"] == payload["vs_baseline"]
@@ -216,7 +220,7 @@ def test_bench_auc_contract():
         payload["value"] / 0.02, abs=1e-3
     )
     # Proxy payloads point at the newest on-chip artifact of the family
-    # (VERDICT r5 next #7) — present even when None.
+    # — present even when None.
     assert "last_onchip" in payload
     detail = payload["detail"]
     assert detail["backend"] == "cpu"
@@ -231,7 +235,7 @@ def test_bench_auc_contract():
 def test_bench_predict_contract():
     payload = _run_bench(
         "predict",
-        env_extra={"BENCH_BACKEND_WAIT": "60", "BENCH_PREDICT_SAMPLES": "8"},
+        env_extra={"BENCH_PREDICT_SAMPLES": "8"},
     )
     assert payload["metric"] == "qtopt_cem_predict_hz_cpu_proxy"
     assert payload["unit"] == "predict_calls_per_sec"
@@ -251,7 +255,7 @@ def test_bench_pipe_contract():
     train step, ratio against the resident-batch rate."""
     payload = _run_bench(
         "pipe",
-        env_extra={"BENCH_BACKEND_WAIT": "60", "BENCH_PIPE_RECORDS": "8"},
+        env_extra={"BENCH_PIPE_RECORDS": "8"},
     )
     assert payload["metric"] == "qtopt_e2e_pipeline_steps_per_sec_cpu_proxy"
     assert payload["unit"] == "steps_per_sec"
@@ -501,9 +505,9 @@ def test_bench_policies_contract(tmp_path):
 
 
 def test_aot_boot_env_scrubs_every_serving_flag(monkeypatch):
-    """The aot leg's child boots must see ONLY the flags the twin under
-    measurement sets: a leaked ambient bucket ladder / quant regime /
-    cache dir would change what the twins boot and fail the acceptance
+    """The aot leg's child boots must see ONLY the settings the twin
+    under measurement sets: a leaked ambient bucket ladder / quant regime
+    / cache dir would change what the twins boot and fail the acceptance
     gates (or worse, silently measure the wrong tier)."""
     sys.path.insert(0, REPO_ROOT)
     import bench
@@ -511,22 +515,53 @@ def test_aot_boot_env_scrubs_every_serving_flag(monkeypatch):
     for key, value in {
         "T2R_SERVE_AOT": "0",
         "T2R_AOT_REQUIRE": "1",
-        "T2R_COMPILE_CACHE_DIR": "/tmp/leak",
+        "JAX_COMPILATION_CACHE_DIR": "/tmp/leak",
         "T2R_SERVE_BUCKETS": "1,2",
         "T2R_SERVE_QUANT": "int8",
     }.items():
         monkeypatch.setenv(key, value)
-    env = bench._aot_scrubbed_env(True, platform="cpu")
+    env = bench._aot_scrubbed_env(True, "cpu")
     for key in (
-        "T2R_AOT_REQUIRE", "T2R_COMPILE_CACHE_DIR",
+        "T2R_AOT_REQUIRE", "JAX_COMPILATION_CACHE_DIR",
         "T2R_SERVE_BUCKETS", "T2R_SERVE_QUANT",
     ):
         assert key not in env, key
     assert env["T2R_SERVE_AOT"] == "1"
     assert env["JAX_PLATFORMS"] == "cpu"  # pinned to the parent backend
-    cached = bench._aot_scrubbed_env(False, cache_dir="/tmp/tier")
+    cached = bench._aot_scrubbed_env(False, "cpu", cache_dir="/tmp/tier")
     assert cached["T2R_SERVE_AOT"] == "0"
-    assert cached["T2R_COMPILE_CACHE_DIR"] == "/tmp/tier"
+    # The cache twin's directory travels the way jax itself reads it.
+    assert cached["JAX_COMPILATION_CACHE_DIR"] == "/tmp/tier"
+    assert cached["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
+
+
+def test_devices_gate_and_peak_table(monkeypatch):
+    """No leg picks a platform on its own: a non-tpu platform without the
+    explicit JAX_PLATFORMS=cpu request is a reported failure, and a
+    device kind missing from the peaks table is an error off the CPU."""
+    sys.path.insert(0, REPO_ROOT)
+    import types
+
+    import bench
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit):
+        bench._devices("gate_probe")
+    with pytest.raises(SystemExit):
+        bench._require_cpu_request("gate_probe", "this leg")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    bench._require_cpu_request("gate_probe", "this leg")
+    devices = bench._devices("gate_probe", compile_cache=False)
+    assert devices[0].platform == "cpu"
+    assert bench._peak_flops(devices[0]) == bench._CPU_PROXY_PEAK_FLOPS
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert bench._peak_flops(v5e) == 197e12
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v9")
+    with pytest.raises(ValueError, match="TPU v9"):
+        bench._peak_flops(unknown)
+    with pytest.raises(SystemExit):
+        bench._refuse_children_on_chip([v5e], "gate_probe", "boot twins")
+    bench._refuse_children_on_chip(devices, "gate_probe", "boot twins")
 
 
 @pytest.mark.slow
@@ -541,7 +576,6 @@ def test_bench_aot_contract(tmp_path):
     out = str(tmp_path / "aot.json")
     payload = _run_bench(
         "aot", "--buckets", "1,2,4", "--leg-secs", "2.0", "--out", out,
-        env_extra={"BENCH_BACKEND_WAIT": "60"},
         timeout=560,
     )
     assert payload["metric"] == "serve_cold_start_aot_speedup_cpu_proxy"
@@ -589,7 +623,6 @@ def test_bench_serve_contract(tmp_path):
         "--baseline-secs", "0.9",
         "--leg-secs", "1.5",
         "--out", out,
-        env_extra={"BENCH_BACKEND_WAIT": "60"},
         timeout=420,
     )
     assert payload["metric"] == "policy_serve_throughput_cpu_proxy"

@@ -2,8 +2,8 @@
 
 Every research model must export a loadable StableHLO artifact whose
 outputs numerically match the in-process predict path — a regression that
-silently falls back to the model-code path fails here loudly (VERDICT r1
-weak #6; reference serving-receiver coverage in utils/train_eval_test.py
+silently falls back to the model-code path fails here loudly
+(reference serving-receiver coverage in utils/train_eval_test.py
 compared numpy vs tf_example interfaces the same way).
 """
 

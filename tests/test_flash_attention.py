@@ -375,3 +375,38 @@ class TestRingWithFlashTiles:
                 jnp.zeros((1, 16, 1, 8)), jnp.zeros((1, 16, 1, 8)),
                 jnp.zeros((1, 16, 1, 8)), interpret=False,
             )
+
+
+def test_whole_sequence_blocks_are_sized_or_refused_by_name(monkeypatch):
+    """The kernels keep whole-sequence operands in VMEM: small ones ride
+    the compiler's default budget, larger ones raise the scoped limit,
+    and a shape the chip cannot hold is a ValueError naming it — never
+    an XLA allocation failure inside the enclosing program (budget
+    measured on TPU v5 lite; see ops/flash_attention.py)."""
+    import types
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tensor2robot_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(
+        pltpu, "get_tpu_info",
+        lambda: types.SimpleNamespace(vmem_capacity_bytes=128 << 20),
+    )
+
+    def kv(seq, dim=128, dtype=jnp.bfloat16):
+        return [(seq, dim, dtype), (seq, dim, dtype)]
+
+    assert fa._vmem_kwargs("k", kv(8192), False, "s") == {}
+    raised = fa._vmem_kwargs("k", kv(65536), False, "s")
+    assert raised["compiler_params"].vmem_limit_bytes == 100 << 20
+    # D=64 pads to the 128-lane tile: same footprint as D=128.
+    assert fa._vmem_kwargs("k", kv(65536, dim=64), False, "s")
+    with pytest.raises(ValueError, match=r"flash_attention_tile .*128 MiB.*"
+                       r"k/v \(1, 262144, 1, 128\)"):
+        fa._vmem_kwargs(
+            "flash_attention_tile", kv(262144), False,
+            "k/v (1, 262144, 1, 128)",
+        )
+    assert fa._vmem_kwargs("k", kv(262144), True, "s") == {}  # interpreter
+

@@ -393,3 +393,53 @@ class TestTransport:
             assert response.outputs["y"] == pytest.approx(
                 float(frame.astype(np.float64).sum()) + 2.5
             )
+
+
+class TestOneProcessPerChip:
+    """A chip belongs to one process at a time and replicas have no chip
+    binding: fleets that would contend for the accelerator are refused
+    up front with the reason, instead of failing or hanging at boot."""
+
+    @staticmethod
+    def _specs(n, factory, env=None):
+        from tensor2robot_tpu.serving import ReplicaSpec
+
+        return [
+            ReplicaSpec(
+                factory=factory, factory_kwargs={"export_root": "/nowhere"},
+                env=dict(env or {}),
+            )
+            for _ in range(n)
+        ]
+
+    def test_jax_fleet_without_the_cpu_request_is_refused(self, monkeypatch):
+        from tensor2robot_tpu.serving import FleetRouter, policy_server_factory
+        from tensor2robot_tpu.serving.replica import (
+            check_one_process_per_chip,
+        )
+
+        monkeypatch.delenv("JAX_PLATFORMS")
+        specs = self._specs(2, policy_server_factory)
+        with pytest.raises(RuntimeError, match="each open every local acc"):
+            check_one_process_per_chip(specs)
+        with pytest.raises(RuntimeError, match="one process at a time"):
+            FleetRouter(specs).start()
+        # One jax replica under a parent whose backend is the CPU is fine.
+        check_one_process_per_chip(specs[:1])
+
+    def test_explicit_cpu_and_mock_fleets_pass(self, monkeypatch):
+        from tensor2robot_tpu.serving import (
+            mock_server_factory,
+            policy_server_factory,
+        )
+        from tensor2robot_tpu.serving.replica import (
+            check_one_process_per_chip,
+        )
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        check_one_process_per_chip(self._specs(4, policy_server_factory))
+        monkeypatch.delenv("JAX_PLATFORMS")
+        check_one_process_per_chip(
+            self._specs(4, policy_server_factory, {"JAX_PLATFORMS": "cpu"})
+        )
+        check_one_process_per_chip(self._specs(4, mock_server_factory))

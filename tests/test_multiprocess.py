@@ -49,9 +49,9 @@ def test_two_process_distributed_collectives(tmp_path):
 
     env = dict(os.environ)
     # Each worker must see exactly its own single CPU device; scrub the
-    # virtual-device flag the surrounding test session sets.
+    # virtual-device flag the surrounding test session sets (the explicit
+    # JAX_PLATFORMS=cpu request is inherited).
     env.pop("XLA_FLAGS", None)
-    env.pop("JAX_PLATFORMS", None)
 
     workers = [
         subprocess.Popen(

@@ -136,29 +136,17 @@ class TestPipelineApply:
 
 
 class TestShardMapRematScanVma:
-    """Root cause of the (former) pipeline+grad_accum+remat seed failure.
+    """pipeline_apply runs its shard_map with replication checking ON,
+    which rests on jax tracking varying-manual-axes through a scan that
+    is differentiated under jax.checkpoint. Older jax lost the carry
+    annotations there ("Scan carry input and output got mismatched
+    replication types") and the pipeline carried a check-off workaround;
+    jax 0.9.0 tracks it. This is the minimal shape of that program —
+    scan + collective in the body + remat — so a jax change that breaks
+    it again is noticed here, not as a mystery flip in the composed
+    trainer test."""
 
-    jax's shard_map replication tracking (check_rep / varying manual
-    axes) loses its carry annotations when a scan INSIDE a shard_map is
-    differentiated THROUGH jax.checkpoint: partial-eval extends the
-    loop carry with residual/tangent slots whose zero initializers are
-    born *unvarying* while the (collective-touching) body emits them
-    *varying*, and scan's type check then fails with "Scan carry input
-    and output got mismatched replication types ... pass the
-    check_rep=False argument to shard_map". The three ingredients are
-    all required — drop the remat, the scan, or the collective in the
-    body and the program checks clean (see the passing pipeline grad
-    tests above, which differentiate the same scan WITHOUT remat).
-
-    The fix: pipeline_apply runs its shard_map with check_rep=False
-    (parallel/pipeline.py), leaning on the sequential-parity tests for
-    correctness instead of the static replication checker. This repro
-    pins the upstream failure mode at its minimal shape so a jax
-    upgrade that fixes (or changes) the behavior is noticed here, not
-    as a mystery flip in the composed trainer test.
-    """
-
-    def _repro(self, check_rep: bool):
+    def test_scan_in_shard_map_differentiates_under_remat(self):
         from tensor2robot_tpu.parallel import collectives
 
         mesh = mesh_lib.make_mesh(pipe=2, devices=jax.devices()[:2])
@@ -170,11 +158,9 @@ class TestShardMapRematScanVma:
                 )
                 return shifted + x, None
 
-            carry0 = jnp.zeros_like(x)
-            if hasattr(jax.lax, "pcast"):
-                carry0 = jax.lax.pcast(
-                    carry0, (mesh_lib.PIPE_AXIS,), to="varying"
-                )
+            carry0 = jax.lax.pcast(
+                jnp.zeros_like(x), (mesh_lib.PIPE_AXIS,), to="varying"
+            )
             out, _ = jax.lax.scan(tick, carry0, jnp.arange(3))
             return collectives.psum(out, mesh_lib.PIPE_AXIS)
 
@@ -183,7 +169,6 @@ class TestShardMapRematScanVma:
             mesh=mesh,
             in_specs=pipeline.PartitionSpec(),
             out_specs=pipeline.PartitionSpec(),
-            check_rep=check_rep,
         )
 
         def loss(x):
@@ -191,34 +176,5 @@ class TestShardMapRematScanVma:
 
         # jit: eager shard_map cannot evaluate the closed_call remat
         # introduces; the production path (CompiledModel) is always jit.
-        return jax.jit(jax.grad(loss))(jnp.ones((4,), jnp.float32))
-
-    def test_check_rep_off_differentiates_under_remat(self):
-        grads = self._repro(check_rep=False)
+        grads = jax.jit(jax.grad(loss))(jnp.ones((4,), jnp.float32))
         assert np.all(np.isfinite(np.asarray(grads)))
-
-    def test_check_rep_on_pins_upstream_vma_bug(self):
-        """The minimal repro: scan-in-shard_map under jax.checkpoint
-        with replication checking ON. Pinned to fail with the exact
-        upstream complaint; if a jax upgrade makes this pass, the
-        workaround in pipeline_apply can be retired."""
-        try:
-            self._repro(check_rep=True)
-        except Exception as err:
-            # Depending on where the tracker loses the annotation first,
-            # jax reports either the scan-carry mismatch ("Scan carry
-            # input and output got mismatched replication types" — the
-            # composed trainer test's form) or the collective-input form
-            # ("ppermute must be applied to a device-varying replication
-            # type, but got None"); both prescribe the same workaround.
-            message = str(err)
-            assert (
-                "replication type" in message
-                or "check_rep=False" in message
-            ), err
-        else:
-            pytest.fail(
-                "jax now tracks scan-carry replication through remat: "
-                "check_rep=False workaround in pipeline_apply (and this "
-                "pin) can be retired"
-            )

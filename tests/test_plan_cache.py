@@ -258,7 +258,7 @@ class TestCompileCacheBypass:
         prev = bool(jax.config.jax_enable_compilation_cache)
         jax.config.update("jax_enable_compilation_cache", True)
         try:
-            with train_eval._plan_probe_compile_cache_bypass():
+            with train_eval.compile_cache_bypass():
                 assert not jax.config.jax_enable_compilation_cache
             assert jax.config.jax_enable_compilation_cache
         finally:
@@ -269,7 +269,7 @@ class TestCompileCacheBypass:
         jax.config.update("jax_enable_compilation_cache", True)
         try:
             with pytest.raises(RuntimeError, match="boom"):
-                with train_eval._plan_probe_compile_cache_bypass():
+                with train_eval.compile_cache_bypass():
                     raise RuntimeError("boom")
             assert jax.config.jax_enable_compilation_cache
         finally:

@@ -90,7 +90,7 @@ class TestGuards:
 
     def test_rejects_bias_carrying_checkpoint(self):
         """A bias param restored from an nn.Conv(use_bias=True) checkpoint
-        must raise at apply time, not be silently ignored (ADVICE r5)."""
+        must raise at apply time, not be silently ignored."""
         x = jnp.zeros((1, 12, 12, 3))
         module = SpaceToDepthConv(4, (6, 6), strides=(2, 2))
         params = module.init(jax.random.PRNGKey(0), x)

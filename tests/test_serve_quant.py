@@ -382,7 +382,6 @@ class TestLoadRegimes:
         assert spec.choices == (
             "none", "fp16", "int8", "fp8_e4m3", "fp8_e5m2"
         )
-        assert t2r_flags.get_str("T2R_COMPILE_CACHE_DIR") is None
         assert t2r_flags.get_str("T2R_SERVE_NATIVE_LAYERS") is None
 
 
@@ -507,12 +506,13 @@ class TestServerRoundTrip:
 class TestCompileCache:
     @pytest.fixture(autouse=True)
     def _restore_jax_cache_config(self):
-        """enable_compile_cache mutates GLOBAL jax config; leaking a
-        pytest tmp dir as the cache dir (plus min-compile-time 0) into
+        """These tests place a cache in the GLOBAL jax config; leaking
+        a pytest tmp dir as the cache dir (plus min-compile-time 0) into
         the rest of the suite means every later compile writes cache
         entries to a doomed path. Restore the config and drop the
         latched cache state after each test."""
         import jax
+        from jax._src import compilation_cache
 
         previous_dir = jax.config.jax_compilation_cache_dir
         previous_min = jax.config.jax_persistent_cache_min_compile_time_secs
@@ -521,20 +521,23 @@ class TestCompileCache:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", previous_min
         )
-        try:
-            from jax._src import compilation_cache
+        compilation_cache.reset_cache()
 
-            compilation_cache.reset_cache()
-        except ImportError:  # pragma: no cover - future jax relayout
-            pass
+    @staticmethod
+    def _place_cache(path) -> None:
+        jax.config.update("jax_compilation_cache_dir", str(path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-    def test_flag_resolution(self, tmp_path, monkeypatch):
-        from tensor2robot_tpu.serving.compile_cache import enable_compile_cache
+    def test_library_engages_only_a_placed_cache(self, tmp_path):
+        from tensor2robot_tpu.utils.compile_cache import (
+            engage_compile_cache,
+        )
 
-        monkeypatch.delenv("T2R_COMPILE_CACHE_DIR", raising=False)
-        assert enable_compile_cache() is None  # unset flag = no-op
-        monkeypatch.setenv("T2R_COMPILE_CACHE_DIR", str(tmp_path))
-        assert enable_compile_cache() == str(tmp_path)
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert engage_compile_cache() is None  # nothing placed = no-op
+        assert jax.config.jax_compilation_cache_dir is None
+        self._place_cache(tmp_path)
+        assert engage_compile_cache() == str(tmp_path)
 
     # ~14s (two full server boots) on 1 cpu: slow slice; the cache
     # enable/scope pins above and the AOT restore-ladder tests keep
@@ -554,12 +557,9 @@ class TestCompileCache:
         would write no cache entries — tests/test_aot.py covers that
         tier).
         """
-        from tensor2robot_tpu.serving.compile_cache import enable_compile_cache
-
         _, root = quant_export
         monkeypatch.setenv("T2R_SERVE_AOT", "0")
-        monkeypatch.setenv("T2R_COMPILE_CACHE_DIR", str(tmp_path))
-        assert enable_compile_cache() == str(tmp_path)
+        self._place_cache(tmp_path)
 
         def boot_and_serve():
             predictor = ExportedSavedModelPredictor(export_dir=root)
@@ -587,7 +587,7 @@ class TestCompileCache:
         np.testing.assert_array_equal(first, second)
 
     def test_restore_path_engages_cache_before_first_compile(
-        self, monkeypatch
+        self, monkeypatch, tmp_path
     ):
         """Cache engagement moved from the replica factory into the
         predictor's restore path (enable_compile_cache_for): it still
@@ -614,8 +614,8 @@ class TestCompileCache:
             metadata = {"warmup_batch_sizes": [1, 2]}
 
         # AOT covers the resolved ladder -> the cache round-trip is
-        # skipped even though the flag names a directory.
-        monkeypatch.setenv("T2R_COMPILE_CACHE_DIR", "/tmp/t2r_cache_pin")
+        # skipped even though a directory is placed.
+        self._place_cache(tmp_path)
         monkeypatch.delenv("T2R_SERVE_BUCKETS", raising=False)
         assert enable_compile_cache_for(_Loaded()) is None
 

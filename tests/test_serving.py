@@ -536,7 +536,6 @@ class TestBuckets:
             "T2R_SERVE_MAX_WAIT_MS",
             "T2R_SERVE_OVERLOAD",
             "T2R_SERVE_QUANT",
-            "T2R_COMPILE_CACHE_DIR",
         ):
             assert t2r_flags.get_flag(name).name == name
 

@@ -213,7 +213,7 @@ class TestLintsCatch:
         """The round-11 flags ride the same rails: raw environ reads are
         env-undeclared, wrong-kind getter reads are env-kind-mismatch,
         and the declared getter spellings are clean."""
-        for name in ("T2R_SERVE_QUANT", "T2R_COMPILE_CACHE_DIR"):
+        for name in ("T2R_SERVE_QUANT", "T2R_SERVE_NATIVE_LAYERS"):
             assert "env-undeclared" in self._rules(
                 f"import os\nx = os.environ.get({name!r})\n"
             )
@@ -224,7 +224,7 @@ class TestLintsCatch:
         clean = self._rules(
             "from tensor2robot_tpu import flags\n"
             "a = flags.get_enum('T2R_SERVE_QUANT')\n"
-            "b = flags.get_str('T2R_COMPILE_CACHE_DIR')\n"
+            "b = flags.get_str('T2R_SERVE_NATIVE_LAYERS')\n"
         )
         assert "env-kind-mismatch" not in clean
         assert "env-unknown-flag" not in clean

@@ -1,4 +1,4 @@
-"""Two-process FLAGSHIP dryrun worker (VERDICT r4 item 8).
+"""Two-process FLAGSHIP dryrun worker.
 
 Each invocation is one "host" with 2 virtual CPU devices: it joins the
 coordinator, builds the 4-device global data mesh, and trains ONE step of
@@ -20,16 +20,15 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-# 2 virtual devices per process, CPU platform, BEFORE jax initializes.
+# 2 virtual devices per process BEFORE jax initializes; the CPU platform
+# comes from the caller's environment (JAX_PLATFORMS=cpu).
 _FLAGS = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _FLAGS:
     os.environ["XLA_FLAGS"] = (
         _FLAGS + " --xla_force_host_platform_device_count=2"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
+import jax  # noqa: E402
 
 
 def main(coordinator: str, num_processes: int, process_id: int) -> None:

@@ -17,10 +17,7 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
+import jax  # noqa: E402  (the caller's environment says JAX_PLATFORMS=cpu)
 import numpy as np  # noqa: E402
 
 from tensor2robot_tpu.parallel import mesh as mesh_lib  # noqa: E402

@@ -8,8 +8,9 @@ one assert). This applies that gate to the flagship QT-Opt critic at
 debug scale: a committed TFRecord of seeded spec-conforming examples +
 the q_predicted/loss values from two deterministic train steps.
 
-Run `python tools/make_qtopt_golden.py` ONLY on an intentional behavior
-change; commit both regenerated files with that change.
+Run `JAX_PLATFORMS=cpu python tools/make_qtopt_golden.py` ONLY on an
+intentional behavior change; commit both regenerated files with that
+change.
 Fixture caveat (same as the reference's checked-in tfrecord): jpeg BYTES
 are pinned by the committed record file, so only decode determinism
 matters at test time.
@@ -54,9 +55,6 @@ NUM_CONVS = (2, 2, 1)
 
 
 def build_model():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from tensor2robot_tpu.hooks import add_golden_tensor
     from tensor2robot_tpu.research.qtopt.t2r_models import (
         Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom,

@@ -23,6 +23,9 @@ touching an accelerator or real data:
 
 Exit status: 0 clean, 1 findings, 2 infrastructure failure.
 
+Run with JAX_PLATFORMS=cpu (tools/run_checks.sh does): the passes need no
+accelerator and must not take the chip from a running job.
+
 Examples:
   python tools/t2r_check.py                 # passes 1+2+3
   python tools/t2r_check.py --sanitize      # all four
@@ -43,7 +46,6 @@ import tempfile
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _run_specflow(target_names) -> int:
