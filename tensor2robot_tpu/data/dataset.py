@@ -40,6 +40,7 @@ from tensor2robot_tpu.data.roi import (
 )
 from tensor2robot_tpu.data.wire import FastSpecParser
 from tensor2robot_tpu.specs import TensorSpecStruct
+from tensor2robot_tpu.utils import tracing
 
 _log = logging.getLogger(__name__)
 
@@ -144,6 +145,11 @@ class _Prefetcher:
         return self
 
     def __next__(self):
+        # Was the queue empty when the consumer came: the share of gets
+        # that had to wait for the producer.
+        tracing.count("data.prefetch_gets")
+        if self._queue.empty():
+            tracing.count("data.prefetch_empty")
         item = self._queue.get()
         if item is self._SENTINEL:
             if self._error is not None:
@@ -431,12 +437,25 @@ def _parse_chunk_impl(
             return fast.parse_batch(_regroup_chunk(chunk), roi=roi)
         except Exception:
             fast_state.note_fallback()
+            tracing.add(fast_fallback=1)
     try:
         return _parse_with(parser, chunk, roi=roi)
     except Exception as err:
         if default_parse_on_error() != "skip":
             raise
         return _skip_and_parse(parser, chunk, roi, stats, err)
+
+
+def _traced_parse(fast_state, parser, item, stats):
+    """`_parse_chunk_impl` on one `(ordinal, payload)` of `_chunks()` under
+    its `data.parse_chunk` span: one worker's whole work on one batch. The
+    decoder adds `images` and `decode_ns` to it (data/wire.py). Returns
+    (parsed, span)."""
+    ordinal, payload = item
+    records = len(_split_payload(payload)[0])
+    with tracing.span("data.parse_chunk", ordinal=ordinal, records=records) as span:
+        parsed = _parse_chunk_impl(fast_state, parser, payload, stats)
+    return parsed, span
 
 
 def _shm_attach(name: str):
@@ -453,7 +472,7 @@ def _shm_align(nbytes: int) -> int:
     return (nbytes + _SHM_ALIGN - 1) // _SHM_ALIGN * _SHM_ALIGN
 
 
-def _process_parse_chunk(chunk):
+def _process_parse_chunk(item):
     """Worker-side parse + zero-copy return.
 
     Large arrays (decoded image batches) are written into a shared-memory
@@ -466,16 +485,18 @@ def _process_parse_chunk(chunk):
     if parser is None:  # pragma: no cover - initializer always runs first
         raise RuntimeError("process pool worker missing parser init")
     # Skip-mode + fallback counters ride each payload back as a
-    # per-chunk DELTA (worker processes cannot share the parent's
-    # ParseStats).
+    # per-chunk DELTA, and the chunk's `data.parse_chunk` span with them
+    # (worker processes share neither the parent's ParseStats nor its
+    # recorder): epoch nanoseconds mean the same in the parent, and the
+    # negated pid stands for this worker's thread there.
     stats = ParseStats()
     fast = _PROCESS_FAST.parser if _PROCESS_FAST is not None else None
     fallbacks_before = fast.fallbacks if fast is not None else 0
-    parsed = _parse_chunk_impl(_PROCESS_FAST, parser, chunk, stats)
+    parsed, span = _traced_parse(_PROCESS_FAST, parser, item, stats)
     if fast is not None:
         stats.fast_fallbacks = fast.fallbacks - fallbacks_before
-    delta = stats.snapshot()
-    delta = delta if any(delta.values()) else None
+    delta = {key: value for key, value in stats.snapshot().items() if value}
+    delta["span"] = dict(span.as_dict(), thread=-os.getpid())
     if parsed is None:
         return ("dropped", delta)
     # Ship plain (key, value) pairs; the parent rebuilds the struct (cheap)
@@ -868,31 +889,44 @@ class RecordDataset:
         roi_rng = (
             np.random.default_rng(self._seed) if self._decode_roi else None
         )
-        while True:
-            chunk = list(itertools.islice(stream, self._batch_size))
-            if not chunk:
+        # Each payload travels with the ordinal of its batch (0, 1, ... of
+        # this iterator), which its `data.*` spans carry.
+        for ordinal in itertools.count():
+            with tracing.span("data.read_chunk", ordinal=ordinal) as span:
+                chunk = list(itertools.islice(stream, self._batch_size))
+                whole = bool(chunk) and (
+                    len(chunk) == self._batch_size or not self._drop_remainder
+                )
+                payload = chunk
+                if whole and self._decode_roi is not None:
+                    # Offsets resolve HERE, once per chunk, in the parent:
+                    # every consumer of this payload (thread worker, process
+                    # worker, oracle fallback after a fast-path failure)
+                    # crops with the same rects, so the batch is
+                    # reproducible across paths.
+                    payload = (
+                        "roi",
+                        chunk,
+                        resolve_decode_rois(
+                            self._decode_roi, self._specs, len(chunk), roi_rng
+                        ),
+                    )
+                span.add(
+                    records=len(chunk),
+                    bytes=sum(
+                        sum(map(len, row.values())) if isinstance(row, dict)
+                        else len(row)
+                        for row in chunk
+                    ),
+                )
+            if not whole:
                 return
-            if len(chunk) < self._batch_size and self._drop_remainder:
-                return
-            if self._decode_roi is None:
-                yield chunk
-                continue
-            # Offsets resolve HERE, once per chunk, in the parent: every
-            # consumer of this payload (thread worker, process worker,
-            # oracle fallback after a fast-path failure) crops with the
-            # same rects, so the batch is reproducible across paths.
-            yield (
-                "roi",
-                chunk,
-                resolve_decode_rois(
-                    self._decode_roi, self._specs, len(chunk), roi_rng
-                ),
-            )
+            yield ordinal, payload
 
-    def _parse_chunk(self, chunk) -> Optional[TensorSpecStruct]:
-        return _parse_chunk_impl(
-            self._fast_state, self._parser, chunk, self._parse_stats
-        )
+    def _parse_chunk(self, item) -> Optional[TensorSpecStruct]:
+        return _traced_parse(
+            self._fast_state, self._parser, item, self._parse_stats
+        )[0]
 
     def _max_in_flight(self) -> int:
         return self._num_parse_workers + max(self._prefetch_depth, 1)
@@ -933,8 +967,10 @@ class RecordDataset:
     def _rebuild_struct(self, payload) -> Optional[TensorSpecStruct]:
         """Parent-side batch reassembly for the process-return forms
         (inline / shm / dropped), folding any worker-side skip counters
-        into this dataset's ParseStats."""
-        delta = payload[-1] if isinstance(payload[-1], dict) else None
+        into this dataset's ParseStats and the worker's
+        `data.parse_chunk` span into this process's recorder."""
+        delta = payload[-1]
+        tracing.adopt(delta.pop("span"))
         if delta:
             self._parse_stats.merge(delta)
         if payload[0] == "dropped":

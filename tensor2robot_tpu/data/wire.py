@@ -51,6 +51,7 @@ Wire layout recap (proto3, tensor2robot_tpu/proto/example.proto):
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
@@ -70,6 +71,7 @@ from tensor2robot_tpu.specs import (
     canonical_dtype,
     flatten_spec_structure,
 )
+from tensor2robot_tpu.utils import tracing
 
 __all__ = [
     "FastParseError",
@@ -629,6 +631,20 @@ class _CompiledField:
         cache: Optional[DecodeCache],
         rect: Optional[Tuple[int, int, int, int]] = None,
         randomized: bool = False,
+    ) -> None:
+        """One image into its slot, timed: `images` and `decode_ns` (cache
+        lookups and copies included) add up on the enclosing
+        `data.parse_chunk` span. Two clock reads an image, no span."""
+        started = time.perf_counter_ns()
+        try:
+            self._decode_one_image_untimed(
+                record, span, out_slice, cache, rect, randomized
+            )
+        finally:
+            tracing.add(images=1, decode_ns=time.perf_counter_ns() - started)
+
+    def _decode_one_image_untimed(
+        self, record, span, out_slice, cache, rect, randomized
     ) -> None:
         off, ln = span
         if ln == 0:

@@ -21,8 +21,6 @@ from tensor2robot_tpu.hooks.hook_builder import Hook, HookBuilder, HookContext
 from tensor2robot_tpu.hooks.profiling_hook_builder import (
     ProfilerHook,
     ProfilerHookBuilder,
-    StepTimingHook,
-    StepTimingHookBuilder,
 )
 from tensor2robot_tpu.hooks.td3 import TD3Hooks
 from tensor2robot_tpu.hooks.variable_logger_hook import (

@@ -1,30 +1,36 @@
-"""Profiling/tracing hooks: jax.profiler traces + per-step timing + MFU.
+"""Profiling hook: a jax.profiler trace of a window of steps, with the
+program's own spans of that window beside it.
 
 The reference had NO tracing subsystem (SURVEY §5: absent), but the rebuild
 targets an MFU north star, so observability of where step time goes is
-first-class here:
+first-class here. ProfilerHookBuilder captures the device's timeline
+(XPlane, viewable in TensorBoard or xprof) for the steps
+[start_step, start_step + num_steps) and, when the window closes, writes
+the host path's spans of that window (utils/tracing.py) as `spans.jsonl`
+in the directory of the `.xplane.pb`: one JSON object a line with `name`,
+`id`, `parent`, `thread`, `ordinal`, `start_ns`, `end_ns` (epoch
+nanoseconds) and `counts`. The xplane's `profile_start_time` is on the
+same clock, so the spans lie on the device's axis once it is subtracted.
 
-  * ProfilerHookBuilder — captures a jax.profiler trace (XPlane/perfetto,
-    viewable in TensorBoard or xprof) for a window of steps
-    [start_step, start_step + num_steps).
-  * StepTimingHookBuilder — wall-clock per-step timing with a device sync
-    every `sync_every` steps (async dispatch makes raw host timestamps
-    meaningless; a periodic blocking readback of the step's loss anchors
-    them), reporting steps/sec + optional MFU against the step's XLA FLOPs
-    estimate. Results land in a JSONL stream under model_dir/profiling/.
+The profiler's host and python tracers stay off: the host-side relayout of
+a fed uint8 batch alone writes millions of events a few seconds, hundreds
+of megabytes, and the tracing itself then stalls the transfer (PERF.md,
+PR 24). What the host did is in `spans.jsonl`.
 """
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import List, Optional
 
 import jax
 
 from tensor2robot_tpu.config import configurable
 from tensor2robot_tpu.hooks.hook_builder import Hook, HookBuilder, HookContext
+from tensor2robot_tpu.utils import tracing
 
 
 class ProfilerHook(Hook):
@@ -34,6 +40,8 @@ class ProfilerHook(Hook):
         self._stop = start_step + num_steps
         self._active = False
         self._done = False
+        self._trace_dir: Optional[str] = None
+        self._started_ns = 0
 
     def before_step(self, ctx: HookContext) -> None:
         # >= (not a range check): in the multi-step regime ctx.step advances
@@ -43,7 +51,12 @@ class ProfilerHook(Hook):
             if not os.path.isabs(log_dir) and ctx.model_dir:
                 log_dir = os.path.join(ctx.model_dir, log_dir)
             os.makedirs(log_dir, exist_ok=True)
-            jax.profiler.start_trace(log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.host_tracer_level = 0
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(log_dir, profiler_options=options)
+            self._trace_dir = log_dir
+            self._started_ns = time.time_ns()
             self._active = True
 
     def after_step(self, ctx: HookContext) -> None:
@@ -57,6 +70,16 @@ class ProfilerHook(Hook):
         jax.profiler.stop_trace()
         self._active = False
         self._done = True
+        # Beside the newest xplane (jax writes plugins/profile/<time>/), or
+        # in the trace directory itself where the profiler left none.
+        traces = sorted(glob.glob(
+            os.path.join(self._trace_dir, "**", "*.xplane.pb"), recursive=True
+        ))
+        out_dir = os.path.dirname(traces[-1]) if traces else self._trace_dir
+        spans = tracing.snapshot(since_ns=self._started_ns)["spans"]
+        with open(os.path.join(out_dir, "spans.jsonl"), "w") as f:
+            for span in spans:
+                f.write(json.dumps(span) + "\n")
 
     def on_train_end(self, ctx: HookContext) -> None:
         if self._active:
@@ -82,91 +105,3 @@ class ProfilerHookBuilder(HookBuilder):
         del t2r_model, trainer
         log_dir = self._log_dir or "profiling"
         return [ProfilerHook(log_dir, self._start_step, self._num_steps)]
-
-
-class StepTimingHook(Hook):
-    def __init__(
-        self,
-        sync_every: int,
-        flops_per_step: Optional[float],
-        peak_flops: Optional[float],
-        output_path: Optional[str],
-    ):
-        self._sync_every = sync_every
-        self._flops = flops_per_step
-        self._peak = peak_flops
-        self._path = output_path
-        self._t_anchor: Optional[float] = None
-        self._anchor_step: Optional[int] = None
-        self._rows: List[Dict[str, Any]] = []
-
-    def after_step(self, ctx: HookContext) -> None:
-        # Steps-since-anchor gate (not step % N == 0): multi-step dispatch
-        # advances ctx.step by iterations_per_loop, which may never hit an
-        # exact multiple of sync_every.
-        if (
-            self._anchor_step is not None
-            and ctx.step - self._anchor_step < self._sync_every
-        ):
-            return
-        # Anchor the clock with a real device sync: the loop dispatches
-        # asynchronously, so only a blocking readback marks completed work.
-        if ctx.device_metrics is not None:
-            jax.block_until_ready(ctx.device_metrics)
-            loss = ctx.device_metrics.get("loss")
-            if loss is not None:
-                float(jax.device_get(loss))
-        now = time.perf_counter()
-        if self._t_anchor is not None and ctx.step > self._anchor_step:
-            steps = ctx.step - self._anchor_step
-            steps_per_sec = steps / max(now - self._t_anchor, 1e-9)
-            row: Dict[str, Any] = {
-                "step": ctx.step,
-                "steps_per_sec": round(steps_per_sec, 4),
-            }
-            if self._flops:
-                row["model_flops_per_sec"] = self._flops * steps_per_sec
-                if self._peak:
-                    row["mfu"] = round(
-                        self._flops * steps_per_sec / self._peak, 5
-                    )
-            self._rows.append(row)
-            if self._path is not None:
-                path = self._path
-                if not os.path.isabs(path) and ctx.model_dir:
-                    path = os.path.join(ctx.model_dir, path)
-                os.makedirs(os.path.dirname(path), exist_ok=True)
-                with open(path, "a") as f:
-                    f.write(json.dumps(row) + "\n")
-        self._t_anchor = now
-        self._anchor_step = ctx.step
-
-    @property
-    def rows(self) -> List[Dict[str, Any]]:
-        return self._rows
-
-
-@configurable("StepTimingHookBuilder")
-class StepTimingHookBuilder(HookBuilder):
-    """Synced steps/sec (+MFU when FLOPs known) into
-    model_dir/profiling/step_timing.jsonl."""
-
-    def __init__(
-        self,
-        sync_every: int = 50,
-        flops_per_step: Optional[float] = None,
-        peak_flops: Optional[float] = None,
-        output_path: Optional[str] = "profiling/step_timing.jsonl",
-    ):
-        self._sync_every = sync_every
-        self._flops = flops_per_step
-        self._peak = peak_flops
-        self._output_path = output_path
-        self.hook: Optional[StepTimingHook] = None
-
-    def create_hooks(self, t2r_model, trainer=None) -> List[Hook]:
-        del t2r_model, trainer
-        self.hook = StepTimingHook(
-            self._sync_every, self._flops, self._peak, self._output_path
-        )
-        return [self.hook]

@@ -17,6 +17,7 @@ equivalents here:
 from __future__ import annotations
 
 import collections
+import itertools
 from typing import Callable, Iterator, Optional, Sequence
 
 import jax
@@ -24,6 +25,7 @@ import numpy as np
 
 from tensor2robot_tpu import flags
 from tensor2robot_tpu.parallel import mesh as mesh_lib
+from tensor2robot_tpu.utils import tracing
 
 
 def resolve_depth(depth: Optional[int] = None) -> int:
@@ -34,31 +36,63 @@ def resolve_depth(depth: Optional[int] = None) -> int:
     return flags.get_int("T2R_INFEED_DEPTH")
 
 
+def _tree_bytes(tree) -> int:
+    return sum(
+        getattr(leaf, "nbytes", 0) for leaf in jax.tree_util.tree_leaves(tree)
+    )
+
+
 def device_prefetch(
     batches: Iterator,
     shard_fn: Callable,
     depth: int = 2,
+    ordinals: Optional[Iterator[int]] = None,
+    name: str = "infeed",
 ) -> Iterator:
     """Yields device-resident batches, keeping `depth` transfers in flight.
 
     `shard_fn` is typically CompiledModel.shard_batch. With depth=2 the
     transfer of batch N+1 is enqueued before the consumer dispatches step N;
     because device_put is async the copy runs while the device computes.
+
+    Each item is fetched under a `<name>.wait` span (the consumer waits for
+    the host pipeline) and placed under a `<name>.h2d` span that counts the
+    `bytes` handed to `shard_fn`. `ordinals` gives each item's ordinal
+    (default 0, 1, ...): the train loop passes its global step, which is
+    the ordinal the dataset gave the batch.
     """
     buf: collections.deque = collections.deque()
     it = iter(batches)
-    try:
-        while len(buf) < depth:
-            buf.append(shard_fn(next(it)))
-    except StopIteration:
+    ordinals = itertools.count() if ordinals is None else ordinals
+
+    exhausted = False
+
+    def fetch() -> bool:
+        nonlocal exhausted
+        if exhausted:
+            return False
+        ordinal = next(ordinals, None)
+        try:
+            with tracing.span(name + ".wait", ordinal=ordinal):
+                item = next(it)
+        except StopIteration:
+            exhausted = True
+            return False
+        with tracing.span(name + ".h2d", ordinal=ordinal, bytes=_tree_bytes(item)):
+            buf.append(shard_fn(item))
+            # Letting go of the host batch (unmapping a batch's worth of
+            # pages, where the transfer is through with it) is part of
+            # handing it over: inside the span, not at this frame's end.
+            del item
+        return True
+
+    while len(buf) < depth and fetch():
         pass
     while buf:
-        out = buf.popleft()
-        try:
-            buf.append(shard_fn(next(it)))
-        except StopIteration:
-            pass
-        yield out
+        fetch()
+        # Straight from the buffer: a local here would keep the batch
+        # alive, and its device memory held, until the consumer comes back.
+        yield buf.popleft()
 
 
 def stack_batches(batches: Sequence) -> object:

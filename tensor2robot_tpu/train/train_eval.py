@@ -58,6 +58,7 @@ from tensor2robot_tpu.train.metrics import (
     collective_record,
 )
 from tensor2robot_tpu.train.state import TrainState, create_train_state, update_ema
+from tensor2robot_tpu.utils import tracing
 
 
 #: Metric-key prefixes whose values carry a leading batch dimension
@@ -1008,19 +1009,27 @@ class CompiledModel:
         self.predict_step_fn = predict_step
 
     def init_state(self, rng: jax.Array, example_batch) -> TrainState:
+        # Host time of the call (hundreds of small eager programs traced,
+        # compiled or loaded, and enqueued), not the device's.
+        with tracing.span("train.init_state"):
+            return self._init_state(rng, example_batch)
+
+    def _init_state(self, rng: jax.Array, example_batch) -> TrainState:
         # The model initializes at its own (post-preprocess) contract: run the
         # preprocessor on the example batch outside jit once, in TRAIN mode so
         # init shapes match exactly what train_step will feed the network.
-        features, _ = self.preprocessor.preprocess(
-            example_batch["features"],
-            _batch_labels(example_batch),
-            mode=MODE_TRAIN,
-            rng=jax.random.PRNGKey(0),
-        )
-        state = create_train_state(
-            self.model, rng, features, self.optimizer,
-            flat_ema=self._flat_ema,
-        )
+        with tracing.span("train.init_state.preprocess"):
+            features, _ = self.preprocessor.preprocess(
+                example_batch["features"],
+                _batch_labels(example_batch),
+                mode=MODE_TRAIN,
+                rng=jax.random.PRNGKey(0),
+            )
+        with tracing.span("train.init_state.model_init"):
+            state = create_train_state(
+                self.model, rng, features, self.optimizer,
+                flat_ema=self._flat_ema,
+            )
         if self._fuse_stats:
             stats = state.variables.get("batch_stats")
             if isinstance(stats, dict) and stats:
@@ -1401,7 +1410,8 @@ def evaluate(
     count = 0
     deferred = DeferredFetch()
     for batch in infeed.device_prefetch(
-        eval_batches, compiled.shard_batch, depth=infeed.resolve_depth()
+        eval_batches, compiled.shard_batch, depth=infeed.resolve_depth(),
+        name="eval_infeed",
     ):
         metrics = compiled.eval_step(state, batch, use_ema)
         # On-device f32 accumulation through the locked jitted helpers:
@@ -1427,6 +1437,33 @@ def evaluate(
 
 
 # -- the entry point ----------------------------------------------------------
+
+
+def _host_path_record(before, after, steps: int) -> Dict[str, float]:
+    """Where the host's time went over one log interval, from two reads of
+    the recorder's cumulative counters (utils/tracing.py): milliseconds a
+    step the train thread waited for a batch, placed it and dispatched;
+    milliseconds one parse worker took for one batch; the share of batches
+    for which the dataset's prefetch queue was empty when asked; and the
+    milliseconds the loop stood in `checkpoint_and_eval`."""
+
+    def delta(key):
+        return after.get(key, 0) - before.get(key, 0)
+
+    steps = max(steps, 1)
+    return {
+        "infeed/wait_ms_per_step": delta("infeed.wait.ns") / 1e6 / steps,
+        "infeed/h2d_ms_per_step": delta("infeed.h2d.ns") / 1e6 / steps,
+        "dispatch_ms_per_step": delta("train.dispatch.ns") / 1e6 / steps,
+        "input/parse_ms_per_batch": (
+            delta("data.parse_chunk.ns") / 1e6
+            / max(delta("data.parse_chunk.n"), 1)
+        ),
+        "input/prefetch_empty_share": (
+            delta("data.prefetch_empty") / max(delta("data.prefetch_gets"), 1)
+        ),
+        "checkpoint/stall_ms": delta("train.checkpoint.ns") / 1e6,
+    }
 
 
 def train_eval_model(
@@ -1604,22 +1641,30 @@ def train_eval_model(
     # steps_per_sec. Empty dict everywhere else.
     collective_info = compiled.collective_log_record()
 
+    host_counters = tracing.counters()
+
     def log_metrics(step: int, metrics) -> Dict[str, float]:
-        nonlocal t_last, last_log_step
-        host_metrics = {
-            key: float(value)
-            for key, value in jax.device_get(metrics).items()
-            if getattr(value, "ndim", 0) == 0
-        }
-        now = time.time()
-        host_metrics["steps_per_sec"] = (
-            (step - last_log_step) / max(now - t_last, 1e-9)
-        )
-        host_metrics.update(collective_info)
-        t_last = now
-        last_log_step = step
-        writer.write(step, host_metrics)
-        return host_metrics
+        nonlocal t_last, last_log_step, host_counters
+        with tracing.span("train.log", ordinal=step - 1):
+            host_metrics = {
+                key: float(value)
+                for key, value in jax.device_get(metrics).items()
+                if getattr(value, "ndim", 0) == 0
+            }
+            now = time.time()
+            host_metrics["steps_per_sec"] = (
+                (step - last_log_step) / max(now - t_last, 1e-9)
+            )
+            host_metrics.update(collective_info)
+            counters = tracing.counters()
+            host_metrics.update(
+                _host_path_record(host_counters, counters, step - last_log_step)
+            )
+            host_counters = counters
+            t_last = now
+            last_log_step = step
+            writer.write(step, host_metrics)
+            return host_metrics
 
     # after_checkpoint_saved's contract is a DURABLE on-disk checkpoint
     # (backup/eval hooks read ctx.checkpoint_path); only when such a hook
@@ -1634,47 +1679,62 @@ def train_eval_model(
 
     def checkpoint_and_eval(state, step: int) -> Dict[str, float]:
         nonlocal last_saved_step
-        # Fused-stats states persist (and face hooks/exporters/eval) in
-        # the canonical tree layout — the on-disk format never changes.
-        state = compiled.persistable_state(state)
-        previous_saved = last_saved_step
-        # Async save: orbax snapshots device arrays to host memory before
-        # returning, then writes in the background — the next scan window
-        # dispatches immediately instead of stalling on serialization.
-        manager.save(step, args=ocp.args.StandardSave(state), force=True)
-        # Issuing this save was the commit barrier for the PREVIOUS one
-        # (orbax serializes saves): publish its durability manifest.
-        # No-op when no prior save exists (previous_saved is start_step
-        # on the first call; publish_durable ignores absent dirs).
-        durability.publish_durable(model_dir, previous_saved)
-        # Chaos site: the async write for `step` is now in flight — a
-        # `kill` clause here is the SIGKILL-mid-orbax-save fault the
-        # crash-consistency suite injects. (After the previous step's
-        # blessing: a crash mid-save must not cost the durable past.)
-        chaos.maybe_fire("save")
-        last_saved_step = step
-        ctx.checkpoint_path = str(
-            os.path.join(model_dir, "checkpoints", str(step))
-        )
-        if ckpt_hooks_present:
-            manager.wait_until_finished()
-            durability.publish_durable(model_dir, step)
-        for hook in hooks:
-            hook.after_checkpoint_saved(ctx)
-        return run_eval_and_export(state, step)
+        # The loop stands still for all of this: the snapshot of the state
+        # to host memory, hooks, evals and exporters.
+        with tracing.span("train.checkpoint", ordinal=step - 1):
+            # Fused-stats states persist (and face hooks/exporters/eval) in
+            # the canonical tree layout — the on-disk format never changes.
+            state = compiled.persistable_state(state)
+            previous_saved = last_saved_step
+            # Async save: orbax snapshots device arrays to host memory before
+            # returning, then writes in the background — the next scan window
+            # dispatches immediately instead of stalling on serialization.
+            manager.save(step, args=ocp.args.StandardSave(state), force=True)
+            # Issuing this save was the commit barrier for the PREVIOUS one
+            # (orbax serializes saves): publish its durability manifest.
+            # No-op when no prior save exists (previous_saved is start_step
+            # on the first call; publish_durable ignores absent dirs).
+            durability.publish_durable(model_dir, previous_saved)
+            # Chaos site: the async write for `step` is now in flight — a
+            # `kill` clause here is the SIGKILL-mid-orbax-save fault the
+            # crash-consistency suite injects. (After the previous step's
+            # blessing: a crash mid-save must not cost the durable past.)
+            chaos.maybe_fire("save")
+            last_saved_step = step
+            ctx.checkpoint_path = str(
+                os.path.join(model_dir, "checkpoints", str(step))
+            )
+            if ckpt_hooks_present:
+                manager.wait_until_finished()
+                durability.publish_durable(model_dir, step)
+            for hook in hooks:
+                hook.after_checkpoint_saved(ctx)
+            return run_eval_and_export(state, step)
 
     try:
         if iterations_per_loop <= 1:
+            # The global step is the ordinal the dataset gave the batch it
+            # consumes (the resume contract above), so one batch's spans
+            # share it from `data.read_chunk` to `train.dispatch`. The loop
+            # body is spans end to end: a device idle gap always falls
+            # under a named one.
             device_batches = infeed.device_prefetch(
-                host_batches, compiled.shard_batch, depth=infeed_depth
+                host_batches, compiled.shard_batch, depth=infeed_depth,
+                ordinals=itertools.count(step),
             )
             for batch in device_batches:
                 if step >= max_train_steps:
                     break
                 ctx.step = step
-                for hook in hooks:
-                    hook.before_step(ctx)
-                state, metrics = compiled.train_step(state, batch, rng_train)
+                with tracing.span("train.hooks", ordinal=step):
+                    for hook in hooks:
+                        hook.before_step(ctx)
+                with tracing.span("train.dispatch", ordinal=step):
+                    state, metrics = compiled.train_step(state, batch, rng_train)
+                    # The enqueued step holds the batch from here. The
+                    # loop's reference goes now, so that freeing it falls
+                    # under this span and not between two.
+                    del batch
                 step += 1
                 ctx.step = step
                 ctx.state = state
@@ -1685,8 +1745,9 @@ def train_eval_model(
                     ctx.metrics = log_metrics(step, metrics)
                 else:
                     ctx.metrics = None
-                for hook in hooks:
-                    hook.after_step(ctx)
+                with tracing.span("train.hooks", ordinal=step - 1):
+                    for hook in hooks:
+                        hook.after_step(ctx)
                 if step % save_checkpoints_steps == 0 or step == max_train_steps:
                     final_eval = checkpoint_and_eval(state, step)
         else:
@@ -1703,39 +1764,52 @@ def train_eval_model(
                     yield k
                     s += k
 
-            def stacked_chunks():
+            def host_chunks():
                 for k in chunk_sizes():
                     chunk = list(itertools.islice(host_batches, k))
                     if len(chunk) < k:
                         return  # host data exhausted
-                    yield infeed.stack_batches(chunk)
+                    yield chunk
 
+            # `infeed.wait` covers a chunk's k host batches, `infeed.h2d`
+            # its stacking and placement; a chunk's ordinal is that of its
+            # first batch.
             device_chunks = infeed.device_prefetch(
-                stacked_chunks(),
-                lambda s: infeed.shard_stacked_batch(s, compiled.mesh),
+                host_chunks(),
+                lambda chunk: infeed.shard_stacked_batch(
+                    infeed.stack_batches(chunk), compiled.mesh
+                ),
                 depth=infeed_depth,
+                ordinals=itertools.accumulate(
+                    chunk_sizes(), initial=step
+                ),
             )
             for device_chunk in device_chunks:
                 k = int(jax.tree_util.tree_leaves(device_chunk)[0].shape[0])
                 ctx.step = step
-                for hook in hooks:
-                    hook.before_step(ctx)
-                state, stacked_metrics = compiled.train_scan(
-                    state, device_chunk, rng_train
-                )
+                with tracing.span("train.hooks", ordinal=step):
+                    for hook in hooks:
+                        hook.before_step(ctx)
+                with tracing.span("train.dispatch", ordinal=step):
+                    state, stacked_metrics = compiled.train_scan(
+                        state, device_chunk, rng_train
+                    )
+                    # Hooks observe loop granularity: the final step's
+                    # metrics (one small eager program a leaf).
+                    ctx.device_metrics = jax.tree_util.tree_map(
+                        lambda leaf: leaf[-1], stacked_metrics
+                    )
+                    del device_chunk  # as in the single-step loop
                 step += k
                 ctx.step = step
                 ctx.state = state
-                # Hooks observe loop granularity: the final step's metrics.
-                ctx.device_metrics = jax.tree_util.tree_map(
-                    lambda leaf: leaf[-1], stacked_metrics
-                )
                 if step % log_every_steps < k or step == max_train_steps:
                     ctx.metrics = log_metrics(step, ctx.device_metrics)
                 else:
                     ctx.metrics = None
-                for hook in hooks:
-                    hook.after_step(ctx)
+                with tracing.span("train.hooks", ordinal=step - 1):
+                    for hook in hooks:
+                        hook.after_step(ctx)
                 if step % save_checkpoints_steps == 0 or step == max_train_steps:
                     final_eval = checkpoint_and_eval(state, step)
                 if step >= max_train_steps:
