@@ -19,14 +19,17 @@ Pipeline semantics preserved from the reference:
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
+import functools
 import itertools
 import logging
 import os
 import queue
 import random
+import sys
 import threading
-from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional, Sequence, Union
+from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -158,31 +161,60 @@ class _Prefetcher:
         return item
 
 
+#: Cores the pool leaves to the program's other busy threads: the reader
+#: (a third to a half of a core of Python a batch), the train thread and
+#: the runtime's host-to-device relayout threads, which work in bursts.
+_RESERVED_CORES = 1
+
+#: Fewest records a slice job takes: below it a job's fixed cost (a future,
+#: a span, a thread's wake-up) starts to show against the decode.
+_MIN_SLICE_RECORDS = 16
+
+
+def _usable_cores() -> int:
+    """Cores this process may run on: its affinity mask, which a
+    container's cpuset narrows where `os.cpu_count()` counts the machine."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # no affinity call on this platform
+        return os.cpu_count() or 1
+
+
 def default_parse_workers() -> int:
-    """Default parse parallelism: one worker per core, capped.
+    """Default width of the parse pool: the cores this process may run on
+    less `_RESERVED_CORES`, at least 1.
 
     The AUTOTUNE analogue for the parse/decode stage (reference
-    utils/tfdata.py:630-689 used num_parallel_calls=AUTOTUNE). Overridable
-    via T2R_PARSE_WORKERS; 0 disables the pool (synchronous parse).
+    utils/tfdata.py:630-689 used num_parallel_calls=AUTOTUNE). A batch is
+    parsed in slices (`_slice_bounds`), so the pool is never wider than a
+    few batches can occupy, whatever this returns. Overridable via
+    T2R_PARSE_WORKERS; 0 disables the pool (synchronous parse).
     """
     env = flags.get_optional_int("T2R_PARSE_WORKERS")
     if env is not None:
         return env
-    return min(8, os.cpu_count() or 1)
+    return max(1, _usable_cores() - _RESERVED_CORES)
+
+
+def _slice_bounds(records: int, num_workers: int) -> List[Tuple[int, int]]:
+    """[a, b) of each slice job of a batch of `records`: one slice a worker,
+    none shorter than `_MIN_SLICE_RECORDS` but the last."""
+    length = max(_MIN_SLICE_RECORDS, -(-records // max(num_workers, 1)))
+    return [(a, min(a + length, records)) for a in range(0, records, length)]
 
 
 def default_parse_backend() -> str:
     """'thread' (default) or 'process' (T2R_PARSE_BACKEND).
 
-    Threads suffice while the pool is small: the hot ops release the GIL
-    (PIL jpeg decode, the TFRecord codec — measured in
-    tools/measure_gil_release.py), but each parse still holds the GIL for
-    its python/numpy glue (~1/3 of its runtime on this image), so thread
-    scaling saturates around 3-4 workers. The process backend sidesteps
-    the GIL entirely for many-core hosts feeding a fast chip: workers
-    re-parse in forked/spawned interpreters and ship back parsed numpy
-    batches (raw jpeg chunks are cheap to send; the returned uint8 image
-    batch is the dominant IPC cost).
+    Threads carry the pool as wide as the host's cores: a record's parse
+    is some 3% python (the protobuf scan, the scalars) and 97% native jpeg
+    decode and TFRecord codec, both of which release the GIL
+    (tools/measure_gil_release.py), and a batch's slices decode straight
+    into one shared set of arrays. The process backend takes the GIL out
+    of the parse altogether, at the price of whole-batch jobs: workers
+    re-parse in spawned interpreters and ship back parsed numpy batches
+    (raw jpeg chunks are cheap to send; the returned uint8 image batch is
+    the dominant IPC cost, which the shared-memory ring carries).
     """
     return flags.get_enum("T2R_PARSE_BACKEND")
 
@@ -448,14 +480,29 @@ def _parse_chunk_impl(
 
 def _traced_parse(fast_state, parser, item, stats):
     """`_parse_chunk_impl` on one `(ordinal, payload)` of `_chunks()` under
-    its `data.parse_chunk` span: one worker's whole work on one batch. The
-    decoder adds `images` and `decode_ns` to it (data/wire.py). Returns
-    (parsed, span)."""
+    a `data.parse_chunk` span: one worker's work on one whole batch (the
+    job of a batch that is not parsed in slices, and the fallback of one
+    whose slice failed). The decoder adds `images` and `decode_ns` to it
+    (data/wire.py). Returns (parsed, span)."""
     ordinal, payload = item
     records = len(_split_payload(payload)[0])
     with tracing.span("data.parse_chunk", ordinal=ordinal, records=records) as span:
         parsed = _parse_chunk_impl(fast_state, parser, payload, stats)
     return parsed, span
+
+
+def _parse_slice(fast, ordinal, first_row, records, arrays, roi) -> List[str]:
+    """One slice job: `records`, which are records `first_row`,
+    `first_row + 1`, ... of batch `ordinal`, parsed and decoded straight
+    into those rows of the batch's `arrays`, under a `data.parse_chunk`
+    span of its own. Returns the optional keys the slice lacks."""
+    with tracing.span(
+        "data.parse_chunk", ordinal=ordinal, records=len(records),
+        first_row=first_row,
+    ):
+        return fast.parse_rows(
+            _regroup_chunk(records), arrays, first_row, roi=roi
+        )
 
 
 def _shm_attach(name: str):
@@ -629,20 +676,86 @@ class _ShmBatchRing:
         self.slots = {}
 
 
-class _ParallelBatcher:
-    """Ordered parallel parse: N batches in flight across a worker pool.
+class _BatchArrays:
+    """Gives each batch its arrays, the arrays of a batch nobody holds any
+    more before fresh ones.
 
-    Record chunks are submitted to an Executor and results are yielded in
-    submission order, keeping up to `max_in_flight` parse jobs running
-    ahead of the consumer. Default pool: a ThreadPoolExecutor — parsing is
-    dominated by jpeg decode (PIL releases the GIL in its decoder) and
-    numpy copies, so a few threads scale without pickling batches across
-    processes. Callers may pass any Executor instead (the process backend
-    passes a ProcessPoolExecutor, which DOES pickle chunks out and parsed
-    batches back); an externally-passed pool is the caller's to shut down
-    (reused across epochs). This is the rebuild of tf.data's parallel
-    parse/decode maps (reference utils/tfdata.py:630-689,
-    num_parallel_calls=AUTOTUNE).
+    A batch of 256 decoded 472x472 frames is 171 MB. Fresh from the
+    allocator it is an anonymous mapping of its own: every page of it
+    faults in at its first write, under whichever thread of the pool
+    decodes into it, and the mapping goes back to the kernel when the
+    batch is dropped, which interrupts every core the process runs on. On
+    the v5e's 13-core host, twelve threads faulting into one address space
+    made an image cost its worker 6 ms where the decode takes 1.5 ms, and
+    the train thread's `device_put` 42 ms where it takes 12 (PERF.md,
+    PR 27). Arrays that have been written once cost none of that.
+
+    `take` hands out views. A view of an array holds a reference
+    to it however it was derived (numpy points `.base` at the array that
+    owns the memory), and so does whoever reads its buffer (a host-to-
+    device transfer in flight), so a set whose arrays nobody but this
+    object refers to is free to be written again. At most `limit` sets are
+    kept; a consumer that holds on to more batches than that gets fresh
+    arrays, as it always did. One thread takes (the one that iterates the
+    dataset's batcher).
+    """
+
+    def __init__(self, limit: int):
+        self._limit = limit
+        self._sets: List[Dict[str, np.ndarray]] = []
+
+    @staticmethod
+    def _unreferenced(array: np.ndarray) -> bool:
+        # The set's dict, the caller's loop variable, this parameter and
+        # getrefcount's own argument.
+        return sys.getrefcount(array) == 4
+
+    def take(
+        self, allocate: Callable, n: int, roi=None
+    ) -> Optional[Dict[str, np.ndarray]]:
+        """Views of a free set of arrays for a batch of `n` records, else
+        of what `allocate(n, roi)` gives (None where it gives None)."""
+        arrays = next(
+            (
+                held for held in self._sets
+                if all(
+                    len(array) == n and self._unreferenced(array)
+                    for array in held.values()
+                )
+            ),
+            None,
+        )
+        if arrays is None:
+            arrays = allocate(n, roi)
+            if arrays is None:
+                return None
+            if len(self._sets) < self._limit:
+                self._sets.append(arrays)
+        return {key: array[:] for key, array in arrays.items()}
+
+
+class _ParallelBatcher:
+    """Ordered parallel parse over a worker pool.
+
+    Each `(ordinal, payload)` of `chunks` becomes the jobs of one batch:
+    the slice jobs `slice_fn(item)` gives, with what joins their results
+    into the batch, or, where it gives None (or there is no `slice_fn`),
+    the one job `parse_fn(item)`. Up to `max_in_flight` batches are
+    submitted ahead of the consumer; the pool takes their jobs in
+    submission order, so its workers finish the oldest batch together and
+    go on into the next without a gap, and batches are yielded in
+    submission order, each once its last job has closed. A batch whose
+    slice raised (or whose slices disagree) goes through `parse_fn` whole:
+    the oracle fallback and the skip-mode triage see a batch, as ever.
+
+    Default pool: a ThreadPoolExecutor — parsing is jpeg decode in native
+    code that releases the GIL, into arrays the slices share, so threads
+    scale with the cores and nothing is pickled. Callers may pass any
+    Executor instead (the process backend passes a ProcessPoolExecutor,
+    which DOES pickle chunks out and parsed batches back, a whole batch a
+    job); an externally-passed pool is the caller's to shut down (reused
+    across epochs). This is the rebuild of tf.data's parallel parse/decode
+    maps (reference utils/tfdata.py:630-689, num_parallel_calls=AUTOTUNE).
     """
 
     def __init__(
@@ -653,14 +766,17 @@ class _ParallelBatcher:
         max_in_flight: Optional[int] = None,
         pool: Optional[concurrent.futures.Executor] = None,
         on_discard: Optional[Callable] = None,
+        slice_fn: Optional[Callable] = None,
     ):
         self._chunks = chunks
         self._parse_fn = parse_fn
+        self._slice_fn = slice_fn
         self._owns_pool = pool is None
         self._pool = pool or concurrent.futures.ThreadPoolExecutor(
             max_workers=num_workers, thread_name_prefix="t2r-parse"
         )
-        self._in_flight: "queue.Queue" = queue.Queue()
+        # (item, futures, join) of each batch, oldest first.
+        self._in_flight: "collections.deque" = collections.deque()
         self._max_in_flight = max_in_flight or num_workers + 2
         self._exhausted = False
         # Called with each completed-but-unconsumed result when iteration
@@ -670,24 +786,48 @@ class _ParallelBatcher:
 
     def _submit_one(self) -> bool:
         try:
-            chunk = next(self._chunks)
+            item = next(self._chunks)
         except StopIteration:
             self._exhausted = True
             return False
-        self._in_flight.put(self._pool.submit(self._parse_fn, chunk))
+        sliced = self._slice_fn(item) if self._slice_fn is not None else None
+        if sliced is None:
+            futures, join = [self._pool.submit(self._parse_fn, item)], None
+        else:
+            jobs, join = sliced
+            futures = [self._pool.submit(job) for job in jobs]
+        self._in_flight.append((item, futures, join))
         return True
+
+    def _result(self, item, futures, join):
+        if join is None:
+            return futures[0].result()
+        try:
+            return join([future.result() for future in futures])
+        except Exception:
+            # Let the other slices end (they write into arrays nobody will
+            # read), then parse the batch whole.
+            concurrent.futures.wait(futures)
+            return self._pool.submit(self._parse_fn, item).result()
 
     def __iter__(self):
         try:
-            while not self._exhausted and self._in_flight.qsize() < self._max_in_flight:
+            while not self._exhausted and len(self._in_flight) < self._max_in_flight:
                 self._submit_one()
-            while not self._in_flight.empty():
-                future = self._in_flight.get()
+            while self._in_flight:
+                item, futures, join = self._in_flight.popleft()
                 if not self._exhausted:
                     self._submit_one()
-                yield future.result()
+                result = self._result(item, futures, join)
+                # The join holds the batch's arrays; nothing of this batch
+                # stays behind in this frame while the consumer has it.
+                del item, futures, join
+                yield result
+                del result
         finally:
             if self._owns_pool:
+                # Queued jobs are dropped; a running one ends in its own
+                # time and its thread with it.
                 self._pool.shutdown(wait=False, cancel_futures=True)
             else:
                 # External pool (reused across epochs): cancel what we
@@ -695,16 +835,26 @@ class _ParallelBatcher:
                 # Futures past cancellation (running or done) are drained
                 # so their results' resources (shm slots) are released
                 # instead of leaking with the discarded future.
-                while not self._in_flight.empty():
-                    future = self._in_flight.get()
-                    if future.cancel():
-                        continue
-                    try:
-                        result = future.result()
-                    except Exception:
-                        continue
-                    if self._on_discard is not None:
-                        self._on_discard(result)
+                for _, futures, join in self._in_flight:
+                    for future in futures:
+                        if future.cancel():
+                            continue
+                        try:
+                            result = future.result()
+                        except Exception:
+                            continue
+                        if join is None and self._on_discard is not None:
+                            self._on_discard(result)
+                self._in_flight.clear()
+
+
+def _delivered(batches: Iterator) -> Iterator[TensorSpecStruct]:
+    """Skip-mode whole-batch drops surface as None and stop here: every
+    batch that goes on is real, and counted (`data.parse_batches`)."""
+    for batch in batches:
+        if batch is not None:
+            tracing.count("data.parse_batches")
+            yield batch
 
 
 class RecordDataset:
@@ -928,8 +1078,57 @@ class RecordDataset:
             self._fast_state, self._parser, item, self._parse_stats
         )[0]
 
+    def _slices_per_batch(self) -> int:
+        """Jobs the pool gets for one batch: its slices where a batch can
+        be parsed in slices, else 1. Slices need the threads' shared
+        memory, the fast parser (the oracle stacks whole batches) and
+        shapes known before a record is read (a sequence field pads to its
+        batch's longest record)."""
+        fast = self._fast_state.parser
+        if (
+            self._parse_backend != "thread"
+            or fast is None
+            or not fast.static_shapes
+        ):
+            return 1
+        return len(_slice_bounds(self._batch_size, self._num_parse_workers))
+
     def _max_in_flight(self) -> int:
-        return self._num_parse_workers + max(self._prefetch_depth, 1)
+        """Batches the pool works ahead of the consumer: as many as give
+        every worker a job, and `prefetch_depth` behind them."""
+        working = -(-self._num_parse_workers // self._slices_per_batch())
+        return working + max(self._prefetch_depth, 1)
+
+    def _slice_jobs(self, buffers: _BatchArrays, item):
+        """`_ParallelBatcher`'s `slice_fn`: the batch's arrays taken once
+        (from `buffers`, which an iterator owns), one job a slice that
+        fills its rows of them, and the join that makes the batch of the
+        jobs' results. None where the batch has to be parsed whole
+        (`_slices_per_batch`)."""
+        fast = self._fast_state.parser
+        if fast is None:
+            return None
+        ordinal, payload = item
+        chunk, roi = _split_payload(payload)
+        try:
+            arrays = buffers.take(fast.allocate_batch, len(chunk), roi)
+        except Exception:
+            return None  # the whole-batch job raises it where it belongs
+        if arrays is None:
+            return None
+        jobs = [
+            functools.partial(
+                _parse_slice, fast, ordinal, a, chunk[a:b], arrays, roi
+            )
+            for a, b in _slice_bounds(len(chunk), self._num_parse_workers)
+        ]
+
+        def join(absent):
+            batch = fast.finish_batch(arrays, absent)
+            tracing.count("data.parse_batches_sliced")
+            return batch
+
+        return jobs, join
 
     def _maybe_seed_ring(self, entries) -> None:
         """Creates the shm ring the first time a (large) batch comes back
@@ -1071,6 +1270,7 @@ class RecordDataset:
         out = self._parse_stats.snapshot()
         fast = self._fast_state.parser
         out["fast_fallbacks"] += fast.fallbacks if fast is not None else 0
+        out["parse_workers"] = self._num_parse_workers
         return out
 
     def __iter__(self) -> Iterator[TensorSpecStruct]:
@@ -1087,19 +1287,23 @@ class RecordDataset:
                 ),
             )
         elif self._num_parse_workers > 0:
+            in_flight = self._max_in_flight()
+            # As many sets of arrays as batches can be on their way at
+            # once: in the pool, in the prefetch queue and before it, and
+            # the two or three the consumer places on the device.
+            buffers = _BatchArrays(in_flight + self._prefetch_depth + 4)
             batches = iter(
                 _ParallelBatcher(
                     self._chunks(),
                     self._parse_chunk,
                     num_workers=self._num_parse_workers,
-                    max_in_flight=self._max_in_flight(),
+                    max_in_flight=in_flight,
+                    slice_fn=functools.partial(self._slice_jobs, buffers),
                 )
             )
         else:
             batches = map(self._parse_chunk, self._chunks())
-        # Skip-mode whole-batch drops surface as None: filter them here
-        # so every consumer-visible batch is real.
-        batches = (batch for batch in batches if batch is not None)
+        batches = _delivered(batches)
         if self._prefetch_depth > 0:
             return iter(_Prefetcher(batches, self._prefetch_depth))
         return batches

@@ -827,6 +827,83 @@ class _CompiledGroup:
                 self.context_fields.append(field)
         self.is_sequence = bool(self.sequence_fields)
 
+    def allocate(
+        self, n: int, roi: Optional[Mapping[str, ResolvedROI]] = None
+    ) -> Dict[str, np.ndarray]:
+        """One array per context field for a batch of `n` records, its rows
+        still to be written (`fill_rows`). A context field's shape is known
+        before any record is read: from its spec, or from the resolved ROI
+        window of a cropped image field."""
+        arrays: Dict[str, np.ndarray] = {}
+        for field in self.context_fields:
+            shape, dtype = tuple(field.shape), field.parse_dtype
+            if field.is_image_field():
+                dtype = field.out_dtype
+                resolved = roi.get(field.key) if roi else None
+                if resolved is not None:
+                    if len(resolved.ys) != n:
+                        raise FastParseError(
+                            f"ResolvedROI for {field.key!r} has "
+                            f"{len(resolved.ys)} offsets, batch holds {n}"
+                        )
+                    shape = (resolved.height, resolved.width) + shape[2:]
+            arrays[field.key] = np.empty((n,) + shape, dtype=dtype)
+        return arrays
+
+    def fill_rows(
+        self,
+        records: Sequence[bytes],
+        scans: Sequence[Tuple[Dict, Dict]],
+        arrays: Mapping[str, np.ndarray],
+        start: int,
+        cache: Optional[DecodeCache],
+        roi: Optional[Mapping[str, ResolvedROI]] = None,
+    ) -> List[str]:
+        """Parses and decodes the context fields of `records`, which are
+        records `start`, `start + 1`, ... of their batch, straight into
+        those rows of `arrays` (from `allocate` at the batch's size; `roi`
+        holds the whole batch's offsets). Returns the keys of the optional
+        fields that none of `records` carries."""
+        absent: List[str] = []
+        for field in self.context_fields:
+            features = [scan[0].get(field.name_bytes) for scan in scans]
+            present = [f is not None for f in features]
+            if not all(present):
+                if field.optional and not any(present):
+                    absent.append(field.key)
+                    continue
+                if not field.optional:
+                    missing = present.index(False)
+                    raise KeyError(
+                        f"Required feature {field.spec.name or field.key!r} "
+                        f"missing from example {start + missing}"
+                    )
+                raise ValueError(
+                    f"Optional feature {field.key!r} present in only some "
+                    "batch elements; optional features must be all-present "
+                    "or all-absent within a batch."
+                )
+            batch = arrays[field.key]
+            if field.is_image_field():
+                resolved = roi.get(field.key) if roi else None
+                for i, feature in enumerate(features):
+                    row = start + i
+                    if resolved is None:
+                        field.fill_image(records[i], feature, batch[row], cache)
+                    else:
+                        field.fill_image(
+                            records[i], feature, batch[row], cache,
+                            rect=resolved.rect(row),
+                            randomized=resolved.randomized,
+                        )
+            else:
+                for i, feature in enumerate(features):
+                    field.fill_numeric(records[i], feature, batch, start + i)
+        return absent
+
+    def scan(self, records: Sequence[bytes]) -> List[Tuple[Dict, Dict]]:
+        return [scan_record(bytes(r), self.is_sequence) for r in records]
+
     def parse_into(
         self,
         records: Sequence[bytes],
@@ -835,60 +912,12 @@ class _CompiledGroup:
         roi: Optional[Mapping[str, ResolvedROI]] = None,
     ) -> None:
         n = len(records)
-        scans = [scan_record(bytes(r), self.is_sequence) for r in records]
-        for field in self.context_fields:
-            features = [scan[0].get(field.name_bytes) for scan in scans]
-            present = [f is not None for f in features]
-            if not all(present):
-                if field.optional and not any(present):
-                    continue
-                if not field.optional:
-                    missing = present.index(False)
-                    raise KeyError(
-                        f"Required feature {field.spec.name or field.key!r} "
-                        f"missing from example {missing}"
-                    )
-                raise ValueError(
-                    f"Optional feature {field.key!r} present in only some "
-                    "batch elements; optional features must be all-present "
-                    "or all-absent within a batch."
-                )
-            if field.is_image_field():
-                resolved = roi.get(field.key) if roi else None
-                if resolved is not None:
-                    if len(resolved.ys) != n:
-                        raise FastParseError(
-                            f"ResolvedROI for {field.key!r} has "
-                            f"{len(resolved.ys)} offsets, batch holds {n}"
-                        )
-                    batch = np.empty(
-                        (n, resolved.height, resolved.width)
-                        + tuple(field.shape[2:]),
-                        dtype=field.out_dtype,
-                    )
-                    for i in range(n):
-                        field.fill_image(
-                            records[i],
-                            features[i],
-                            batch[i],
-                            cache,
-                            rect=resolved.rect(i),
-                            randomized=resolved.randomized,
-                        )
-                    out[field.key] = batch
-                    continue
-                batch = np.empty(
-                    (n,) + tuple(field.shape), dtype=field.out_dtype
-                )
-                for i in range(n):
-                    field.fill_image(records[i], features[i], batch[i], cache)
-            else:
-                batch = np.empty(
-                    (n,) + tuple(field.shape), dtype=field.parse_dtype
-                )
-                for i in range(n):
-                    field.fill_numeric(records[i], features[i], batch, i)
-            out[field.key] = batch
+        scans = self.scan(records)
+        arrays = self.allocate(n, roi)
+        absent = self.fill_rows(records, scans, arrays, 0, cache, roi)
+        for key, batch in arrays.items():
+            if key not in absent:
+                out[key] = batch
         for field in self.sequence_fields:
             steps = [scan[1].get(field.name_bytes) for scan in scans]
             present = [s is not None for s in steps]
@@ -968,21 +997,11 @@ class FastSpecParser:
     def dataset_keys(self) -> Tuple[str, ...]:
         return tuple(self._groups.keys())
 
-    def parse_batch(
-        self,
-        serialized_batch: Union[Sequence[bytes], Mapping[str, Sequence[bytes]]],
-        cache: Optional[DecodeCache] = None,
-        roi: Optional[Mapping[str, ResolvedROI]] = None,
-    ) -> TensorSpecStruct:
-        """Fast parse; `roi` ({flat key: ResolvedROI}) decodes the named
-        image fields cropped (decode-time ROI) — bit-identical to
-        `SpecParser.parse_batch(..., roi=roi)`'s full-decode-then-crop."""
+    def _by_dataset_key(self, serialized_batch) -> Dict[str, Sequence[bytes]]:
         if not self.supported:
             raise FastParseError(
                 f"unsupported spec structure: {self.unsupported_reason}"
             )
-        if cache is None:
-            cache = get_decode_cache()
         if isinstance(serialized_batch, Mapping):
             by_key = dict(serialized_batch)
         else:
@@ -995,13 +1014,14 @@ class FastSpecParser:
         sizes = {len(v) for v in by_key.values()}
         if not sizes or sizes == {0}:
             raise ValueError("Cannot parse an empty batch.")
-        flat: Dict[str, np.ndarray] = {}
-        for dataset_key, group in self._groups.items():
+        for dataset_key in self._groups:
             if dataset_key not in by_key:
                 raise KeyError(
                     f"Missing serialized record for dataset {dataset_key!r}"
                 )
-            group.parse_into(by_key[dataset_key], flat, cache, roi)
+        return by_key
+
+    def _struct(self, flat: Mapping[str, np.ndarray]) -> TensorSpecStruct:
         out = TensorSpecStruct()
         for key, value in flat.items():
             out[key] = value
@@ -1009,3 +1029,84 @@ class FastSpecParser:
             if key in out:
                 out[key] = out[key].astype(jnp.bfloat16)
         return out
+
+    def parse_batch(
+        self,
+        serialized_batch: Union[Sequence[bytes], Mapping[str, Sequence[bytes]]],
+        cache: Optional[DecodeCache] = None,
+        roi: Optional[Mapping[str, ResolvedROI]] = None,
+    ) -> TensorSpecStruct:
+        """Fast parse; `roi` ({flat key: ResolvedROI}) decodes the named
+        image fields cropped (decode-time ROI) — bit-identical to
+        `SpecParser.parse_batch(..., roi=roi)`'s full-decode-then-crop."""
+        by_key = self._by_dataset_key(serialized_batch)
+        if cache is None:
+            cache = get_decode_cache()
+        flat: Dict[str, np.ndarray] = {}
+        for dataset_key, group in self._groups.items():
+            group.parse_into(by_key[dataset_key], flat, cache, roi)
+        return self._struct(flat)
+
+    # -- one batch in slices: allocate once, fill rows from any thread ---------
+
+    @property
+    def static_shapes(self) -> bool:
+        """Whether a batch's shapes are known before its records are read.
+        A sequence field pads to the longest record of its batch, so a spec
+        set that holds one is parsed a whole batch at a time."""
+        return self.supported and not any(
+            group.is_sequence for group in self._groups.values()
+        )
+
+    def allocate_batch(
+        self, n: int, roi: Optional[Mapping[str, ResolvedROI]] = None
+    ) -> Optional[Dict[str, np.ndarray]]:
+        """The arrays of a batch of `n` records, for `parse_rows` to fill
+        and `finish_batch` to hand out, or None where `static_shapes` is
+        not given (`parse_batch` is the way then)."""
+        if not self.static_shapes:
+            return None
+        arrays: Dict[str, np.ndarray] = {}
+        for group in self._groups.values():
+            arrays.update(group.allocate(n, roi))
+        return arrays
+
+    def parse_rows(
+        self,
+        serialized: Union[Sequence[bytes], Mapping[str, Sequence[bytes]]],
+        arrays: Mapping[str, np.ndarray],
+        start: int,
+        cache: Optional[DecodeCache] = None,
+        roi: Optional[Mapping[str, ResolvedROI]] = None,
+    ) -> List[str]:
+        """Parses `serialized`, records `start`, `start + 1`, ... of a
+        batch, into those rows of the batch's `arrays` (`allocate_batch`);
+        `roi` is the whole batch's. Slices that do not overlap may run on
+        different threads. Returns the optional keys these records lack."""
+        by_key = self._by_dataset_key(serialized)
+        if cache is None:
+            cache = get_decode_cache()
+        absent: List[str] = []
+        for dataset_key, group in self._groups.items():
+            records = by_key[dataset_key]
+            absent += group.fill_rows(
+                records, group.scan(records), arrays, start, cache, roi
+            )
+        return absent
+
+    def finish_batch(
+        self, arrays: Mapping[str, np.ndarray], absent: Sequence[Sequence[str]]
+    ) -> TensorSpecStruct:
+        """The batch whose every row `parse_rows` has filled; `absent` holds
+        what each of those calls returned. An optional field has to be in
+        every record of a batch or in none."""
+        lacking = set(absent[0])
+        if any(set(keys) != lacking for keys in absent[1:]):
+            raise ValueError(
+                "An optional feature is present in only some batch "
+                "elements; optional features must be all-present or "
+                "all-absent within a batch."
+            )
+        return self._struct(
+            {key: value for key, value in arrays.items() if key not in lacking}
+        )

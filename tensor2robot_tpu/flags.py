@@ -372,7 +372,8 @@ _declare(
     "T2R_PARSE_WORKERS",
     _INT,
     None,
-    "Parse pool size; 0 = synchronous; unset = min(8, cpu_count).",
+    "Parse pool size; 0 = synchronous; unset = the cores the process may "
+    "run on, less one.",
     "tensor2robot_tpu/data/dataset.py",
     minimum=0,
 )

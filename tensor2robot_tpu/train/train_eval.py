@@ -1443,7 +1443,8 @@ def _host_path_record(before, after, steps: int) -> Dict[str, float]:
     """Where the host's time went over one log interval, from two reads of
     the recorder's cumulative counters (utils/tracing.py): milliseconds a
     step the train thread waited for a batch, placed it and dispatched;
-    milliseconds one parse worker took for one batch; the share of batches
+    milliseconds of parse workers' time one batch took (the sum over its
+    slices where it was parsed in slices); the share of batches
     for which the dataset's prefetch queue was empty when asked; and the
     milliseconds the loop stood in `checkpoint_and_eval`."""
 
@@ -1457,7 +1458,7 @@ def _host_path_record(before, after, steps: int) -> Dict[str, float]:
         "dispatch_ms_per_step": delta("train.dispatch.ns") / 1e6 / steps,
         "input/parse_ms_per_batch": (
             delta("data.parse_chunk.ns") / 1e6
-            / max(delta("data.parse_chunk.n"), 1)
+            / max(delta("data.parse_batches"), 1)
         ),
         "input/prefetch_empty_share": (
             delta("data.prefetch_empty") / max(delta("data.prefetch_gets"), 1)

@@ -5,6 +5,7 @@ Mirrors the coverage strategy of the reference's utils/tfdata_test.py
 pipeline.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -785,6 +786,427 @@ class TestParallelParse:
         )
         with pytest.raises(KeyError):
             list(dataset)
+
+
+def _counted(name, before):
+    from tensor2robot_tpu.utils import tracing
+
+    return tracing.counters().get(name, 0) - before.get(name, 0)
+
+
+class TestSlicedParse:
+    """A batch parsed in slices over the pool is the batch a single job
+    parses whole, bit for bit: same records, same order, same offsets for
+    a seed. Batches of 50 over 1, 3 and 8 workers are cut into slices of
+    50, 17+17+16 and 16+16+16+2 records."""
+
+    BATCH = 50
+
+    def _spec(self, kind):
+        spec = TensorSpecStruct()
+        if kind == "stack":
+            spec["frames"] = ExtendedTensorSpec(
+                shape=(3, 12, 16, 3), dtype=np.uint8, name="frames",
+                data_format="jpeg",
+            )
+            spec["pose"] = ExtendedTensorSpec(
+                shape=(4,), dtype="bfloat16", name="pose"
+            )
+            spec["tail"] = ExtendedTensorSpec(
+                shape=(5,), dtype=np.int64, name="tail",
+                varlen_default_value=-1,
+            )
+            spec["never_written"] = ExtendedTensorSpec(
+                shape=(1,), dtype=np.float32, name="never_written",
+                is_optional=True,
+            )
+        elif kind == "multi":
+            spec["img"] = ExtendedTensorSpec(
+                shape=(12, 16, 3), dtype=np.uint8, name="img",
+                data_format="jpeg", dataset_key="d1",
+            )
+            spec["a"] = ExtendedTensorSpec(
+                shape=(2,), dtype=np.float32, name="a", dataset_key="d1"
+            )
+            spec["b"] = ExtendedTensorSpec(
+                shape=(), dtype=np.int64, name="b", dataset_key="d2"
+            )
+        elif kind == "sequence":
+            spec["obs"] = ExtendedTensorSpec(
+                shape=(2,), dtype=np.float32, name="obs", is_sequence=True
+            )
+            spec["goal"] = ExtendedTensorSpec(
+                shape=(1,), dtype=np.float32, name="goal"
+            )
+        else:
+            raise ValueError(kind)
+        return spec
+
+    def _values(self, kind, i, rng):
+        if kind == "stack":
+            return {
+                "frames": rng.randint(0, 256, (3, 12, 16, 3), dtype=np.uint8),
+                "pose": rng.randn(4).astype(np.float32),
+                "tail": np.arange(i % 7, dtype=np.int64),
+            }
+        if kind == "multi":
+            return {
+                "img": rng.randint(0, 256, (12, 16, 3), dtype=np.uint8),
+                "a": rng.randn(2).astype(np.float32),
+                "b": np.asarray(i, np.int64),
+            }
+        return {
+            "obs": rng.randn(1 + i % 5, 2).astype(np.float32),
+            "goal": np.full((1,), i, np.float32),
+        }
+
+    def _write(self, tmp_path, kind, n):
+        spec = self._spec(kind)
+        rng = np.random.RandomState(7)
+        rows = [self._values(kind, i, rng) for i in range(n)]
+        if kind == "multi":
+            by_key = [encode_examples_by_dataset(spec, row) for row in rows]
+            patterns = {}
+            for key in ("d1", "d2"):
+                patterns[key] = str(tmp_path / f"{key}.tfrecord")
+                tfrecord.write_tfrecords(
+                    patterns[key], [record[key] for record in by_key]
+                )
+            return spec, patterns
+        path = str(tmp_path / f"{kind}.tfrecord")
+        tfrecord.write_tfrecords(
+            path, [encode_example(spec, row) for row in rows]
+        )
+        return spec, path
+
+    def _batches(self, spec, patterns, workers, **kwargs):
+        dataset = RecordDataset(
+            specs=spec, file_patterns=patterns, batch_size=self.BATCH,
+            mode="train", seed=11, shuffle_buffer_size=32, repeat=False,
+            num_parse_workers=workers, **kwargs,
+        )
+        try:
+            return [
+                {key: np.asarray(value).copy() for key, value in batch.items()}
+                for batch in dataset
+            ]
+        finally:
+            dataset.close()
+
+    @staticmethod
+    def _assert_same(got, want):
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            assert list(a.keys()) == list(b.keys())
+            for key in a:
+                assert a[key].dtype == b[key].dtype, key
+                np.testing.assert_array_equal(a[key], b[key], err_msg=key)
+
+    @pytest.mark.parametrize("workers", [1, 3, 8])
+    @pytest.mark.parametrize("kind", ["stack", "multi"])
+    def test_sliced_batches_equal_the_whole_batch_parse(
+        self, tmp_path, kind, workers
+    ):
+        from tensor2robot_tpu.utils import tracing
+
+        spec, patterns = self._write(tmp_path, kind, 3 * self.BATCH + 7)
+        whole = self._batches(spec, patterns, workers=0)
+        before = tracing.counters()
+        sliced = self._batches(spec, patterns, workers=workers)
+        assert len(whole) == 3 and whole[0][
+            "frames" if kind == "stack" else "img"
+        ].shape[0] == self.BATCH
+        assert "never_written" not in whole[0]
+        self._assert_same(sliced, whole)
+        assert _counted("data.parse_batches", before) == 3
+        assert _counted("data.parse_batches_sliced", before) == 3
+
+    def test_slices_written_from_more_threads_than_cores(self, tmp_path):
+        """Sixteen workers on slices of one record each, the interpreter
+        switching threads every 10 microseconds: a row written by two
+        slices, or by none, would show against the whole-batch parse."""
+        import sys
+
+        from tensor2robot_tpu.data import dataset as dataset_lib
+
+        spec, patterns = self._write(tmp_path, "stack", 6 * self.BATCH)
+        whole = self._batches(spec, patterns, workers=0)
+        interval = sys.getswitchinterval()
+        floor = dataset_lib._MIN_SLICE_RECORDS
+        sys.setswitchinterval(1e-5)
+        dataset_lib._MIN_SLICE_RECORDS = 1
+        try:
+            sliced = self._batches(spec, patterns, workers=16)
+        finally:
+            dataset_lib._MIN_SLICE_RECORDS = floor
+            sys.setswitchinterval(interval)
+        self._assert_same(sliced, whole)
+
+    @pytest.mark.parametrize("why", ["sequence", "process", "oracle"])
+    def test_whole_batch_paths_say_so(self, tmp_path, why):
+        """Where slices do not apply the batch is one job, and the counters
+        say so: a sequence field pads to its batch's longest record, the
+        process backend ships whole batches, the oracle stacks them."""
+        from tensor2robot_tpu.utils import tracing
+
+        kind = "sequence" if why == "sequence" else "multi"
+        spec, patterns = self._write(tmp_path, kind, 2 * self.BATCH)
+        whole = self._batches(spec, patterns, workers=0)
+        before = tracing.counters()
+        pooled = self._batches(
+            spec, patterns, workers=3,
+            parse_backend="process" if why == "process" else "thread",
+            parse_fast=why != "oracle",
+        )
+        self._assert_same(pooled, whole)
+        assert _counted("data.parse_batches", before) == 2
+        assert _counted("data.parse_batches_sliced", before) == 0
+
+    @pytest.mark.parametrize("mode", ["skip", "raise"])
+    def test_corrupt_record_in_one_slice(self, tmp_path, monkeypatch, mode):
+        """A slice that raises sends the whole batch through the whole-batch
+        job: the oracle's error under `raise`; under `skip` the same
+        surviving batch and the same ParseStats as a whole-batch parse."""
+        monkeypatch.setenv("T2R_PARSE_ON_ERROR", mode)
+        spec = self._spec("multi")
+        del spec["b"]
+        rng = np.random.RandomState(3)
+        records = [
+            encode_example(spec, {
+                "img": rng.randint(0, 256, (12, 16, 3), dtype=np.uint8),
+                "a": np.full((2,), i, np.float32),
+            })
+            for i in range(2 * self.BATCH)
+        ]
+        # Record 20 lies in the second of the first batch's three slices.
+        records[20] = records[20][:4] + b"\xff\xff\xff\xff"
+        path = str(tmp_path / "mixed.tfrecord")
+        tfrecord.write_tfrecords(path, records)
+
+        def run(workers):
+            dataset = RecordDataset(
+                spec, {"d1": path}, batch_size=self.BATCH, mode="eval",
+                repeat=False, num_parse_workers=workers, prefetch_depth=0,
+            )
+            try:
+                batches = [
+                    {k: np.asarray(v).copy() for k, v in batch.items()}
+                    for batch in dataset
+                ]
+                stats = dataset.stats()
+            finally:
+                dataset.close()
+            assert stats.pop("parse_workers") == workers
+            return batches, stats
+
+        if mode == "raise":
+            with pytest.raises(Exception) as whole_error:
+                run(0)
+            with pytest.raises(Exception) as sliced_error:
+                run(3)
+            assert type(sliced_error.value) is type(whole_error.value)
+            return
+        whole, whole_stats = run(0)
+        sliced, sliced_stats = run(3)
+        assert [b["a"].shape[0] for b in whole] == [self.BATCH - 1, self.BATCH]
+        self._assert_same(sliced, whole)
+        assert sliced_stats == whole_stats
+        assert whole_stats["records_skipped"] == 1
+        assert whole_stats["batches_degraded"] == 1
+        assert whole_stats["fast_fallbacks"] == 1
+
+    def test_optional_field_in_only_some_slices_is_refused(self, tmp_path):
+        """Every slice of its own agrees (all present, or all absent), the
+        batch does not: the join refuses it and the whole-batch job raises
+        what it has always raised."""
+        spec = self._spec("stack")
+        rng = np.random.RandomState(5)
+        rows = [self._values("stack", i, rng) for i in range(self.BATCH)]
+        for row in rows[:17]:  # the first of three slices, and only it
+            row["never_written"] = np.ones((1,), np.float32)
+        path = str(tmp_path / "optional.tfrecord")
+        tfrecord.write_tfrecords(
+            path, [encode_example(spec, row) for row in rows]
+        )
+        for workers in (0, 3):
+            dataset = RecordDataset(
+                spec, path, batch_size=self.BATCH, mode="eval", repeat=False,
+                num_parse_workers=workers,
+            )
+            with pytest.raises(ValueError, match="only some batch"):
+                list(dataset)
+            dataset.close()
+
+    def test_a_batch_nobody_holds_gives_its_arrays_to_a_later_one(
+        self, tmp_path, monkeypatch
+    ):
+        """Thirty batches through a handful of sets of arrays, every batch
+        still bit for bit the whole-batch parse; a batch the consumer keeps
+        (or keeps a view of a view of) is never written again."""
+        from tensor2robot_tpu.data.wire import FastSpecParser
+
+        spec, patterns = self._write(tmp_path, "stack", 30 * self.BATCH)
+        whole = self._batches(spec, patterns, workers=0)
+        allocate, fresh = FastSpecParser.allocate_batch, []
+
+        def counted(self, n, roi=None):
+            fresh.append(n)
+            return allocate(self, n, roi)
+
+        monkeypatch.setattr(FastSpecParser, "allocate_batch", counted)
+        self._assert_same(self._batches(spec, patterns, workers=3), whole)
+        # in flight 1 + 2, prefetch 2, and four: at most nine sets.
+        assert 3 <= len(fresh) <= 9
+        del fresh[:]
+        dataset = RecordDataset(
+            specs=spec, file_patterns=patterns, batch_size=self.BATCH,
+            mode="train", seed=11, shuffle_buffer_size=32, repeat=False,
+            num_parse_workers=3,
+        )
+        kept = []
+        for index, batch in enumerate(dataset):
+            # Every third batch stays, by a view of a view of its frames.
+            if index % 3 == 0:
+                kept.append((index, batch["frames"][1:][:, 0]))
+        dataset.close()
+        assert len(fresh) >= len(kept) == 10
+        for index, frames in kept:
+            np.testing.assert_array_equal(
+                frames, whole[index]["frames"][1:][:, 0]
+            )
+
+    def test_batch_arrays_are_free_when_nobody_refers_to_them(self):
+        from tensor2robot_tpu.data.dataset import _BatchArrays
+
+        made = []
+
+        def allocate(n, roi=None):
+            made.append(n)
+            return {"a": np.empty((n, 4)), "b": np.empty((n,), np.int64)}
+
+        buffers = _BatchArrays(limit=2)
+        take = functools.partial(buffers.take, allocate)
+        first, second = take(3), take(3)
+        assert made == [3, 3] and first["a"].base is not second["a"].base
+        third = take(3)  # both sets held: fresh, and not kept
+        assert made == [3, 3, 3]
+        owner = id(first["a"].base)
+        row = first["a"][1:2][0]  # a view of a view of a view
+        del first, third
+        assert id(take(3)["b"].base) != owner  # `row` still holds it
+        assert made == [3, 3, 3, 3]
+        del row
+        again = take(3)
+        assert id(again["a"].base) == owner and made == [3, 3, 3, 3]
+        assert take(5)["a"].shape == (5, 4)  # another size: fresh
+        assert _BatchArrays(2).take(lambda n, roi: None, 3) is None
+
+    def test_a_batch_on_its_way_to_the_device_is_not_written_again(self):
+        """`jax.device_put` may read the host array after it returns (a
+        transfer in flight) or alias it for the device array's life (the
+        CPU backend): either way it holds a reference for as long, so the
+        set is not handed out under it; and once it has let go, writing
+        the arrays again leaves what reached the device as it was."""
+        import jax
+
+        from tensor2robot_tpu.data.dataset import _BatchArrays
+
+        buffers = _BatchArrays(limit=4)
+        placed = []
+        for value in range(12):
+            arrays = buffers.take(
+                lambda n, roi: {"img": np.empty((n, 64, 64, 3), np.uint8)}, 8
+            )
+            arrays["img"][...] = value
+            placed.append(jax.device_put(arrays["img"]))
+            del arrays
+        assert len(buffers._sets) <= 4
+        for value, on_device in enumerate(placed):
+            assert (np.asarray(on_device) == value).all(), value
+
+    @pytest.mark.parametrize("prefetch_depth", [0, 2])
+    def test_early_close_mid_batch_leaks_no_thread_and_no_batch(
+        self, tmp_path, monkeypatch, prefetch_depth
+    ):
+        import gc
+        import threading
+        import time
+        import weakref
+
+        from tensor2robot_tpu.data.wire import FastSpecParser
+
+        spec, patterns = self._write(tmp_path, "stack", 8 * self.BATCH)
+        allocated = []
+        allocate = FastSpecParser.allocate_batch
+
+        def watched(self, n, roi=None):
+            arrays = allocate(self, n, roi)
+            allocated.extend(weakref.ref(a) for a in arrays.values())
+            return arrays
+
+        monkeypatch.setattr(FastSpecParser, "allocate_batch", watched)
+        mine = lambda: [  # noqa: E731
+            t for t in threading.enumerate()
+            if t.name.startswith("t2r-parse") and t.is_alive()
+        ]
+        threads_before = set(mine())
+        dataset = RecordDataset(
+            specs=spec, file_patterns=patterns, batch_size=self.BATCH,
+            mode="train", seed=3, num_parse_workers=3,
+            prefetch_depth=prefetch_depth,
+        )
+        iterator = iter(dataset)
+        first = next(iterator)
+        assert first["frames"].shape[0] == self.BATCH
+        # Batches are in flight behind the first: several arrays live.
+        assert len(allocated) > len(first)
+        iterator.close()
+        del iterator, first
+        dataset.close()
+        deadline = time.monotonic() + 20
+        while time.monotonic() < deadline:
+            gc.collect()
+            if not (set(mine()) - threads_before) and not any(
+                ref() is not None for ref in allocated
+            ):
+                break
+            time.sleep(0.05)
+        assert not set(mine()) - threads_before
+        assert not any(ref() is not None for ref in allocated)
+
+    def test_width_and_in_flight_follow_cores_and_batch(
+        self, tmp_path, monkeypatch
+    ):
+        from tensor2robot_tpu.data import dataset as dataset_lib
+
+        monkeypatch.delenv("T2R_PARSE_WORKERS", raising=False)
+        for cores, want in ((1, 1), (2, 1), (13, 12), (64, 63)):
+            monkeypatch.setattr(
+                dataset_lib.os, "sched_getaffinity",
+                lambda pid, cores=cores: set(range(cores)), raising=False,
+            )
+            assert dataset_lib.default_parse_workers() == want
+        monkeypatch.setenv("T2R_PARSE_WORKERS", "5")
+        assert dataset_lib.default_parse_workers() == 5
+        bounds = dataset_lib._slice_bounds
+        assert bounds(256, 12) == [(a, min(a + 22, 256)) for a in range(0, 256, 22)]
+        assert bounds(50, 8) == [(0, 16), (16, 32), (32, 48), (48, 50)]
+        assert bounds(4, 8) == [(0, 4)] and bounds(50, 1) == [(0, 50)]
+        spec, files = self._write(tmp_path, "multi", 1)
+
+        def in_flight(batch, workers, **kwargs):
+            return RecordDataset(
+                spec, files, batch_size=batch, num_parse_workers=workers,
+                **kwargs,
+            )._max_in_flight()
+
+        # A batch of 256 gives 12 workers a slice each: one batch working,
+        # prefetch_depth (2) behind it, where whole-batch jobs need 12 + 2.
+        assert in_flight(256, 12) == 3
+        assert in_flight(256, 12, parse_backend="process") == 14
+        assert in_flight(256, 12, parse_fast=False) == 14
+        assert in_flight(32, 12) == 8  # two slices a batch: six batches working
+        assert in_flight(4, 2, prefetch_depth=0) == 3
 
 
 class TestCompression:

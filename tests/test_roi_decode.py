@@ -298,6 +298,43 @@ class TestCacheThrashingGuard:
         assert len(cache._entries) == entries_before  # nothing populated
 
 
+class TestRefusedWindowsFallBack:
+    @pytest.mark.parametrize("mode", ["random", "center"])
+    def test_a_window_the_native_decoder_refuses_is_decoded_whole(self, mode):
+        """A progressive jpeg among baseline ones: the native ROI decoder
+        refuses it (-7) and it alone takes the full decode and crop;
+        oracle-identical pixels, with the cache keyed by window (static
+        offsets) and bypassed (random) alike."""
+        import io
+
+        from PIL import Image
+
+        specs = _image_specs()
+        rng = np.random.RandomState(4)
+        rows = [
+            {"img": rng.randint(0, 256, (64, 80, 3), dtype=np.uint8),
+             "a": rng.randn(2).astype(np.float32)}
+            for _ in range(4)
+        ]
+        records = [encode_example(specs, row) for row in rows]
+        buf = io.BytesIO()
+        Image.fromarray(rows[2]["img"]).save(
+            buf, format="JPEG", quality=90, progressive=True
+        )
+        records[2] = encode_example(
+            specs, {"img": buf.getvalue(), "a": rows[2]["a"]}
+        )
+        rois = normalize_decode_rois({"img": DecodeROI(31, 29, mode)}, specs)
+        resolved = resolve_decode_rois(
+            rois, specs, len(records), np.random.default_rng(5)
+        )
+        assert_roi_parity(specs, records, resolved, cache=None)
+        cache = DecodeCache(1 << 20)
+        first = assert_roi_parity(specs, records, resolved, cache=cache)
+        again = assert_roi_parity(specs, records, resolved, cache=cache)
+        np.testing.assert_array_equal(first["img"], again["img"])
+
+
 class TestNormalization:
     def test_rejects_unknown_key(self):
         specs = _image_specs()
@@ -360,6 +397,48 @@ class TestDatasetGate:
         assert any(
             not np.array_equal(x, y) for x, y in zip(a, c)
         )  # different seed, different crops
+
+    @pytest.mark.parametrize("workers", [1, 3, 8])
+    def test_sliced_roi_batches_equal_the_whole_batch_parse(
+        self, tmp_path, workers
+    ):
+        """A batch of 50 decoded in slices (50; 17+17+16; 16+16+16+2) takes
+        each record's window at the offset the reader drew for that row of
+        the whole batch: bit for bit the synchronous whole-batch parse."""
+        from tensor2robot_tpu.data.dataset import RecordDataset
+        from tensor2robot_tpu.utils import tracing
+
+        specs = _image_specs()
+        path = self._write(tmp_path, specs, n=110)
+
+        def batches(num_workers):
+            ds = RecordDataset(
+                specs=specs, file_patterns=path, batch_size=50, mode="train",
+                shuffle_buffer_size=16, seed=21, repeat=False,
+                num_parse_workers=num_workers,
+                decode_roi={"img": DecodeROI(31, 29, "random")},
+            )
+            try:
+                return [
+                    {k: np.asarray(v).copy() for k, v in b.items()} for b in ds
+                ]
+            finally:
+                ds.close()
+
+        whole = batches(0)
+        before = tracing.counters()
+        sliced = batches(workers)
+        assert tracing.counters()["data.parse_batches_sliced"] - before.get(
+            "data.parse_batches_sliced", 0
+        ) == 2
+        assert len(whole) == len(sliced) == 2
+        assert whole[0]["img"].shape == (50, 31, 29, 3)
+        # Random offsets: the windows of a batch differ from row to row.
+        assert len({w.tobytes() for w in whole[0]["img"][:, :2, :2]}) > 1
+        for a, b in zip(whole, sliced):
+            assert list(a.keys()) == list(b.keys())
+            for key in a:
+                np.testing.assert_array_equal(a[key], b[key], err_msg=key)
 
     def test_env_zero_restores_full_frame_decode(self, tmp_path, monkeypatch):
         from tensor2robot_tpu.data.dataset import RecordDataset

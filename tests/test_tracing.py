@@ -174,7 +174,7 @@ class TestRecorder:
         json.dumps(recorder.snapshot())  # what spans.jsonl writes
 
 
-def write_jpeg_records(tmp_path, n=24):
+def write_jpeg_records(tmp_path, n=24, first_chunk=4):
     spec = TensorSpecStruct()
     spec["img"] = ExtendedTensorSpec(
         shape=(16, 24, 3), dtype=np.uint8, name="img", data_format="jpeg"
@@ -190,19 +190,25 @@ def write_jpeg_records(tmp_path, n=24):
     ]
     path = str(tmp_path / "imgs.tfrecord")
     tfrecord.write_tfrecords(path, records)
-    return spec, path, sum(len(r) for r in records[:4])
+    return spec, path, sum(len(r) for r in records[:first_chunk])
 
 
 class TestHostInputSpans:
-    @pytest.mark.parametrize("workers,backend", [
-        (0, "thread"), (4, "thread"), (2, "process"),
+    @pytest.mark.parametrize("workers,backend,batch,slices", [
+        (0, "thread", 4, None), (4, "thread", 4, [(0, 4)]),
+        (2, "process", 4, None),
+        # 40 records over 3 workers: slices of 16, the last one short.
+        (3, "thread", 40, [(0, 16), (16, 16), (32, 8)]),
     ])
     def test_one_batch_one_ordinal_from_read_to_h2d(
-        self, tmp_path, workers, backend
+        self, tmp_path, workers, backend, batch, slices
     ):
-        spec, path, first_chunk_bytes = write_jpeg_records(tmp_path)
+        """`slices` is the (first row, records) of each `data.parse_chunk`
+        span of a batch, None where the batch is one whole-batch job."""
+        n = 6 * batch
+        spec, path, first_chunk_bytes = write_jpeg_records(tmp_path, n, batch)
         dataset = RecordDataset(
-            specs=spec, file_patterns=path, batch_size=4, mode="eval",
+            specs=spec, file_patterns=path, batch_size=batch, mode="eval",
             num_parse_workers=workers, parse_backend=backend,
         )
         mark = time.time_ns()
@@ -217,7 +223,9 @@ class TestHostInputSpans:
         spans = snapshot["spans"]
         assert len(batches) == 6
         reads = {s["ordinal"]: s for s in by_name(spans, "data.read_chunk")}
-        parses = {s["ordinal"]: s for s in by_name(spans, "data.parse_chunk")}
+        parses = {}
+        for s in by_name(spans, "data.parse_chunk"):
+            parses.setdefault(s["ordinal"], []).append(s)
         waits = {s["ordinal"]: s for s in by_name(spans, "infeed.wait")}
         puts = {s["ordinal"]: s for s in by_name(spans, "infeed.h2d")}
         assert sorted(parses) == sorted(puts) == list(range(6))
@@ -225,38 +233,52 @@ class TestHostInputSpans:
         # does the consumer's last wait.
         assert sorted(reads) == sorted(waits) == list(range(7))
         assert reads[6]["counts"]["records"] == 0
-        assert reads[0]["counts"] == {"records": 4, "bytes": first_chunk_bytes}
-        for ordinal, batch in enumerate(batches):
-            # Eval mode reads in order: batch i holds records 4i .. 4i+3.
-            assert list(batch["y"]) == list(range(4 * ordinal, 4 * ordinal + 4))
-            read, parse = reads[ordinal], parses[ordinal]
-            wait, put = waits[ordinal], puts[ordinal]
-            assert put["counts"]["bytes"] == sum(
-                leaf.nbytes for leaf in (batch["img"], batch["y"])
+        assert reads[0]["counts"] == {
+            "records": batch, "bytes": first_chunk_bytes
+        }
+        for ordinal, got in enumerate(batches):
+            # Eval mode reads in order: batch i holds records
+            # batch * i .. batch * (i + 1) - 1.
+            assert list(got["y"]) == list(
+                range(batch * ordinal, batch * (ordinal + 1))
             )
-            assert parse["counts"]["records"] == 4
-            assert parse["counts"]["images"] == 4
-            duration = parse["end_ns"] - parse["start_ns"]
-            assert 0 < parse["counts"]["decode_ns"] <= duration
-            # One batch's way through the pipeline, on one clock.
-            assert read["end_ns"] <= parse["start_ns"]
-            assert parse["end_ns"] <= wait["end_ns"] <= put["start_ns"]
-        parse_threads = {s["thread"] for s in parses.values()}
+            read, wait, put = reads[ordinal], waits[ordinal], puts[ordinal]
+            assert put["counts"]["bytes"] == sum(
+                leaf.nbytes for leaf in (got["img"], got["y"])
+            )
+            # The slices of an ordinal cover its records once each.
+            covered = sorted(
+                (s["counts"].get("first_row", 0), s["counts"]["records"])
+                for s in parses[ordinal]
+            )
+            assert covered == (slices or [(0, batch)])
+            for parse in parses[ordinal]:
+                assert ("first_row" in parse["counts"]) == (slices is not None)
+                assert parse["counts"]["images"] == parse["counts"]["records"]
+                duration = parse["end_ns"] - parse["start_ns"]
+                assert 0 < parse["counts"]["decode_ns"] <= duration
+                # One batch's way through the pipeline, on one clock.
+                assert read["end_ns"] <= parse["start_ns"]
+                assert parse["end_ns"] <= wait["end_ns"] <= put["start_ns"]
+        parse_threads = {
+            s["thread"] for group in parses.values() for s in group
+        }
         if backend == "process":
             # A worker's span comes home with its batch; the negated pid
             # stands for the worker.
             assert all(t < 0 and t != -os.getpid() for t in parse_threads)
         elif workers:
             assert threading.get_ident() not in parse_threads
-        gets = (
-            snapshot["counters"]["data.prefetch_gets"]
-            - before.get("data.prefetch_gets", 0)
-        )
-        empty = (
-            snapshot["counters"].get("data.prefetch_empty", 0)
-            - before.get("data.prefetch_empty", 0)
-        )
+
+        def since(name):
+            return snapshot["counters"].get(name, 0) - before.get(name, 0)
+
+        gets, empty = since("data.prefetch_gets"), since("data.prefetch_empty")
         assert gets == 7 and 0 <= empty <= gets
+        # Every batch delivered is counted, and as sliced where it was.
+        assert since("data.parse_batches") == 6
+        assert since("data.parse_batches_sliced") == (6 if slices else 0)
+        assert dataset.stats()["parse_workers"] == workers
 
     def test_the_fallback_to_the_oracle_is_counted_on_the_span(self, tmp_path):
         from tensor2robot_tpu.data.dataset import _FastParseState, _traced_parse
@@ -382,7 +404,8 @@ class TestTrainLoopSpans:
         after = {
             "infeed.wait.ns": 25_000_000, "infeed.h2d.ns": 4_000_000,
             "train.dispatch.ns": 2_000_000, "data.parse_chunk.ns": 90_000_000,
-            "data.parse_chunk.n": 3, "data.prefetch_gets": 14,
+            "data.parse_chunk.n": 36, "data.parse_batches": 3,
+            "data.prefetch_gets": 14,
             "data.prefetch_empty": 1, "train.checkpoint.ns": 7_000_000,
         }
         assert train_eval._host_path_record(before, after, steps=4) == {
