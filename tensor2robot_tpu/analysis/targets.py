@@ -71,6 +71,21 @@ def _transformer_bc():
     )
 
 
+def _hybrid_sequence_lm():
+    from tensor2robot_tpu.models.sequence_lm_models import (
+        HybridSequenceLMModel,
+    )
+
+    # Small widths, the family's layer pattern: the spec contract (int32
+    # tokens and segment ids, no position input) does not depend on size.
+    return HybridSequenceLMModel(
+        layer_types=("mamba", "attention", "mamba"),
+        sequence_length=64,
+        mamba_chunk_size=16,
+        device_type="cpu",
+    )
+
+
 def _mock_noop():
     from tensor2robot_tpu.utils.mocks import MockT2RModel
 
@@ -79,6 +94,7 @@ def _mock_noop():
 
 register_target("qtopt-grasping44", _qtopt_grasping44)
 register_target("transformer-bc", _transformer_bc)
+register_target("hybrid-sequence-lm", _hybrid_sequence_lm)
 register_target("mock-noop", _mock_noop)
 # The policy server's request path: predict-mode specs are what the
 # server's submit() validates against and what the micro-batcher stacks

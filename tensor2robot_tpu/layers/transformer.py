@@ -17,7 +17,7 @@ contexts on a CP mesh without code changes.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -101,6 +101,12 @@ class MultiHeadAttention(nn.Module):
     # of opening its own shard_map (which cannot nest). The piece that
     # composes SP with PP (parallel/planner.py 3D plans).
     manual_sequence_size: int = 1
+    # Score multiplier; None is the ops' default of head_dim ** -0.5.
+    # Single-device full-forward paths only.
+    scale: Optional[float] = None
+    # Compute dtype of the projections (None follows input and params).
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
 
     def _kv_heads(self) -> int:
         kv = self.num_kv_heads if self.num_kv_heads is not None else self.num_heads
@@ -120,26 +126,60 @@ class MultiHeadAttention(nn.Module):
         return jnp.repeat(t, groups, axis=2)
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(
+        self, x: jax.Array, segment_ids: Optional[jax.Array] = None
+    ) -> jax.Array:
+        """segment_ids [B, S]: packed documents; a query then sees the
+        earlier keys of its own document only (causal, single device)."""
         batch, seq, _ = x.shape
         features = self.num_heads * self.head_dim
         kv_heads = self._kv_heads()
         kv_features = kv_heads * self.head_dim
-        qkv = nn.Dense(
-            features + 2 * kv_features, use_bias=False, name="qkv"
-        )(x)
+
+        def project(width, name):
+            return nn.Dense(
+                width, use_bias=False, dtype=self.dtype,
+                kernel_init=self.kernel_init, name=name,
+            )
+
+        with jax.named_scope("attention_proj"):
+            qkv = project(features + 2 * kv_features, "qkv")(x)
         q, k, v = jnp.split(
             qkv, [features, features + kv_features], axis=-1
         )
         q = q.reshape(batch, seq, self.num_heads, self.head_dim)
         k = k.reshape(batch, seq, kv_heads, self.head_dim)
         v = v.reshape(batch, seq, kv_heads, self.head_dim)
+        distributed = (
+            self.decode or self.mesh is not None
+            or self.manual_sequence_size > 1
+        )
+        if self.scale is not None and distributed:
+            raise ValueError(
+                "scale is taken by the single-device full forward only"
+            )
+        if segment_ids is not None and (
+            distributed or self.window is not None or not self.causal
+        ):
+            raise ValueError(
+                "segment_ids belong to the causal single-device full "
+                "forward: no decode, mesh or window"
+            )
+        if segment_ids is not None:
+            with jax.named_scope("attention"):
+                out = flash_lib.segment_attention(
+                    q, k, v, segment_ids, scale=self.scale
+                )
+            with jax.named_scope("attention_proj"):
+                return project(x.shape[-1], "out")(
+                    out.reshape(batch, seq, features)
+                )
         if self.decode:
             # The cache stores kv_heads only (the GQA memory win); the
             # group broadcast happens on the read inside _decode_step.
             out = self._decode_step(q, k, v)
             out = out.reshape(batch, seq, features)
-            return nn.Dense(x.shape[-1], use_bias=False, name="out")(out)
+            return project(x.shape[-1], "out")(out)
         # Training/full-forward paths attend at full head count: the
         # flash/ring/ulysses kernels take equal q/k head dims.
         k, v = self._expand_kv(k), self._expand_kv(v)
@@ -176,7 +216,7 @@ class MultiHeadAttention(nn.Module):
                     window=self.window,
                 )
             out = out.reshape(batch, seq, features)
-            return nn.Dense(x.shape[-1], use_bias=False, name="out")(out)
+            return project(x.shape[-1], "out")(out)
         sequence_axis = (
             dict(self.mesh.shape).get(mesh_lib.SEQUENCE_AXIS, 1)
             if self.mesh is not None
@@ -213,17 +253,18 @@ class MultiHeadAttention(nn.Module):
                 use_flash = seq >= _FLASH_AUTO_SEQ
             if use_flash:
                 out = flash_lib.flash_attention(
-                    q, k, v, causal=self.causal, interpret=self.interpret,
-                    window=self.window,
+                    q, k, v, causal=self.causal, scale=self.scale,
+                    interpret=self.interpret, window=self.window,
                 )
             else:
                 # Plain-XLA attention, measured faster on-chip than the
                 # Pallas kernel at these sizes (use_flash docstring).
                 out = flash_lib.reference_attention(
-                    q, k, v, causal=self.causal, window=self.window
+                    q, k, v, causal=self.causal, scale=self.scale,
+                    window=self.window,
                 )
         out = out.reshape(batch, seq, features)
-        return nn.Dense(x.shape[-1], use_bias=False, name="out")(out)
+        return project(x.shape[-1], "out")(out)
 
     def _decode_step(self, q, k, v):
         """Appends this step's k/v to the cache and attends q against the
@@ -365,6 +406,97 @@ class TransformerBlock(nn.Module):
             h = nn.gelu(h)
             h = nn.Dense(x.shape[-1], name="mlp_out")(h)
         return x + h
+
+
+class RMSNorm(nn.Module):
+    """x / rms(x) * scale over the last axis, statistics in float32."""
+
+    epsilon: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        variance = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * lax.rsqrt(variance + self.epsilon) * scale).astype(x.dtype)
+
+
+class SwiGLU(nn.Module):
+    """W_down (silu(W_gate x) * (W_up x)), no bias."""
+
+    hidden_dim: int
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        def dense(width, name):
+            return nn.Dense(
+                width, use_bias=False, dtype=self.dtype,
+                kernel_init=self.kernel_init, name=name,
+            )
+
+        with jax.named_scope("mlp"):
+            hidden = nn.silu(dense(self.hidden_dim, "gate")(x)) * dense(
+                self.hidden_dim, "up"
+            )(x)
+            return dense(x.shape[-1], "down")(hidden)
+
+
+class HybridBlock(nn.Module):
+    """Pre-RMSNorm block whose mixer is chosen by `layer_type`:
+
+        u = h + r * Mixer(RMSNorm(h));  h_next = u + r * SwiGLU(RMSNorm(u))
+
+    "mamba" is the Mamba-2 mixer (layers/mamba2.py); "attention" is
+    grouped-query attention with no positional encoding and a fixed score
+    multiplier. Both read `segment_ids` [B, S]: packed documents do not
+    see each other.
+    """
+
+    layer_type: str
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    attention_multiplier: float
+    mlp_dim: int
+    mamba_heads: int
+    mamba_head_dim: int
+    mamba_state: int
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
+    residual_multiplier: float = 1.0
+    epsilon: float = 1e-5
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, h: jax.Array, segment_ids: jax.Array) -> jax.Array:
+        init = nn.initializers.normal(0.02)
+        normed = RMSNorm(self.epsilon, name="norm_mixer")(h)
+        if self.layer_type == "mamba":
+            from tensor2robot_tpu.layers.mamba2 import Mamba2Mixer
+
+            mixed = Mamba2Mixer(
+                num_heads=self.mamba_heads, head_dim=self.mamba_head_dim,
+                state_size=self.mamba_state, num_groups=self.mamba_groups,
+                conv_width=self.mamba_conv, chunk_size=self.mamba_chunk,
+                epsilon=self.epsilon, dtype=self.dtype, name="mixer",
+            )(normed, segment_ids)
+        elif self.layer_type == "attention":
+            mixed = MultiHeadAttention(
+                num_heads=self.num_heads, head_dim=self.head_dim,
+                num_kv_heads=self.num_kv_heads, causal=True,
+                scale=self.attention_multiplier, dtype=self.dtype,
+                kernel_init=init, name="mixer",
+            )(normed, segment_ids)
+        else:
+            raise ValueError(f"no mixer for layer type {self.layer_type!r}")
+        u = h + self.residual_multiplier * mixed
+        mlp = SwiGLU(self.mlp_dim, dtype=self.dtype, kernel_init=init, name="mlp")(
+            RMSNorm(self.epsilon, name="norm_mlp")(u)
+        )
+        return u + self.residual_multiplier * mlp
 
 
 class PipelineStage(nn.Module):

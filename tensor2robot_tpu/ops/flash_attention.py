@@ -216,6 +216,66 @@ def reference_attention(
     ).astype(q.dtype)
 
 
+#: Queries a block of `segment_attention` takes at once.
+SEGMENT_BLOCK_Q = 1024
+
+
+def segment_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    segment_ids: jax.Array,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """Causal attention over packed documents: query i sees key j iff
+    j <= i and segment_ids[j] == segment_ids[i].
+
+    q [B, S, H, D]; k, v [B, S, KVH, D] with H a multiple of KVH (the
+    group's keys are read once, never repeated); segment_ids [B, S]. An
+    einsum blocked over queries: block n of `SEGMENT_BLOCK_Q` queries meets
+    keys 0 .. (n+1) * SEGMENT_BLOCK_Q only, so the blocks above the diagonal
+    are never computed and the largest logits alive are
+    [B, H, SEGMENT_BLOCK_Q, S]. Each
+    block is recomputed in the backward pass (`jax.checkpoint`), so its
+    probabilities do not outlive it. Softmax statistics in float32.
+    """
+    batch, seq, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} key heads")
+    scale = scale if scale is not None else dim ** -0.5
+    precision = _dot_precision(q.dtype)
+    block_q = min(SEGMENT_BLOCK_Q, seq)
+    q = q.reshape(batch, seq, kv_heads, heads // kv_heads, dim)
+
+    @jax.checkpoint
+    def block(q_blk, k_ctx, v_ctx, seg_q, seg_k, start):
+        logits = jnp.einsum(
+            "bqgrd,bkgd->bgrqk", q_blk, k_ctx, precision=precision,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        q_pos = start + jnp.arange(q_blk.shape[1])
+        mask = (q_pos[:, None] >= jnp.arange(k_ctx.shape[1])[None, :])[None] & (
+            seg_q[:, :, None] == seg_k[:, None, :]
+        )
+        probs = jax.nn.softmax(
+            jnp.where(mask[:, None, None], logits, _NEG_INF), axis=-1
+        )
+        return jnp.einsum(
+            "bgrqk,bkgd->bqgrd", probs.astype(v_ctx.dtype), v_ctx,
+            precision=precision,
+        )
+
+    out = []
+    for start in range(0, seq, block_q):
+        stop = min(start + block_q, seq)
+        out.append(block(
+            q[:, start:stop], k[:, :stop], v[:, :stop],
+            segment_ids[:, start:stop], segment_ids[:, :stop], start,
+        ))
+    return jnp.concatenate(out, axis=1).reshape(batch, seq, heads, dim)
+
+
 def _flash_body(
     offsets_ref, q_ref, k_ref, v_ref, block_k, scale, causal, precision,
     window=None,

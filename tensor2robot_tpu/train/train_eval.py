@@ -1467,6 +1467,43 @@ def _host_path_record(before, after, steps: int) -> Dict[str, float]:
     }
 
 
+_TOKEN_KEYS = ("tokens", "pad_tokens")
+
+
+def add_token_counts(sums, metrics):
+    """Token-sequence models put `tokens` (positions with a loss) and
+    `pad_tokens` into a step's metrics. Adds one step's counts (or a
+    scanned chunk's stacked ones) to the running sums on the device, which
+    the next log reads back with the metrics it reads anyway. `sums` comes
+    back as it was for any other model."""
+    if any(key not in metrics for key in _TOKEN_KEYS):
+        return sums
+    counts = {
+        key: jnp.round(jnp.sum(metrics[key])).astype(jnp.int32)
+        for key in _TOKEN_KEYS
+    }
+    if sums is None:
+        return counts
+    return {key: sums[key] + counts[key] for key in _TOKEN_KEYS}
+
+
+def token_log_record(token_sums, seconds: float) -> Dict[str, float]:
+    """From the counts summed over an interval's steps, already read back:
+    the recorder's counters `train.tokens` and `train.pad_tokens` grow by
+    them, and the log record gets `tokens_per_s` and `pad_share` (padding
+    over the positions that are a token or padding). Empty where no step
+    counted tokens."""
+    if token_sums is None:
+        return {}
+    tokens, pad = (int(token_sums[key]) for key in _TOKEN_KEYS)
+    tracing.count("train.tokens", tokens)
+    tracing.count("train.pad_tokens", pad)
+    return {
+        "tokens_per_s": tokens / max(seconds, 1e-9),
+        "pad_share": pad / max(tokens + pad, 1),
+    }
+
+
 def train_eval_model(
     t2r_model: AbstractT2RModel,
     input_generator_train=None,
@@ -1644,12 +1681,16 @@ def train_eval_model(
 
     host_counters = tracing.counters()
 
+    token_sums = None  # add_token_counts: every step since the last log
+
     def log_metrics(step: int, metrics) -> Dict[str, float]:
-        nonlocal t_last, last_log_step, host_counters
+        nonlocal t_last, last_log_step, host_counters, token_sums
         with tracing.span("train.log", ordinal=step - 1):
+            fetched, interval_tokens = jax.device_get((metrics, token_sums))
+            token_sums = None
             host_metrics = {
                 key: float(value)
-                for key, value in jax.device_get(metrics).items()
+                for key, value in fetched.items()
                 if getattr(value, "ndim", 0) == 0
             }
             now = time.time()
@@ -1657,6 +1698,7 @@ def train_eval_model(
                 (step - last_log_step) / max(now - t_last, 1e-9)
             )
             host_metrics.update(collective_info)
+            host_metrics.update(token_log_record(interval_tokens, now - t_last))
             counters = tracing.counters()
             host_metrics.update(
                 _host_path_record(host_counters, counters, step - last_log_step)
@@ -1732,6 +1774,7 @@ def train_eval_model(
                         hook.before_step(ctx)
                 with tracing.span("train.dispatch", ordinal=step):
                     state, metrics = compiled.train_step(state, batch, rng_train)
+                    token_sums = add_token_counts(token_sums, metrics)
                     # The enqueued step holds the batch from here. The
                     # loop's reference goes now, so that freeing it falls
                     # under this span and not between two.
@@ -1795,6 +1838,7 @@ def train_eval_model(
                     state, stacked_metrics = compiled.train_scan(
                         state, device_chunk, rng_train
                     )
+                    token_sums = add_token_counts(token_sums, stacked_metrics)
                     # Hooks observe loop granularity: the final step's
                     # metrics (one small eager program a leaf).
                     ctx.device_metrics = jax.tree_util.tree_map(

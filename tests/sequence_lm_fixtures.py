@@ -1,0 +1,64 @@
+"""What the two files of sequence-model tests share: a packing of
+documents into two rows, a tiny model and a batch (ISSUE 28)."""
+
+import os
+
+import jax.numpy as jnp
+import numpy as np
+
+from tensor2robot_tpu.models.sequence_lm_models import HybridSequenceLMModel
+from tensor2robot_tpu.specs import TensorSpecStruct
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ = 64
+# Two rows of documents back to back; 0 is padding.
+LENGTHS = ((20, 9, 23, 12), (31, 5, 17))
+
+
+def segments():
+    rows = []
+    for lengths in LENGTHS:
+        row = np.concatenate(
+            [np.full(n, i + 1, np.int32) for i, n in enumerate(lengths)]
+        )
+        rows.append(np.pad(row, (0, SEQ - len(row))))
+    return np.stack(rows)
+
+
+def spans(row):
+    """[(start, stop)] of the documents of row `row`."""
+    edges = np.cumsum((0,) + LENGTHS[row])
+    return list(zip(edges[:-1], edges[1:]))
+
+
+def one_segment(batch=2, seq=SEQ):
+    return jnp.ones((batch, seq), jnp.int32)
+
+
+def model(**overrides):
+    kwargs = dict(
+        vocab_size=96, hidden_size=64, shared_intermediate_size=128,
+        layer_types=("mamba", "attention", "mamba"), num_attention_heads=4,
+        num_key_value_heads=2, mamba_n_heads=4, mamba_d_head=32,
+        mamba_d_state=16, mamba_chunk_size=16, sequence_length=SEQ,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, attention_multiplier=0.0625, device_type="cpu",
+    )
+    kwargs.update(overrides)
+    return HybridSequenceLMModel(**kwargs)
+
+
+def batch(seed=0):
+    rng = np.random.RandomState(seed)
+    ids = segments()
+    tokens = rng.randint(0, 96, ids.shape).astype(np.int32)
+    tokens[ids == 0] = 0
+    targets = np.roll(tokens, -1, axis=1)
+    ends = np.concatenate(
+        [ids[:, 1:] != ids[:, :-1], np.ones((2, 1), bool)], axis=1
+    )
+    loss_mask = ((ids > 0) & ~ends).astype(np.float32)
+    return (
+        TensorSpecStruct({"tokens": tokens, "segment_ids": ids}),
+        TensorSpecStruct({"targets": targets, "loss_mask": loss_mask}),
+    )
