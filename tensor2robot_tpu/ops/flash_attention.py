@@ -22,6 +22,12 @@ tile from the forward's saved row statistics L = m + log(l) and
 D = rowsum(dO*O), so the backward is also O(S·D) HBM (the
 FlashAttention-2 scheme); the S×S logit matrix never materializes in
 either direction.
+
+Packed documents go another way: `segment_attention` (causal and same
+document, grouped-query) runs the upstream splash kernel where it is
+lowered for a TPU and its tiles divide the sequence, and a blocked einsum
+everywhere else. The kernels above have no document mask; with one added
+they lost that race (tools/race_segment_attention.py, PERF.md PR 29).
 """
 
 from __future__ import annotations
@@ -216,8 +222,21 @@ def reference_attention(
     ).astype(q.dtype)
 
 
-#: Queries a block of `segment_attention` takes at once.
+#: Queries a block of `segment_attention`'s einsum path takes at once.
 SEGMENT_BLOCK_Q = 1024
+
+#: Tiles of `segment_attention`'s kernel path (`BlockSizes` of the upstream
+#: splash kernel): queries a grid step, keys fetched a grid step and keys a
+#: product, for the forward kernel and for the fused backward kernel. From
+#: the sweep of `tools/race_segment_attention.py` over 256 to 2048 on a
+#: TPU v5e at q [1, 8192, 32, 64], k, v [1, 8192, 8, 64] (PERF.md, PR 29):
+#: within half a percent of the sweep's fastest, which took tiles of 2048;
+#: with none over 1024 every multiple of 1024 positions tiles, and 2048
+#: queries with 2048 keys no longer fit the kernel's VMEM.
+SEGMENT_KERNEL_BLOCKS = dict(
+    block_q=1024, block_kv=1024, block_kv_compute=512,
+    block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=1024,
+)
 
 
 def segment_attention(
@@ -231,19 +250,49 @@ def segment_attention(
     j <= i and segment_ids[j] == segment_ids[i].
 
     q [B, S, H, D]; k, v [B, S, KVH, D] with H a multiple of KVH (the
-    group's keys are read once, never repeated); segment_ids [B, S]. An
-    einsum blocked over queries: block n of `SEGMENT_BLOCK_Q` queries meets
-    keys 0 .. (n+1) * SEGMENT_BLOCK_Q only, so the blocks above the diagonal
-    are never computed and the largest logits alive are
-    [B, H, SEGMENT_BLOCK_Q, S]. Each
-    block is recomputed in the backward pass (`jax.checkpoint`), so its
-    probabilities do not outlive it. Softmax statistics in float32.
+    group's keys are never repeated); segment_ids [B, S]. Softmax
+    statistics and every accumulation in float32, the probabilities
+    rounded to the values' dtype for the second product only. Two
+    implementations of that one contract, chosen by what the call can see:
+
+    * `_segment_kernel`, a tiled online-softmax Pallas kernel whose scores
+      never leave VMEM, where the program is lowered for a TPU
+      (`lax.platform_dependent`), the operands are bfloat16 and S is a
+      multiple of every tile of `SEGMENT_KERNEL_BLOCKS`;
+    * `_segment_einsum`, an einsum blocked over `SEGMENT_BLOCK_Q` queries,
+      everywhere else: other platforms, float32 (the kernel's products
+      would round it to bfloat16), short or ragged sequences.
     """
-    batch, seq, heads, dim = q.shape
-    kv_heads = k.shape[2]
+    heads, kv_heads = q.shape[2], k.shape[2]
     if heads % kv_heads:
         raise ValueError(f"{heads} query heads over {kv_heads} key heads")
-    scale = scale if scale is not None else dim ** -0.5
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    if _segment_kernel_tiles(q):
+        return lax.platform_dependent(
+            q, k, v, segment_ids,
+            tpu=functools.partial(_segment_kernel, scale=scale),
+            default=functools.partial(_segment_einsum, scale=scale),
+        )
+    return _segment_einsum(q, k, v, segment_ids, scale=scale)
+
+
+def _segment_kernel_tiles(q: jax.Array) -> bool:
+    """Whether the kernel path can take `q` as it is: static, from shape
+    and dtype alone."""
+    return q.dtype == jnp.bfloat16 and all(
+        q.shape[1] % tile == 0 for tile in SEGMENT_KERNEL_BLOCKS.values()
+    )
+
+
+def _segment_einsum(q, k, v, segment_ids, scale):
+    """Block n of `SEGMENT_BLOCK_Q` queries meets keys
+    0 .. (n+1) * SEGMENT_BLOCK_Q only, so the blocks above the diagonal are
+    never computed and the largest logits alive are
+    [B, H, SEGMENT_BLOCK_Q, S], float32 in HBM. Each block is recomputed in
+    the backward pass (`jax.checkpoint`), so its probabilities do not
+    outlive it."""
+    batch, seq, heads, dim = q.shape
+    kv_heads = k.shape[2]
     precision = _dot_precision(q.dtype)
     block_q = min(SEGMENT_BLOCK_Q, seq)
     q = q.reshape(batch, seq, kv_heads, heads // kv_heads, dim)
@@ -274,6 +323,45 @@ def segment_attention(
             segment_ids[:, start:stop], segment_ids[:, :stop], start,
         ))
     return jnp.concatenate(out, axis=1).reshape(batch, seq, heads, dim)
+
+
+def _segment_kernel(q, k, v, segment_ids, scale, interpret=False):
+    """The upstream splash kernel (`jax.experimental.pallas.ops.tpu.
+    splash_attention`), one multi-query call a key head and sequence: a
+    forward kernel with running row maxima and sums that emits the output
+    and the log-sum-exp, and a backward kernel that rebuilds each tile of
+    probabilities from them. A tile's mask is made in the kernel from
+    positions and the two id vectors; tiles above the diagonal are skipped.
+    The kernel takes no scale, so the queries carry it: exact for a power
+    of two (granite's 1/64, 64 ** -0.5), else one more rounding of the
+    scaled query to its dtype, from float32."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+        splash_attention_mask as splash_mask,
+    )
+
+    batch, seq, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    group = heads // kv_heads
+    kernel = splash.make_splash_mqa_single_device(
+        splash_mask.MultiHeadMask([splash_mask.CausalMask((seq, seq))] * group),
+        # dq, dk and dv from one backward kernel: it won the race too.
+        block_sizes=splash.BlockSizes(
+            **{"use_fused_bwd_kernel": True, **SEGMENT_KERNEL_BLOCKS}
+        ),
+        interpret=interpret,
+    )
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    # [B, S, KVH * G, D] -> [B, KVH, G, S, D]; keys [B, KVH, S, D].
+    q = q.reshape(batch, seq, kv_heads, group, dim).transpose(0, 2, 3, 1, 4)
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+
+    def one_sequence(q, k, v, ids):
+        ids = splash.SegmentIds(ids, ids)
+        return jax.vmap(kernel, in_axes=(0, 0, 0, None))(q, k, v, ids)
+
+    out = jax.vmap(one_sequence)(q, k, v, segment_ids.astype(jnp.int32))
+    return out.transpose(0, 3, 1, 2, 4).reshape(batch, seq, heads, dim)
 
 
 def _flash_body(

@@ -15,13 +15,13 @@ SEQ = 64
 LENGTHS = ((20, 9, 23, 12), (31, 5, 17))
 
 
-def segments():
+def segments(lengths=LENGTHS, seq=SEQ):
     rows = []
-    for lengths in LENGTHS:
+    for row_lengths in lengths:
         row = np.concatenate(
-            [np.full(n, i + 1, np.int32) for i, n in enumerate(lengths)]
+            [np.full(n, i + 1, np.int32) for i, n in enumerate(row_lengths)]
         )
-        rows.append(np.pad(row, (0, SEQ - len(row))))
+        rows.append(np.pad(row, (0, seq - len(row))))
     return np.stack(rows)
 
 

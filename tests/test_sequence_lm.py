@@ -193,6 +193,23 @@ def test_packed_model_gives_each_document_its_own_logits():
             )
 
 
+def _masked_attention(q, k, v, segments, scale):
+    """Materialised logits, keys repeated over their group: the plain form."""
+    group = q.shape[2] // k.shape[2]
+    highest = jax.lax.Precision.HIGHEST
+    logits = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, jnp.repeat(k, group, axis=2), precision=highest
+    ) * scale
+    seq = q.shape[1]
+    mask = jnp.tril(jnp.ones((seq, seq), bool))[None] & (
+        segments[:, :, None] == segments[:, None, :]
+    )
+    probs = jax.nn.softmax(jnp.where(mask[:, None], logits, -1e30), axis=-1)
+    return jnp.einsum(
+        "bhqk,bkhd->bqhd", probs, jnp.repeat(v, group, axis=2), precision=highest
+    )
+
+
 def test_segment_attention_is_masked_attention(monkeypatch):
     monkeypatch.setattr(flash_lib, "SEGMENT_BLOCK_Q", 16)  # four blocks of queries
     rng = np.random.RandomState(2)
@@ -201,19 +218,184 @@ def test_segment_attention_is_masked_attention(monkeypatch):
     v = jnp.asarray(rng.randn(2, SEQ, 2, 8), jnp.float32)
     segments = jnp.asarray(_segments())
     got = flash_lib.segment_attention(q, k, v, segments, scale=0.2)
-    logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2),
-        precision=jax.lax.Precision.HIGHEST,
-    ) * 0.2
-    mask = jnp.tril(jnp.ones((SEQ, SEQ), bool))[None] & (
-        segments[:, :, None] == segments[:, None, :]
-    )
-    probs = jax.nn.softmax(jnp.where(mask[:, None], logits, -1e30), axis=-1)
-    want = jnp.einsum(
-        "bhqk,bkhd->bqhd", probs, jnp.repeat(v, 2, axis=2),
-        precision=jax.lax.Precision.HIGHEST,
-    )
+    want = _masked_attention(q, k, v, segments, 0.2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+# The kernel path of `segment_attention` (ISSUE 29), in Pallas interpret mode:
+# tiles of 128 over 256 positions, so every case has four tiles a head, one
+# of them above the diagonal.
+KERNEL_SEQ = 256
+PACKINGS = {
+    "one_document": ((256,), (256,)),
+    "several_documents": ((40, 60, 28, 100, 28), (128, 64, 50)),
+    "boundary_inside_a_tile": ((100, 156), (190, 66)),
+    "first_token_only_document": ((1, 255), (1, 1, 126, 1, 127)),
+}
+HEAD_COUNTS = {"grouped": (8, 2), "equal": (4, 4)}
+
+
+@pytest.fixture
+def kernel_tiles(monkeypatch):
+    monkeypatch.setattr(
+        flash_lib, "SEGMENT_KERNEL_BLOCKS",
+        dict.fromkeys(flash_lib.SEGMENT_KERNEL_BLOCKS, 128),
+    )
+    monkeypatch.setattr(flash_lib, "SEGMENT_BLOCK_Q", 64)
+
+
+def _packing(name):
+    """segment_ids [2, KERNEL_SEQ]; what a row's documents leave is padding (0)."""
+    return jnp.asarray(_segments(PACKINGS[name], KERNEL_SEQ))
+
+
+def _qkv(heads, kv_heads, dtype=jnp.float32, dim=16):
+    rng = np.random.RandomState(heads)
+    return tuple(
+        jnp.asarray(rng.randn(2, KERNEL_SEQ, n, dim), dtype)
+        for n in (heads, kv_heads, kv_heads)
+    )
+
+
+@pytest.mark.parametrize("heads", HEAD_COUNTS)
+@pytest.mark.parametrize("packing", PACKINGS)
+def test_segment_kernel_forward_is_masked_attention(kernel_tiles, packing, heads):
+    q, k, v = _qkv(*HEAD_COUNTS[heads])
+    segments = _packing(packing)
+    got = flash_lib._segment_kernel(q, k, v, segments, 0.25, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_masked_attention(q, k, v, segments, 0.25)),
+        rtol=1e-5, atol=1e-5,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(flash_lib._segment_einsum(q, k, v, segments, 0.25)),
+        rtol=1e-5, atol=1e-5,
+    )
+
+
+@pytest.mark.parametrize("heads", HEAD_COUNTS)
+@pytest.mark.parametrize("packing", PACKINGS)
+def test_segment_kernel_gradients_are_masked_attentions(kernel_tiles, packing, heads):
+    q, k, v = _qkv(*HEAD_COUNTS[heads])
+    segments = _packing(packing)
+    weight = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+
+    def gradients(attend):
+        return jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v, segments, 0.25) * weight),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    got = gradients(lambda *a: flash_lib._segment_kernel(*a, interpret=True))
+    for name, g, plain, einsum in zip(
+        "qkv", got, gradients(_masked_attention),
+        gradients(flash_lib._segment_einsum),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(plain), rtol=1e-4, atol=1e-4, err_msg=name
+        )
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(einsum), rtol=1e-4, atol=1e-4, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("scale", [2 ** -6, 0.3])
+def test_segment_kernel_in_bfloat16_follows_the_einsum(kernel_tiles, scale):
+    """At the dtype the kernel path is chosen for. A scale that is no power
+    of two costs the scaled query one more rounding."""
+    q, k, v = _qkv(8, 2, jnp.bfloat16)
+    segments = _packing("several_documents")
+    weight = jnp.asarray(np.random.RandomState(1).randn(*q.shape), jnp.float32)
+
+    def both(attend):
+        def loss(q, k, v):
+            out = attend(q, k, v, segments, scale)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v
+        )
+        return (out,) + grads
+
+    got = both(lambda *a: flash_lib._segment_kernel(*a, interpret=True))
+    want = both(lambda q, k, v, s, scale: _masked_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), s, scale
+    ))
+    for name, g, w, e in zip(
+        ("out", "dq", "dk", "dv"), got, want, both(flash_lib._segment_einsum)
+    ):
+        assert g.dtype == jnp.bfloat16, name
+        g, w, e = (np.asarray(x, np.float32) for x in (g, w, e))
+        size = np.abs(w).max()
+        assert np.abs(g - w).max() < 0.02 * size, name
+        # No farther from the plain float32 form than the einsum path is.
+        assert np.abs(g - w).max() < 2 * np.abs(e - w).max() + 1e-3 * size, name
+
+
+def test_segment_attention_takes_the_kernel_on_a_tpu_only_and_only_where_it_tiles(
+    kernel_tiles, monkeypatch
+):
+    segments = _packing("several_documents")
+    q, k, v = _qkv(8, 2, jnp.bfloat16)
+
+    def attend(q, k, v, segments=segments):
+        return flash_lib.segment_attention(q, k, v, segments, scale=0.25)
+
+    # Tiled and bfloat16: the platform chooses, and this one is no TPU. The
+    # kernel was traced without `interpret`, so lowering it here would raise.
+    assert "platform_index" in str(jax.make_jaxpr(attend)(q, k, v))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(attend)(q, k, v), np.float32),
+        np.asarray(flash_lib._segment_einsum(q, k, v, segments, 0.25), np.float32),
+    )
+    # Lowered for a TPU, the kernels carry it: no logits in the program.
+    lowered = jax.jit(attend).trace(q, k, v).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert f"{KERNEL_SEQ}x{KERNEL_SEQ}" not in lowered.as_text()
+    both = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2),
+    )).trace(q, k, v).lower(lowering_platforms=("tpu",)).as_text()
+    assert both.count("tpu_custom_call") == 2  # forward; dq, dk and dv fused
+
+    # Ragged, shorter than a tile, or float32: the einsum, whatever the platform.
+    def kernel_refused(*args, **kwargs):
+        raise AssertionError("the kernel path was traced")
+
+    monkeypatch.setattr(flash_lib, "_segment_kernel", kernel_refused)
+    for cut, dtype in ((200, jnp.bfloat16), (64, jnp.bfloat16), (256, jnp.float32)):
+        args = [x[:, :cut].astype(dtype) for x in (q, k, v)]
+        jaxpr = str(jax.make_jaxpr(attend)(*args, segments[:, :cut]))
+        assert "platform_index" not in jaxpr, (cut, dtype)
+
+
+def test_attention_layer_gives_the_same_through_kernel_and_einsum(
+    kernel_tiles, monkeypatch
+):
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(2, KERNEL_SEQ, 32), jnp.float32)
+    segments = _packing("several_documents")
+    module = MultiHeadAttention(
+        num_heads=4, head_dim=16, num_kv_heads=2, scale=0.125, dtype=jnp.bfloat16
+    )
+    variables = module.init(jax.random.PRNGKey(0), x, segments)
+    through_einsum = module.apply(variables, x, segments)
+
+    calls = []
+
+    def interpreted(*args, **kwargs):
+        calls.append(args[0].shape)
+        return flash_lib._segment_kernel(*args, interpret=True, **kwargs)
+
+    # The platform's choice falls on the default branch here: put the kernel there.
+    monkeypatch.setattr(flash_lib, "_segment_einsum", interpreted)
+    through_kernel = module.apply(variables, x, segments)
+    assert calls == [(2, KERNEL_SEQ, 4, 16)]
+    assert through_kernel.dtype == through_einsum.dtype
+    np.testing.assert_allclose(
+        np.asarray(through_kernel, np.float32),
+        np.asarray(through_einsum, np.float32), rtol=0.02, atol=0.02,
+    )
 
 
 def test_one_segment_is_plain_causal_attention_at_the_given_scale():
