@@ -237,16 +237,6 @@ def _analytic_train_flops(
     return flops * 3.0
 
 
-def _pool_backward_mode() -> str:
-    """Which pool VJP this process traced with (ops/pooling.max_pool)."""
-    from tensor2robot_tpu.ops.pooling import resolve_backward_mode
-
-    resolved = resolve_backward_mode()
-    if t2r_flags.get_enum("T2R_POOL_BACKWARD") == "auto":
-        return f"auto:{resolved}"
-    return resolved
-
-
 def _stem_s2d() -> bool:
     """Whether the stem traced with the space-to-depth lowering."""
     from tensor2robot_tpu.layers.s2d_conv import stem_s2d_enabled
@@ -1133,12 +1123,7 @@ def bench_bc() -> None:
                 model.get_label_specification("train"), batch_size=batch
             ),
         }
-        compiled = CompiledModel(
-            model, donate_state=True,
-            flatten_optimizer_update=(
-                os.environ.get("BENCH_FLAT_OPT", "1") != "0"
-            ),
-        )
+        compiled = CompiledModel(model, donate_state=True)
         state = compiled.init_state(jax.random.PRNGKey(0), batch_np)
         sharded = compiled.shard_batch(batch_np)
         rng = jax.random.PRNGKey(1)
@@ -1370,9 +1355,7 @@ def bench_pipe() -> None:
             ),
             "labels": model.preprocessor.get_in_label_specification("train"),
         }
-        compiled = CompiledModel(
-            model, donate_state=True, flatten_optimizer_update=True
-        )
+        compiled = CompiledModel(model, donate_state=True)
         state = compiled.init_state(jax.random.PRNGKey(0), batch)
         resident = compiled.shard_batch(batch)
         rng = jax.random.PRNGKey(1)
@@ -5750,14 +5733,10 @@ def main() -> None:
         )
     # BENCH_WIDTH != 64 runs the MXU-width-aligned tower twin (the c128
     # half of the two-number ceiling proof) under a distinct metric name.
-    # BENCH_FUSE_STATS=0 opts out of the fused batch-stats update (the
-    # on-chip A/B against the default; distinct metric name).
-    env_fuse_stats = os.environ.get("BENCH_FUSE_STATS")
     intended_metric = (
         f"qtopt_critic_train_mfu_bs{env_batch}_472px"
         + (f"_c{env_width}" if env_width != 64 else "")
         + ("_remat" if use_remat else "")
-        + ("_nofusestats" if env_fuse_stats == "0" else "")
     )
 
     devices = _devices(intended_metric)
@@ -5795,25 +5774,12 @@ def main() -> None:
 
         # Same construction the driver's dryrun exercises — the bench must
         # measure the workload the compile checks validate. State donation
-        # lets XLA alias param/opt buffers in place across steps. The
-        # optimizer update runs flattened by default (BENCH_FLAT_OPT=0
-        # opts out): one fused whole-model Adam instead of per-leaf small
-        # kernels, which the round-3 profile showed paying ~1-4 ms each
-        # on this backend.
-        flat_opt = os.environ.get("BENCH_FLAT_OPT", "1") != "0"
+        # lets XLA alias param/opt buffers in place across steps.
         model, batch = _flagship(
             image_size=image_size, batch_size=batch_size,
             num_convs=num_convs, width=width,
         )
-        compiled = CompiledModel(
-            model, donate_state=True, remat=use_remat,
-            flatten_optimizer_update=flat_opt,
-            **(
-                {"fuse_batch_stats_update": env_fuse_stats != "0"}
-                if env_fuse_stats is not None
-                else {}
-            ),
-        )
+        compiled = CompiledModel(model, donate_state=True, remat=use_remat)
         state = compiled.init_state(jax.random.PRNGKey(0), batch)
         sharded = compiled.shard_batch(batch)
         rng = jax.random.PRNGKey(1)
@@ -6009,9 +5975,6 @@ def main() -> None:
                     "batch_size": batch_size,
                     "tower_width": width,
                     "remat": use_remat,
-                    "flat_optimizer_update": flat_opt,
-                    "fuse_batch_stats_update": compiled._fuse_stats,
-                    "pool_backward": _pool_backward_mode(),
                     "stem_s2d": _stem_s2d(),
                 },
                 **_proxy_fields(on_tpu, "qtopt_critic_train_mfu"),
@@ -6404,7 +6367,7 @@ def _build_cli():
         ),
         epilog=(
             "headline env knobs: BENCH_BATCH, BENCH_WIDTH, BENCH_REMAT, "
-            "BENCH_FLAT_OPT, BENCH_FUSE_STATS, BENCH_SCAN_K, "
+            "BENCH_SCAN_K, "
             "BENCH_SKIP_SCAN, BENCH_SKIP_INFEED, BENCH_PROFILE_DIR, "
             "BENCH_BACKEND_WAIT"
         ),
@@ -6439,7 +6402,7 @@ def _build_cli():
     leg(
         "bc", lambda a: bench_bc(),
         "transformer-BC train throughput",
-        epilog="env knobs: BENCH_BC_WINDOW, BENCH_FLAT_OPT",
+        epilog="env knobs: BENCH_BC_WINDOW",
     )
     leg(
         "stream", lambda a: bench_stream(),

@@ -241,7 +241,7 @@ class Exporter:
         generator = self._export_generator
         generator.set_specification_from_model(model)
         use_ema = getattr(model, "use_avg_model_params", False)
-        variables = compiled.export_variables(state, use_ema=use_ema)
+        variables = state.export_variables(use_ema=use_ema)
         serving_fn = generator.create_serving_fn(
             compiled, variables, quantize_weights=self._quantize_weights,
             quantize_bits=self._quantize_bits,

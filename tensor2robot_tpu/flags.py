@@ -502,14 +502,6 @@ _declare(
     minimum=0,
 )
 _declare(
-    "T2R_POOL_BACKWARD",
-    _ENUM,
-    "auto",
-    "Max-pool VJP path; auto dispatches per lowering platform.",
-    "tensor2robot_tpu/ops/pooling.py",
-    choices=("auto", "native", "scatterfree"),
-)
-_declare(
     "T2R_REPLAY_RETRIES",
     _INT,
     5,

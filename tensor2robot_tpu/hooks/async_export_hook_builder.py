@@ -42,9 +42,7 @@ def default_create_export_fn(
 
     def export_fn(state, export_dir: str, global_step: int) -> str:
         use_ema = getattr(model, "use_avg_model_params", False)
-        # compiled.export_variables: per-step submissions may carry the
-        # live fused-stats state; the export must see the tree layout.
-        variables = compiled.export_variables(state, use_ema=use_ema)
+        variables = state.export_variables(use_ema=use_ema)
         serving_fn = generator.create_serving_fn(
             compiled, variables, quantize_weights=quantize_weights,
             quantize_bits=quantize_bits,

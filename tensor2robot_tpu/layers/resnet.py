@@ -16,8 +16,6 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import flax.linen as nn
-
-from tensor2robot_tpu.layers.batch_norm import BatchNorm
 import jax
 import jax.numpy as jnp
 
@@ -74,7 +72,7 @@ class _ConvFixedPadding(nn.Module):
 class _BatchNorm(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool) -> jax.Array:
-        return BatchNorm(
+        return nn.BatchNorm(
             use_running_average=not train,
             momentum=0.997,
             epsilon=1e-5,

@@ -16,8 +16,6 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
-
-from tensor2robot_tpu.layers.batch_norm import BatchNorm
 import jax
 import jax.numpy as jnp
 
@@ -65,7 +63,7 @@ class ImagesToFeaturesNet(nn.Module):
         if self.normalizer == "layer_norm":
             return nn.LayerNorm(use_scale=scale, name=f"norm_{idx}")(x)
         if self.normalizer == "batch_norm":
-            return BatchNorm(
+            return nn.BatchNorm(
                 use_running_average=not train,
                 momentum=0.99,
                 epsilon=1e-4,
@@ -158,9 +156,6 @@ class ImagesToFeaturesHighResNet(nn.Module):
         net = nn.relu(nn.LayerNorm(name="norm2")(net))
         block_outs.append(nn.Conv(32, (1, 1), name="conv2_1x1")(net))
         for i in range(1, self.num_blocks):
-            # Non-overlapping pool: backend-dispatched backward
-            # (ops/pooling.py; SelectAndScatter on TPU per the round-5
-            # on-chip A/B, not re-measured).
             net = pooling.max_pool(net, (2, 2), "VALID")
             net = nn.Conv(
                 32,

@@ -253,11 +253,6 @@ class FlaxT2RModel(AbstractT2RModel):
     # Networks whose __call__ accepts (features, mode, labels) — e.g. models
     # with density-decoder heads — set this True to receive packed labels.
     _NETWORK_TAKES_LABELS = False
-    # Set by CompiledModel(fuse_batch_stats_update=True): TRAIN applies open
-    # the 'batch_stats_new' collection so layers.batch_norm.BatchNorm
-    # defers its running-stats EMA to the trainer's single fused
-    # cross-layer update instead of per-layer in-place axpys.
-    defer_batch_stats_update: bool = False
 
     @abc.abstractmethod
     def create_network(self) -> "flax.linen.Module":
@@ -313,12 +308,6 @@ class FlaxT2RModel(AbstractT2RModel):
                 for c in self._extra_mutable_collections(mode)
                 if c not in mutable
             ]
-            if (
-                getattr(self, "defer_batch_stats_update", False)
-                and "batch_stats" in variables
-                and "batch_stats_new" not in mutable
-            ):
-                mutable = mutable + ["batch_stats_new"]
         if mode == MODE_TRAIN and mutable:
             outputs, updates = self.network.apply(
                 variables, *args, mutable=mutable, rngs=rngs

@@ -123,9 +123,9 @@ def load_checkpoint_variables(
                 "ema_params (trained without use_avg_model_params)."
             )
         variables = dict(variables)
-        # ema_as_tree: a flat-EMA checkpoint (flatten_optimizer_update)
-        # stores one 1-D vector; unravel it against the checkpoint's own
-        # params structure before path-based matching sees it.
+        # ema_as_tree: a quantized ZeRO-2 checkpoint stores the EMA as
+        # one block-padded 1-D vector; unravel it against the checkpoint's
+        # own params structure before path-based matching sees it.
         from tensor2robot_tpu.train.state import ema_as_tree
 
         variables["params"] = ema_as_tree(

@@ -146,9 +146,9 @@ class CheckpointPredictor(AbstractPredictor):
                                 self._use_ema
                                 and restored.get("ema_params") is not None
                             ):
-                                # ema_as_tree: a flat-EMA checkpoint
-                                # (flatten_optimizer_update) stores one
-                                # 1-D vector, not a params tree.
+                                # ema_as_tree: a quantized ZeRO-2
+                                # checkpoint stores the EMA as one
+                                # block-padded 1-D vector, not a tree.
                                 variables["params"] = state_lib.ema_as_tree(
                                     restored["ema_params"],
                                     variables["params"],

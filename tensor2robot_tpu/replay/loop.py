@@ -150,7 +150,7 @@ class PublishPolicyHook(Hook):
 
 class _PublishHookBuilder(HookBuilder):
     """Hands the trainer's CompiledModel to the loop (the export path
-    needs export_variables) and installs the publish hook."""
+    traces its predict step) and installs the publish hook."""
 
     def __init__(
         self,

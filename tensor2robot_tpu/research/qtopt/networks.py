@@ -29,7 +29,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tensor2robot_tpu.layers.batch_norm import BatchNorm
 from tensor2robot_tpu.layers.s2d_conv import SpaceToDepthConv, stem_s2d_enabled
 from tensor2robot_tpu.ops import pooling
 
@@ -77,7 +76,7 @@ class _ConvBNRelu(nn.Module):
             kernel_init=_CONV_INIT,
             dtype=self.dtype,
         )(x)
-        x = BatchNorm(
+        x = nn.BatchNorm(
             use_running_average=not is_training,
             momentum=self.momentum,
             epsilon=self.epsilon,
@@ -161,12 +160,8 @@ class Grasping44(nn.Module):
                 use_bias=False, kernel_init=_CONV_INIT, name="conv1_1",
                 dtype=dtype,
             )(images)
-        net = BatchNorm(use_scale=False, name="bn1", **bn_kwargs)(net)
+        net = nn.BatchNorm(use_scale=False, name="bn1", **bn_kwargs)(net)
         net = nn.relu(net)
-        # Non-overlapping pools dispatch the backward on the backend:
-        # SelectAndScatter on TPU, scatter-free elsewhere (ops/pooling.py;
-        # round-5 on-chip A/B, not re-measured). Forward is bit-identical
-        # to nn.max_pool either way.
         net = pooling.max_pool(net, (3, 3))
 
         for i in range(self.num_convs[0]):
@@ -193,14 +188,14 @@ class Grasping44(nn.Module):
                 grasp_params[:, offset : offset + size]
             )
             fcgrasp = piece if fcgrasp is None else fcgrasp + piece
-        fcgrasp = BatchNorm(use_scale=False, name="bn_fcgrasp", **bn_kwargs)(
+        fcgrasp = nn.BatchNorm(use_scale=False, name="bn_fcgrasp", **bn_kwargs)(
             fcgrasp
         )
         fcgrasp = nn.relu(fcgrasp)
         fcgrasp = nn.Dense(
             self.width, kernel_init=_CONV_INIT, name="fcgrasp2", dtype=dtype
         )(fcgrasp)
-        fcgrasp = BatchNorm(name="bn_fcgrasp2", **bn_kwargs)(fcgrasp)
+        fcgrasp = nn.BatchNorm(name="bn_fcgrasp2", **bn_kwargs)(fcgrasp)
         fcgrasp = nn.relu(fcgrasp)
         end_points["fcgrasp"] = fcgrasp
         context = fcgrasp.reshape(-1, 1, 1, self.width)
@@ -247,7 +242,7 @@ class Grasping44(nn.Module):
             net = nn.Dense(64, kernel_init=_CONV_INIT, name=f"fc{i}", dtype=dtype)(
                 net
             )
-            net = BatchNorm(name=f"bn_fc{i}", **bn_kwargs)(net)
+            net = nn.BatchNorm(name=f"bn_fc{i}", **bn_kwargs)(net)
             net = nn.relu(net)
 
         # Logit head computes and emits float32: the loss-bearing scalar

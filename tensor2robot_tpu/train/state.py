@@ -20,7 +20,8 @@ import optax
 
 def _is_flat_ema(ema) -> bool:
     """True when the EMA is stored as one concatenated vector (the
-    flatten_optimizer_update regime) rather than a params-shaped tree."""
+    quantized ZeRO-2 regime, train_eval.CompiledModel._init_quant_state)
+    rather than a params-shaped tree."""
     return hasattr(ema, "ndim") and ema.ndim == 1
 
 
@@ -29,12 +30,12 @@ def ema_as_tree(ema_params, params_tree):
 
     Every consumer that reads ema_params — live state, restored
     checkpoints (predictors, warm start) — must route through this, not
-    use the raw value: a flat-stored EMA (flatten_optimizer_update
-    regime) is a single 1-D vector that only this unravel, against the
-    matching params structure, turns back into variables. A flat EMA
-    longer than the parameter count is the quantized-collective regime's
-    block-padded layout (parallel/collectives.FlatShardLayout); the
-    zero-gradient tail never moves and is dropped here."""
+    use the raw value: the quantized ZeRO-2 regime (the only producer
+    of a flat EMA) stores it as a single 1-D vector that only this
+    unravel, against the matching params structure, turns back into
+    variables. That vector is block-padded past the parameter count
+    (parallel/collectives.FlatShardLayout); the zero-gradient tail
+    never moves and is dropped here."""
     if _is_flat_ema(ema_params):
         flat, unravel = jax.flatten_util.ravel_pytree(params_tree)
         if ema_params.shape[0] > flat.shape[0]:
@@ -64,7 +65,7 @@ class TrainState:
     def export_variables(self, use_ema: bool = False) -> Dict[str, Any]:
         """Variables to serve/export: EMA params when present and requested.
 
-        A flat-stored EMA (one concatenated vector; see update_ema) is
+        A flat-stored EMA (one concatenated vector; see ema_as_tree) is
         unraveled here against the live params' structure — export/eval
         is the only place the EMA is ever needed as a tree."""
         if use_ema and self.ema_params is not None:
@@ -79,22 +80,13 @@ def create_train_state(
     rng: jax.Array,
     example_features,
     optimizer: optax.GradientTransformation,
-    flat_ema: bool = False,
 ) -> TrainState:
-    """Initializes variables (with warm-start hook) + optimizer state.
-
-    flat_ema stores the EMA as one concatenated vector (see update_ema);
-    like optax.flatten it changes the checkpoint layout, so it is only
-    set by the flatten_optimizer_update regime."""
+    """Initializes variables (with warm-start hook) + optimizer state."""
     variables = model.init_variables(rng, example_features)
     variables = model.maybe_init_from_checkpoint(variables)
     opt_state = optimizer.init(variables["params"])
     if getattr(model, "use_avg_model_params", False):
-        ema = (
-            jax.flatten_util.ravel_pytree(variables["params"])[0]
-            if flat_ema
-            else jax.tree_util.tree_map(jnp.copy, variables["params"])
-        )
+        ema = jax.tree_util.tree_map(jnp.copy, variables["params"])
     else:
         ema = None
     return TrainState(
@@ -106,17 +98,7 @@ def create_train_state(
 
 
 def update_ema(ema_params, new_params, decay: float):
-    """One EMA step. Tree-shaped EMA updates leaf-wise; a flat-stored EMA
-    (flatten_optimizer_update regime) updates as ONE fused axpy over the
-    concatenated parameter vector — the per-leaf form compiles to one
-    small kernel per parameter, which on a backend with fixed per-kernel
-    latency costs more than the math (same rationale as optax.flatten,
-    CompiledModel docstring)."""
-    if _is_flat_ema(ema_params):
-        flat = jax.flatten_util.ravel_pytree(new_params)[0]
-        return ema_params * decay + flat.astype(ema_params.dtype) * (
-            1.0 - decay
-        )
+    """One EMA step, leaf by leaf."""
     return jax.tree_util.tree_map(
         lambda e, p: e * decay + p.astype(e.dtype) * (1.0 - decay),
         ema_params,

@@ -152,61 +152,6 @@ def maybe_wrap_for_tpu(model: AbstractT2RModel) -> AbstractT2RModel:
     return model
 
 
-def _is_flat_stats(stats) -> bool:
-    """True when batch_stats is the fused one-vector form
-    (CompiledModel(fuse_batch_stats_update=True) live states)."""
-    return getattr(stats, "ndim", None) == 1
-
-
-def _stats_update_trees(template, new_col):
-    """(new_stats, decay) trees in `template`'s structure, pulled from the
-    deferred 'batch_stats_new' collection — whose entries mirror the
-    batch_stats paths with 'mean'/'var' plus a per-layer 'momentum'."""
-
-    def lookup(path, _leaf):
-        node = new_col
-        for entry in path:
-            node = node[entry.key]
-        return node
-
-    def decay(path, leaf):
-        node = new_col
-        for entry in path[:-1]:
-            node = node[entry.key]
-        return jnp.broadcast_to(
-            node["momentum"], getattr(leaf, "shape", ())
-        )
-
-    new_tree = jax.tree_util.tree_map_with_path(lookup, template)
-    decay_tree = jax.tree_util.tree_map_with_path(decay, template)
-    return new_tree, decay_tree
-
-
-def _apply_stats_update(old_stats, new_col, flat_template):
-    """Batch-norm running-stats EMA from the deferred collection.
-
-    Flat old stats (fused regime): the whole network's update is ONE
-    concatenated axpy — ~2 kernels instead of ~2 tiny [C]-vector kernels
-    per BN layer (the same fixed-per-kernel-latency rationale as
-    optax.flatten; measured shapes in tests/test_train_eval.py's
-    kernel-count pin). Tree old stats: per-leaf axpys, the
-    flax-equivalent fallback for a non-fused trainer driving a model
-    whose deferral switch another CompiledModel enabled. Both forms
-    compute momentum*old + (1-momentum)*new per element."""
-    if _is_flat_stats(old_stats):
-        new_tree, decay_tree = _stats_update_trees(flat_template, new_col)
-        flat_new = jax.flatten_util.ravel_pytree(new_tree)[0]
-        flat_decay = jax.flatten_util.ravel_pytree(decay_tree)[0]
-        return old_stats * flat_decay + flat_new * (1.0 - flat_decay)
-    new_tree, decay_tree = _stats_update_trees(old_stats, new_col)
-    return jax.tree_util.tree_map(
-        lambda o, n, d: o * d + n * (1.0 - d),
-        old_stats,
-        new_tree,
-        decay_tree,
-    )
-
-
 def _batch_labels(batch):
     """The batch's labels subtree, or None for label-less (self-supervised)
     models whose generators emit no 'labels' keys — grasp2vec's empty
@@ -387,8 +332,6 @@ class CompiledModel:
         remat: bool = False,
         grad_accum_steps: int = 1,
         shard_weight_update: bool = False,
-        flatten_optimizer_update: bool = False,
-        fuse_batch_stats_update: Optional[bool] = None,
         collective_quant: Optional[str] = None,
         collective_block: Optional[int] = None,
         weight_update_axes: Optional[Sequence[str]] = None,
@@ -415,42 +358,6 @@ class CompiledModel:
           norm computes statistics per MICRObatch (the standard
           grad-accumulation behavior), so BN models are not bit-identical
           to the unaccumulated step.
-        flatten_optimizer_update: apply the optimizer on ONE concatenated
-          parameter vector (optax.flatten) instead of leaf by leaf. For
-          elementwise transforms (Adam & friends) the math is identical,
-          but the update compiles to a handful of whole-model fused ops
-          instead of ~3 small kernels PER PARAMETER — the round-3 TPU
-          profile showed those small per-leaf update kernels costing
-          0.9-4 ms each (a 4 ms Adam update on a 28 KB entry-conv kernel)
-          on a backend where tiny ops pay a fixed latency. The EMA mirror
-          is stored flat in the same regime (one fused axpy per step
-          instead of one kernel per parameter; unraveled only at
-          export/eval — train/state.py update_ema). Changes the
-          opt_state/ema pytree structure (checkpoints are not
-          interchangeable with the unflattened layout) and is rejected in
-          sharded-param regimes, where moments must follow the parameter
-          sharding.
-        fuse_batch_stats_update: same per-kernel-latency rationale applied
-          to batch-norm running statistics. The LIVE train state stores
-          'batch_stats' as ONE concatenated vector; layers.batch_norm
-          defers each layer's stats to the 'batch_stats_new' collection
-          and the step applies every layer's EMA in one fused axpy
-          (~2 kernels) instead of ~2 tiny kernels per BN layer. Train-mode
-          forwards never read running stats, so nothing else in the step
-          changes. The ON-DISK checkpoint layout is unchanged: saves go
-          through persistable_state (tree form) and restores through
-          fuse_state, and eval/export unravel on the fly. Defaults to
-          flatten_optimizer_update; requires the model's BNs to be
-          layers.batch_norm.BatchNorm (the Grasping44 tower is) — a
-          plain flax BN under this regime raises at trace time rather
-          than silently freezing its stats. Caveat: enabling this sets
-          the deferral switch ON THE MODEL OBJECT, so a non-fused
-          CompiledModel constructed later over the SAME model instance
-          traces the deferred collection too (its train_step then
-          applies the per-leaf fallback of _apply_stats_update —
-          numerically the same EMA, different fusion). Use separate
-          model instances when exact cross-trainer HLO stability
-          matters.
         collective_quant / collective_block: wire format for the ZeRO-2
           gradient collectives (parallel/collectives.py). None reads the
           central T2R_COLLECTIVE_QUANT / T2R_COLLECTIVE_BLOCK flags;
@@ -508,36 +415,6 @@ class CompiledModel:
         self.mesh = mesh if mesh is not None else mesh_lib.make_mesh()
         self.preprocessor = model.preprocessor
         self.optimizer = model.create_optimizer()
-        if flatten_optimizer_update:
-            if (
-                self.mesh.shape[mesh_lib.FSDP_AXIS] > 1
-                or self.mesh.shape[mesh_lib.MODEL_AXIS] > 1
-                or shard_weight_update
-            ):
-                raise ValueError(
-                    "flatten_optimizer_update concatenates all parameters "
-                    "into one replicated vector, which defeats "
-                    "fsdp/tensor-parallel parameter sharding and ZeRO-2 "
-                    "weight-update sharding; use it only in replicated-"
-                    "parameter regimes."
-                )
-            self.optimizer = optax.flatten(self.optimizer)
-        self._flat_ema = flatten_optimizer_update
-        self._fuse_stats = (
-            flatten_optimizer_update
-            if fuse_batch_stats_update is None
-            else fuse_batch_stats_update
-        )
-        if self._fuse_stats:
-            # The deferral switch lives on the model (the wrapper delegates
-            # inference to the inner model, so set both): TRAIN applies
-            # open 'batch_stats_new' and layers.batch_norm defers.
-            for m in (model, getattr(model, "_model", None)):
-                if m is not None:
-                    m.defer_batch_stats_update = True
-        # Set by init_state when the model actually carries batch stats.
-        self._stats_template = None
-        self._stats_unravel = None
         self._donate = donate_state
         self._param_min_shard_size = param_min_shard_size
         self._shard_weight_update = shard_weight_update
@@ -580,13 +457,6 @@ class CompiledModel:
             and pure_data_parallel
             and self.mesh.shape[mesh_lib.DATA_AXIS] > 1
         ):
-            if self._fuse_stats:
-                raise ValueError(
-                    "fuse_batch_stats_update is unsupported with quantized "
-                    "collectives: the quantized ZeRO-2 step already runs "
-                    "per-shard on the flat parameter vector and averages "
-                    "batch-norm statistics across replicas itself."
-                )
             self._quant_collective = collectives.get_collective(
                 quant_name, quant_block
             )
@@ -749,48 +619,16 @@ class CompiledModel:
                 batch["features"], _batch_labels(batch),
                 mode=MODE_TRAIN, rng=rng_pre,
             )
-            # Fused-stats regime: the live state's batch_stats is one flat
-            # vector. The train forward never READS running stats, but
-            # flax needs the collection tree present — hand it dead zeros
-            # (DCE'd by XLA) and drop the unchanged tree from the mutable
-            # merge below.
-            live_stats = state.variables.get("batch_stats")
-            stats_fused = _is_flat_stats(live_stats)
-            fwd_state = state
-            if stats_fused:
-                fwd_variables = dict(state.variables)
-                fwd_variables["batch_stats"] = jax.tree_util.tree_map(
-                    lambda s: jnp.zeros(s.shape, s.dtype),
-                    self._stats_template,
-                )
-                fwd_state = state.replace(variables=fwd_variables)
             loss, train_metrics, mutable, grads = accumulated_grads(
-                fwd_state, features, labels, rng_net
+                state, features, labels, rng_net
             )
             updates, opt_state = self.optimizer.update(
                 grads, state.opt_state, state.params
             )
             params = optax.apply_updates(state.params, updates)
-            new_stats = mutable.pop("batch_stats_new", None)
-            if stats_fused:
-                mutable.pop("batch_stats", None)
-                if not new_stats:
-                    raise ValueError(
-                        "fuse_batch_stats_update is on but no layer wrote "
-                        "'batch_stats_new' — the model's batch norms must "
-                        "be layers.batch_norm.BatchNorm (plain flax "
-                        "BatchNorm would silently freeze its running "
-                        "stats in this regime)."
-                    )
             variables = dict(state.variables)
             variables.update(mutable)
             variables["params"] = params
-            if new_stats:
-                variables["batch_stats"] = _apply_stats_update(
-                    variables["batch_stats"],
-                    new_stats,
-                    self._stats_template if stats_fused else None,
-                )
             ema = state.ema_params
             if ema is not None:
                 ema = update_ema(ema, params, model.avg_model_params_decay)
@@ -809,14 +647,7 @@ class CompiledModel:
                 batch["features"], _batch_labels(batch),
                 mode=MODE_EVAL, rng=None,
             )
-            variables = dict(state.export_variables(use_ema=use_ema))
-            if _is_flat_stats(variables.get("batch_stats")):
-                # Fused live state: eval DOES read running stats —
-                # unravel to the canonical tree (slices; eval cadence
-                # only, never inside the train step).
-                variables["batch_stats"] = self._stats_unravel(
-                    variables["batch_stats"]
-                )
+            variables = state.export_variables(use_ema=use_ema)
             f, l, outputs, _ = model.packed_inference(
                 variables, features, MODE_EVAL, labels=labels
             )
@@ -909,21 +740,14 @@ class CompiledModel:
                 params = self._flat_unravel(
                     layout.unpad(flat_params + full_update)
                 )
-                new_stats = mutable.pop("batch_stats_new", None)
                 # Per-replica batch-norm statistics average across the
                 # data axis — exact for the means; the variance-of-means
                 # term is the standard local-BN caveat (same family as
                 # grad-accum's per-microbatch statistics).
                 mutable = collectives.pmean(mutable, axis)
-                if new_stats is not None:
-                    new_stats = collectives.pmean(new_stats, axis)
                 variables = dict(state.variables)
                 variables.update(mutable)
                 variables["params"] = params
-                if new_stats:
-                    variables["batch_stats"] = _apply_stats_update(
-                        variables["batch_stats"], new_stats, None
-                    )
                 ema = state.ema_params
                 if ema is not None:
                     # The EMA mirror follows the flat sharded layout: each
@@ -1028,22 +852,7 @@ class CompiledModel:
         with tracing.span("train.init_state.model_init"):
             state = create_train_state(
                 self.model, rng, features, self.optimizer,
-                flat_ema=self._flat_ema,
             )
-        if self._fuse_stats:
-            stats = state.variables.get("batch_stats")
-            if isinstance(stats, dict) and stats:
-                self._stats_template = jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), stats
-                )
-                flat, unravel = jax.flatten_util.ravel_pytree(stats)
-                self._stats_unravel = unravel
-                variables = dict(state.variables)
-                variables["batch_stats"] = flat
-                state = state.replace(variables=variables)
-            else:
-                # No batch statistics in this model; nothing to fuse.
-                self._fuse_stats = False
 
         def place(tree, base_rule):
             # Pipeline-stage placement layers over every regime: leaves
@@ -1112,10 +921,9 @@ class CompiledModel:
         as in the GSPMD regime; optimizer state and the EMA mirror move to
         the FLAT block-padded parameter vector, sharded over the data axis
         (each replica owns the slice its shard_map step updates), and the
-        error-feedback residual joins the state as zeros. Like
-        flatten_optimizer_update, this changes the opt-state checkpoint
-        layout — checkpoints are not interchangeable with the tree-layout
-        regimes.
+        error-feedback residual joins the state as zeros. This changes
+        the opt-state and EMA checkpoint layout — checkpoints are not
+        interchangeable with the tree-layout regimes.
         """
         mesh = self.mesh
         num_shards = mesh.shape[mesh_lib.DATA_AXIS]
@@ -1241,44 +1049,6 @@ class CompiledModel:
     def shard_batch(self, batch):
         return mesh_lib.shard_batch(batch, self.mesh)
 
-    def export_variables(self, state: TrainState, use_ema: bool = False):
-        """state.export_variables with fused (flat) batch_stats unraveled
-        to the canonical tree — the form every serving/export consumer
-        expects. Identity on non-fused states."""
-        variables = dict(state.export_variables(use_ema=use_ema))
-        if _is_flat_stats(variables.get("batch_stats")):
-            variables["batch_stats"] = self._stats_unravel(
-                variables["batch_stats"]
-            )
-        return variables
-
-    def persistable_state(self, state: TrainState) -> TrainState:
-        """Checkpoint/hook-boundary form of a fused-stats state: the flat
-        batch_stats vector back as the canonical tree, so the ON-DISK
-        layout never changes and hooks/exporters see ordinary variables.
-        No-op for non-fused states."""
-        stats = state.variables.get("batch_stats")
-        if not _is_flat_stats(stats):
-            return state
-        variables = dict(state.variables)
-        variables["batch_stats"] = jax.device_put(
-            self._stats_unravel(stats), mesh_lib.replicated(self.mesh)
-        )
-        return state.replace(variables=variables)
-
-    def fuse_state(self, state: TrainState) -> TrainState:
-        """Inverse of persistable_state: tree batch_stats raveled into the
-        live fused form (applied after a checkpoint restore)."""
-        stats = state.variables.get("batch_stats")
-        if not self._fuse_stats or not isinstance(stats, dict) or not stats:
-            return state
-        variables = dict(state.variables)
-        variables["batch_stats"] = jax.device_put(
-            jax.flatten_util.ravel_pytree(stats)[0],
-            mesh_lib.replicated(self.mesh),
-        )
-        return state.replace(variables=variables)
-
 
 # -- checkpointing ------------------------------------------------------------
 
@@ -1312,17 +1082,11 @@ def restore_or_init_state(
     if latest is not None:
         # Chaos site: `restore` (slow-restore delay / exception injection).
         chaos.maybe_fire("restore")
-        # Checkpoints always hold the PERSISTABLE (tree-stats) layout;
-        # restore against that form, then refuse back into the live fused
-        # form if this trainer runs one.
-        template = compiled.persistable_state(state)
         abstract = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
-            template,
+            state,
         )
-        state = compiled.fuse_state(
-            manager.restore(latest, args=ocp.args.StandardRestore(abstract))
-        )
+        state = manager.restore(latest, args=ocp.args.StandardRestore(abstract))
     return state
 
 
@@ -1525,7 +1289,6 @@ def train_eval_model(
     remat: bool = False,
     grad_accum_steps: int = 1,
     shard_weight_update: bool = False,
-    flatten_optimizer_update: bool = False,
     plan: Optional[planner_lib.ShardingPlan] = None,
 ) -> Dict[str, float]:
     """Trains (and periodically evaluates/exports) the model.
@@ -1597,7 +1360,6 @@ def train_eval_model(
     compiled = CompiledModel(
         model, mesh=mesh, remat=remat, grad_accum_steps=grad_accum_steps,
         shard_weight_update=shard_weight_update,
-        flatten_optimizer_update=flatten_optimizer_update,
         plan=plan,
     )
     state = restore_or_init_state(manager, compiled, rng_init, first_batch)
@@ -1623,7 +1385,7 @@ def train_eval_model(
     for builder in hook_builders or []:
         hooks.extend(builder.create_hooks(model, trainer=compiled))
     ctx = HookContext(model=model, model_dir=model_dir, step=start_step,
-                      state=compiled.persistable_state(state))
+                      state=state)
     for hook in hooks:
         hook.on_train_begin(ctx)
 
@@ -1725,9 +1487,6 @@ def train_eval_model(
         # The loop stands still for all of this: the snapshot of the state
         # to host memory, hooks, evals and exporters.
         with tracing.span("train.checkpoint", ordinal=step - 1):
-            # Fused-stats states persist (and face hooks/exporters/eval) in
-            # the canonical tree layout — the on-disk format never changes.
-            state = compiled.persistable_state(state)
             previous_saved = last_saved_step
             # Async save: orbax snapshots device arrays to host memory before
             # returning, then writes in the background — the next scan window
@@ -1866,11 +1625,6 @@ def train_eval_model(
             final_eval = checkpoint_and_eval(state, step)
 
     finally:
-        # The last per-step assignment may have left the live fused form
-        # on the context; terminal hooks (e.g. the async exporter's final
-        # synchronous export) get the canonical layout.
-        if ctx.state is not None:
-            ctx.state = compiled.persistable_state(ctx.state)
         for hook in hooks:
             hook.on_train_end(ctx)
         writer.close()

@@ -12,8 +12,6 @@ from __future__ import annotations
 from typing import Iterator, Optional
 
 import flax.linen as nn
-
-from tensor2robot_tpu.layers.batch_norm import BatchNorm
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,7 +36,7 @@ class _MockNetwork(nn.Module):
         for width in (100, 100):
             x = nn.Dense(width)(x)
             if self.use_batch_norm:
-                x = BatchNorm(
+                x = nn.BatchNorm(
                     use_running_average=(mode != "train"), momentum=0.9
                 )(x)
             x = nn.relu(x)

@@ -57,7 +57,9 @@ def test_tiny_preset_reports_every_stage_on_cpu(tmp_path):
     assert "warm-up bucket restore tiers: {'1': 'aot', '2': 'aot'}" in text
     assert "host codec native={'tfrecord': True, 'jpeg': True}" in text
     assert "all-reduce in the compiled step: True (devices=2)" in text
-    assert "pool backward in the lowered step: scatter-free" in text
+    assert (
+        "pool backward in the lowered step: native select_and_scatter" in text
+    )
     assert f"compile cache dir={tmp_path / 'jax_cache'}" in text
     assert "persistent_misses=0" not in text  # cold cache: it compiled
     assert os.listdir(tmp_path / "jax_cache")

@@ -80,9 +80,7 @@ state = te.restore_or_init_state(
     next(iter(gen.create_dataset("train"))),
 )
 digest = hashlib.sha256()
-for leaf in jax.tree_util.tree_leaves(
-    jax.device_get(compiled.persistable_state(state))
-):
+for leaf in jax.tree_util.tree_leaves(jax.device_get(state)):
     digest.update(np.ascontiguousarray(leaf).tobytes())
 print(
     "STATE_SHA256", digest.hexdigest(), "STEP", int(state.step), flush=True

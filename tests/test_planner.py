@@ -438,9 +438,7 @@ class TestCheckpointRoundtrip:
         )
         manager.save(
             3,
-            args=train_eval.ocp.args.StandardSave(
-                compiled.persistable_state(state)
-            ),
+            args=train_eval.ocp.args.StandardSave(state),
             force=True,
         )
         manager.wait_until_finished()
@@ -469,9 +467,7 @@ class TestCheckpointRoundtrip:
         )
         manager.save(
             2,
-            args=train_eval.ocp.args.StandardSave(
-                compiled.persistable_state(state)
-            ),
+            args=train_eval.ocp.args.StandardSave(state),
             force=True,
         )
         manager.wait_until_finished()

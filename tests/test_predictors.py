@@ -281,26 +281,55 @@ class TestCheckpointPredictor:
         assert out["a_predicted"].shape == (2, 1)
         assert predictor.model_path.endswith("4")
 
+    @staticmethod
+    def _train_quant_zero2(model, model_dir):
+        """Two steps and a checkpoint in the quantized ZeRO-2 regime, the
+        one producer of a flat optimizer state and a flat EMA."""
+        from tensor2robot_tpu import flags
+        from tensor2robot_tpu.train.train_eval import train_eval_model
+
+        saved = flags.read_raw("T2R_COLLECTIVE_QUANT")
+        try:
+            flags.write_env("T2R_COLLECTIVE_QUANT", "int8")
+            train_eval_model(
+                model,
+                input_generator_train=MockInputGenerator(batch_size=8),
+                model_dir=model_dir,
+                max_train_steps=2,
+                save_checkpoints_steps=2,
+                log_every_steps=2,
+                shard_weight_update=True,
+            )
+        finally:
+            flags.restore_env("T2R_COLLECTIVE_QUANT", saved)
+
     def test_restore_flat_ema_checkpoint(self, tmp_path):
-        """A checkpoint from the flatten_optimizer_update regime stores
-        the EMA as ONE concatenated vector; every consumer must unravel
-        it against the params structure (train/state.py ema_as_tree), not
-        serve the raw 1-D vector as 'params'."""
+        """A checkpoint from the quantized ZeRO-2 regime stores the EMA
+        as ONE concatenated, block-padded vector; every consumer must
+        unravel it against the params structure and drop the tail
+        (train/state.py ema_as_tree), not serve the raw 1-D vector as
+        'params'."""
         from tensor2robot_tpu.models.checkpoint_init import (
             load_checkpoint_variables,
         )
-        from tensor2robot_tpu.train.train_eval import train_eval_model
+        from tensor2robot_tpu.train.state import checkpoint_metadata_template
 
         model_dir = str(tmp_path / "run")
-        train_eval_model(
+        self._train_quant_zero2(
             MockT2RModel(device_type="cpu", use_avg_model_params=True),
-            input_generator_train=MockInputGenerator(batch_size=8),
-            model_dir=model_dir,
-            max_train_steps=2,
-            save_checkpoints_steps=2,
-            log_every_steps=2,
-            flatten_optimizer_update=True,
+            model_dir,
         )
+        on_disk = checkpoint_metadata_template(
+            os.path.join(model_dir, "checkpoints"), 2
+        )
+        n_params = sum(
+            int(np.prod(leaf.shape))
+            for leaf in jax.tree_util.tree_leaves(
+                on_disk["variables"]["params"]
+            )
+        )
+        assert len(on_disk["ema_params"].shape) == 1
+        assert on_disk["ema_params"].shape[0] > n_params  # padded tail
         predictor = CheckpointPredictor(
             t2r_model=MockT2RModel(
                 device_type="cpu", use_avg_model_params=True
@@ -320,22 +349,12 @@ class TestCheckpointPredictor:
 
     def test_restore_checkpoint_with_different_opt_layout(self, tmp_path):
         """Serving must not care how the TRAINER laid out its optimizer
-        state: a checkpoint written with flatten_optimizer_update=True (one
+        state: a checkpoint written in the quantized ZeRO-2 regime (one
         concatenated moment vector) restores into a predictor whose
         model-derived template is per-leaf — the opt_state template comes
         from the checkpoint's own metadata."""
-        from tensor2robot_tpu.train.train_eval import train_eval_model
-
         model_dir = str(tmp_path / "run")
-        train_eval_model(
-            MockT2RModel(device_type="cpu"),
-            input_generator_train=MockInputGenerator(batch_size=8),
-            model_dir=model_dir,
-            max_train_steps=2,
-            save_checkpoints_steps=2,
-            log_every_steps=2,
-            flatten_optimizer_update=True,
-        )
+        self._train_quant_zero2(MockT2RModel(device_type="cpu"), model_dir)
         predictor = CheckpointPredictor(
             t2r_model=MockT2RModel(device_type="cpu"),
             checkpoint_dir=model_dir,

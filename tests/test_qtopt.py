@@ -297,6 +297,43 @@ class TestGrasping44Model:
         export = wrapped.create_export_outputs_fn(pre_features, outputs)
         assert export["q_predicted"].dtype == jnp.float32
 
+    @pytest.mark.parametrize("seed", [5, 7, 11])
+    def test_jitted_gradients_match_eager(self, seed):
+        """The tiny critic's training gradients under jit are the ones
+        the same code returns run eagerly (with the scatter-free pool
+        backward every leaf upstream of conv5 differed by 0.38-0.52 of
+        its norm on the CPU). A leaf's gap is taken over the larger of
+        its norm and the median leaf's: pre-BatchNorm biases have no
+        gradient to speak of."""
+        model = self.make_model(image_size=(96, 96), num_convs=(2, 2, 1))
+        features = make_random_numpy(
+            model.get_feature_specification("train"), batch_size=8, seed=seed
+        )
+        reward = np.random.RandomState(seed).randint(0, 2, (8, 1))
+        labels = {"reward": reward.astype(np.float32)}
+        variables = model.init_variables(jax.random.PRNGKey(seed), features)
+
+        def loss_fn(params):
+            f, l, outputs, _ = model.packed_inference(
+                {**variables, "params": params}, features, "train",
+                labels=labels, rng=jax.random.PRNGKey(1),
+            )
+            return model.model_train_fn(f, l, outputs, "train")[0]
+
+        jitted = jax.jit(jax.grad(loss_fn))(variables["params"])
+        with jax.disable_jit():
+            eager = jax.grad(loss_fn)(variables["params"])
+        norms = jax.tree_util.tree_map(
+            lambda x: float(jnp.linalg.norm(x)), eager
+        )
+        median = float(np.median(jax.tree_util.tree_leaves(norms)))
+        gaps = jax.tree_util.tree_map(
+            lambda a, b, n: float(jnp.linalg.norm(a - b)) / max(n, median),
+            jitted, eager, norms,
+        )
+        worst = max(jax.tree_util.tree_leaves(gaps))
+        assert worst < 1e-4, gaps
+
     @pytest.mark.slow
     def test_golden_values(self):
         """Data->checkpoint golden regression for the flagship (reference

@@ -1022,7 +1022,7 @@ class TestGateMeasuresTheNativePath:
         compiled, state = trained
         generator = DefaultExportGenerator()
         generator.set_specification_from_model(compiled.model)
-        variables = compiled.export_variables(state)
+        variables = state.export_variables()
         batch = {
             "x": np.random.RandomState(0)
             .uniform(-1, 1, (4, 3))

@@ -563,317 +563,6 @@ class TestMemoryLevers:
             params_accum,
         )
 
-    def test_flattened_optimizer_update_matches_plain_step(self):
-        """optax.flatten applies the (elementwise) optimizer on one
-        concatenated vector — mathematically identical, so trained params
-        must match the per-leaf update bit-for-bit. The mode exists
-        because the round-3 TPU profile showed per-leaf Adam kernels
-        paying ~1-4 ms of fixed per-op latency each."""
-        compiled, state, batch = self._setup()
-        params_plain, loss_plain = self._one_step_params(
-            compiled, state, batch
-        )
-        compiled_f, state_f, _ = self._setup(flatten_optimizer_update=True)
-        params_flat, loss_flat = self._one_step_params(
-            compiled_f, state_f, batch
-        )
-        assert loss_plain == loss_flat
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_array_equal(a, b),
-            params_plain,
-            params_flat,
-        )
-
-    def test_fused_batch_stats_matches_per_leaf(self):
-        """fuse_batch_stats_update (default-on under the flatten regime)
-        must be numerically transparent: same loss bit-for-bit (stats
-        never feed the train forward), running stats equal to the
-        per-leaf EMA within FMA-fusion ULPs, and eval through the
-        unravel path equal to the tree path."""
-        compiled_p, state_p, batch = self._setup(
-            flatten_optimizer_update=True, fuse_batch_stats_update=False
-        )
-        compiled_f, state_f, _ = self._setup(
-            flatten_optimizer_update=True
-        )
-        assert train_eval._is_flat_stats(
-            state_f.variables["batch_stats"]
-        ), "fused regime did not store flat stats"
-        rng = jax.random.PRNGKey(7)
-        for _ in range(3):
-            state_p, metrics_p = compiled_p.train_step(
-                state_p, compiled_p.shard_batch(batch), rng
-            )
-            state_f, metrics_f = compiled_f.train_step(
-                state_f, compiled_f.shard_batch(batch), rng
-            )
-        assert float(metrics_p["loss"]) == float(metrics_f["loss"])
-        stats_p = state_p.variables["batch_stats"]
-        stats_f = compiled_f.export_variables(state_f)["batch_stats"]
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=0, atol=1e-6
-            ),
-            stats_p,
-            stats_f,
-        )
-        # The stats really moved (a silent freeze would also "match" a
-        # frozen twin — compare against init instead).
-        init_stats = compiled_p.init_state(
-            jax.random.PRNGKey(0), batch
-        ).variables["batch_stats"]
-        moved = max(
-            float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
-            for a, b in zip(
-                jax.tree_util.tree_leaves(init_stats),
-                jax.tree_util.tree_leaves(stats_f),
-            )
-        )
-        assert moved > 0.0
-        eval_p = compiled_p.eval_step(
-            state_p, compiled_p.shard_batch(batch), False
-        )
-        eval_f = compiled_f.eval_step(
-            state_f, compiled_f.shard_batch(batch), False
-        )
-        for key in eval_p:
-            np.testing.assert_allclose(
-                np.asarray(eval_p[key]), np.asarray(eval_f[key]), atol=1e-5
-            )
-
-    def test_fused_batch_stats_persist_roundtrip(self):
-        """persistable_state emits the canonical tree layout (the on-disk
-        format) and fuse_state restores the live flat form exactly."""
-        compiled, state, batch = self._setup(flatten_optimizer_update=True)
-        state, _ = compiled.train_step(
-            state, compiled.shard_batch(batch), jax.random.PRNGKey(7)
-        )
-        tree_state = compiled.persistable_state(state)
-        assert isinstance(tree_state.variables["batch_stats"], dict)
-        refused = compiled.fuse_state(tree_state)
-        np.testing.assert_array_equal(
-            np.asarray(refused.variables["batch_stats"]),
-            np.asarray(state.variables["batch_stats"]),
-        )
-
-    # ~18s of HLO text compiles on 1 cpu: slow slice; the numeric
-    # fused-vs-refused parity pins above stay fast.
-    @pytest.mark.slow
-    def test_fused_batch_stats_kernel_count(self):
-        """Structural pin of the fused-stats step.
-
-        What the CPU-compiled HLO proves: (a) the step's INPUT surface
-        shrinks — the ~2-per-BN-layer tiny [C]-vector batch_stats
-        parameters (each a separate buffer, and per the r3 trace a
-        separate small async copy-start DMA)
-        collapse into ONE concatenated vector parameter; (b) the fused
-        form costs at most a couple of extra kernels (the concat+axpy)
-        — XLA's CPU fusion pass already absorbs the per-leaf EMA axpys
-        into neighbors, so total schedulable-kernel parity is the
-        honest off-chip expectation; the on-chip A/B
-        (BENCH_FUSE_STATS=0 vs default) settles the device-plane
-        question."""
-        import re
-
-        from __graft_entry__ import _flagship
-
-        def census(fuse):
-            model, batch = _flagship(
-                image_size=(96, 96), batch_size=2, num_convs=(2, 2, 1)
-            )
-            compiled = train_eval.CompiledModel(
-                model,
-                donate_state=False,
-                flatten_optimizer_update=True,
-                fuse_batch_stats_update=fuse,
-            )
-            state = compiled.init_state(jax.random.PRNGKey(0), batch)
-            txt = (
-                compiled.train_step.lower(
-                    state, compiled.shard_batch(batch), jax.random.PRNGKey(1)
-                )
-                .compile()
-                .as_text()
-            )
-            entry = re.search(r"ENTRY [^{]+\{(.*?)\n\}", txt, re.S).group(1)
-            free = {
-                "parameter",
-                "bitcast",
-                "get-tuple-element",
-                "constant",
-                "tuple",
-            }
-
-            def opname(line):
-                found = re.search(r"= \S+? (\w[\w-]*)\(", line)
-                return found.group(1) if found else None
-
-            kernels = 0
-            stats_params = 0
-            for line in entry.splitlines():
-                if " = " not in line:
-                    continue
-                name = opname(line.strip())
-                if name is None:
-                    continue
-                if name == "parameter":
-                    if "batch_stats" in line:
-                        stats_params += 1
-                elif name not in free:
-                    kernels += 1
-            return kernels, stats_params
-
-        kernels_per_leaf, params_per_leaf = census(fuse=False)
-        kernels_fused, params_fused = census(fuse=True)
-        # (a) Input-surface collapse: 10 BN layers at this reduced depth
-        # hold 20 stat vectors; fused must present exactly ONE.
-        assert params_fused == 1, params_fused
-        assert params_per_leaf >= 2 * 10, params_per_leaf
-        # (b) No kernel-count regression beyond the concat+axpy pair
-        # (plus slack for compiler drift).
-        assert kernels_fused <= kernels_per_leaf + 4, (
-            kernels_per_leaf,
-            kernels_fused,
-        )
-
-    def test_fused_batch_stats_rejects_plain_flax_bn(self):
-        """A model whose BNs bypass layers.batch_norm must fail loudly
-        under the fused regime instead of silently freezing its stats."""
-        import flax.linen as nn
-
-        from tensor2robot_tpu.specs import TensorSpecStruct
-
-        class PlainBNNetwork(nn.Module):
-            @nn.compact
-            def __call__(self, features, mode):
-                x = nn.Dense(4)(features.x)
-                x = nn.BatchNorm(
-                    use_running_average=(mode != "train"), momentum=0.9
-                )(x)
-                out = TensorSpecStruct()
-                out["a_predicted"] = nn.Dense(1)(x)
-                return out
-
-        class PlainBNModel(MockT2RModel):
-            def create_network(self):
-                return PlainBNNetwork()
-
-        model = PlainBNModel(device_type="cpu")
-        generator = MockInputGenerator(batch_size=4)
-        generator.set_specification_from_model(model, "train")
-        batch = next(iter(generator.create_dataset("train")))
-        compiled = train_eval.CompiledModel(
-            model, donate_state=False, flatten_optimizer_update=True
-        )
-        state = compiled.init_state(jax.random.PRNGKey(0), batch)
-        with pytest.raises(ValueError, match="batch_stats_new"):
-            compiled.train_step(
-                state, compiled.shard_batch(batch), jax.random.PRNGKey(1)
-            )
-
-    def test_flat_ema_matches_tree_ema(self):
-        """flatten_optimizer_update also stores the EMA as one flat
-        vector (one fused axpy per step instead of a kernel per leaf);
-        the unraveled export must match the tree-stored EMA to within
-        ULP-scale tolerance (the flat axpy fuses as FMA, the per-leaf
-        kernels as mul+add)."""
-
-        def setup(flat):
-            model = MockT2RModel(
-                device_type="cpu",
-                use_avg_model_params=True,
-                avg_model_params_decay=0.9,
-            )
-            generator = MockInputGenerator(batch_size=8)
-            generator.set_specification_from_model(model, "train")
-            batch = next(iter(generator.create_dataset("train")))
-            compiled = train_eval.CompiledModel(
-                model, donate_state=False, flatten_optimizer_update=flat
-            )
-            state = compiled.init_state(jax.random.PRNGKey(0), batch)
-            return compiled, state, batch
-
-        import jax.flatten_util
-
-        compiled_t, state_t, batch = setup(False)
-        compiled_f, state_f, _ = setup(True)
-        assert state_f.ema_params.ndim == 1  # stored flat
-
-        # One step cross-path: flat and tree EMA exports agree (beyond
-        # one step the paths diverge by design — the flat optimizer's
-        # fusion differs by a ULP and the network amplifies it, which is
-        # why the existing flat-optimizer test is also single-step).
-        state_t, _ = compiled_t.train_step(
-            state_t, compiled_t.shard_batch(batch), jax.random.PRNGKey(0)
-        )
-        state_f, _ = compiled_f.train_step(
-            state_f, compiled_f.shard_batch(batch), jax.random.PRNGKey(0)
-        )
-        # compiled.export_variables: the flat regime also stores fused
-        # batch_stats, which only the CompiledModel-level export unravels
-        # back into the tree layout the comparison needs.
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                a, b, rtol=1e-6, atol=1e-9
-            ),
-            jax.device_get(compiled_t.export_variables(state_t, use_ema=True)),
-            jax.device_get(compiled_f.export_variables(state_f, use_ema=True)),
-        )
-
-        # Multi-step on the flat path alone: the stored vector must track
-        # the EMA recursion of ITS OWN params, and the export must
-        # unravel it into the params' structure.
-        for i in range(3):
-            prev_ema = np.asarray(
-                jax.device_get(state_f.ema_params), np.float64
-            )
-            state_f, _ = compiled_f.train_step(
-                state_f, compiled_f.shard_batch(batch), jax.random.PRNGKey(i)
-            )
-            flat_params = np.asarray(
-                jax.device_get(
-                    jax.flatten_util.ravel_pytree(state_f.params)[0]
-                ),
-                np.float64,
-            )
-            expected = prev_ema * 0.9 + flat_params * 0.1
-            np.testing.assert_allclose(
-                np.asarray(jax.device_get(state_f.ema_params), np.float64),
-                expected,
-                rtol=1e-5,
-                atol=1e-8,
-            )
-        exported = jax.device_get(
-            state_f.export_variables(use_ema=True)["params"]
-        )
-        restitched = np.concatenate(
-            [
-                np.ravel(leaf)
-                for leaf in jax.tree_util.tree_leaves(exported)
-            ]
-        )
-        np.testing.assert_allclose(
-            restitched,
-            np.asarray(jax.device_get(state_f.ema_params)),
-            rtol=1e-6,
-        )
-
-    def test_flattened_optimizer_rejected_in_sharded_regimes(self):
-        from tensor2robot_tpu.parallel import mesh as mesh_lib
-
-        model = MockT2RModel(device_type="cpu")
-        with pytest.raises(ValueError, match="flatten_optimizer_update"):
-            train_eval.CompiledModel(
-                model,
-                mesh=mesh_lib.make_mesh(fsdp=len(jax.devices())),
-                flatten_optimizer_update=True,
-            )
-        with pytest.raises(ValueError, match="flatten_optimizer_update"):
-            train_eval.CompiledModel(
-                model, shard_weight_update=True,
-                flatten_optimizer_update=True,
-            )
-
     def test_grad_accum_metric_recombination_is_key_driven(self):
         """Batch-carrying metrics are declared by key prefix, not inferred
         from shape: a fixed-size float vector that coincidentally has
@@ -924,6 +613,41 @@ class TestMemoryLevers:
         model = MockT2RModel(device_type="cpu")
         with pytest.raises(ValueError, match="grad_accum_steps"):
             train_eval.CompiledModel(model, grad_accum_steps=0)
+
+
+class TestModelObjectUntouched:
+    """CompiledModel reads the model and sets nothing on it."""
+
+    @staticmethod
+    def _lowered_text(model, batch, **compiled_kwargs):
+        compiled = train_eval.CompiledModel(
+            model, donate_state=False, **compiled_kwargs
+        )
+        state = compiled.init_state(jax.random.PRNGKey(0), batch)
+        return compiled.train_step.lower(
+            state, compiled.shard_batch(batch), jax.random.PRNGKey(7)
+        ).as_text()
+
+    @staticmethod
+    def _model_and_batch():
+        model = MockT2RModel(device_type="cpu")
+        generator = MockInputGenerator(batch_size=8)
+        generator.set_specification_from_model(model, "train")
+        return model, next(iter(generator.create_dataset("train")))
+
+    def test_no_attribute_set_through_init_and_lowering(self):
+        model, batch = self._model_and_batch()
+        before = dict(vars(model))
+        self._lowered_text(model, batch)
+        assert vars(model) == before
+
+    def test_two_trainers_over_one_model_lower_to_the_same_text(self):
+        """A trainer built after another over the same model instance
+        traces the program it would have traced alone."""
+        model, batch = self._model_and_batch()
+        alone = self._lowered_text(model, batch)
+        self._lowered_text(model, batch, remat=True, grad_accum_steps=2)
+        assert self._lowered_text(model, batch) == alone
 
 
 class TestWeightUpdateSharding:

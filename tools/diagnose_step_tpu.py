@@ -187,7 +187,7 @@ def main() -> None:
     t = timed(jax.jit(jax.grad(bn_loss)), (pbn, x79))
     record("conv5x5_block6_bn_fwd_bwd", t, flops=3.0 * flops_blk)
 
-    # --- round-4 A/Bs: the BN-compute-dtype fix, the scatter-free pool,
+    # --- round-4 A/Bs: the BN-compute-dtype fix, the pool backward,
     # and the conv-efficiency hypotheses (odd 79x79 spatial tiling;
     # 64 channels on the 128-lane MXU). Each pairs with a control above
     # so the post-fix chip session decomposes the remaining step time. ---
@@ -248,27 +248,15 @@ def main() -> None:
     )
     record("bn_stats_reduce_c128", t)
 
-    # Stem-pool backward A/B: scatter-free custom VJP vs XLA
-    # SelectAndScatter, at the stem activation size.
-    from tensor2robot_tpu.ops.pooling import max_pool_nonoverlap
+    # Stem-pool backward (SelectAndScatter) at the stem activation size.
+    from tensor2robot_tpu.ops.pooling import max_pool
 
     x236 = jax.random.normal(key, (B, 236, 236, 64), jnp.bfloat16)
 
-    def pool_free_loss(x):
-        return jnp.sum(
-            max_pool_nonoverlap(x, (3, 3)).astype(jnp.float32)
-        )
+    def pool_loss(x):
+        return jnp.sum(max_pool(x, (3, 3)).astype(jnp.float32))
 
-    def pool_sas_loss(x):
-        return jnp.sum(
-            nn.max_pool(x, (3, 3), strides=(3, 3), padding="SAME").astype(
-                jnp.float32
-            )
-        )
-
-    t = timed(jax.jit(jax.grad(pool_free_loss)), (x236,))
-    record("stem_pool_bwd_scatterfree", t)
-    t = timed(jax.jit(jax.grad(pool_sas_loss)), (x236,))
+    t = timed(jax.jit(jax.grad(pool_loss)), (x236,))
     record("stem_pool_bwd_selectscatter", t)
 
     # Spatial-tiling hypothesis: same block at 80x80 (8-aligned) vs the
