@@ -18,7 +18,9 @@ that enters a chunk adds its part to every position of it. The decay
 between two positions is a difference of cumulative sums of `log a`, kept
 in float32; everything that meets the matrix units is in the layer's
 compute dtype. The backward is autodiff through these products: with the
-block under `nn.remat` nothing of it outlives one layer.
+block under `nn.remat` nothing of it outlives one layer but what the
+remat's policy keeps by name (the input projection's output carries the
+`checkpoint_name` "mamba2_in_proj"; the name lowers to nothing elsewhere).
 
 Packed documents: where `segment_ids` change, a new document starts. Its
 first token takes `a_t = 0` (no state carries over), and the convolution's
@@ -36,6 +38,7 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from tensor2robot_tpu.layers.transformer import RMSNorm
 
@@ -210,10 +213,10 @@ class Mamba2Mixer(nn.Module):
         doc = document_index(segment_ids)
 
         with jax.named_scope("mamba2/in_proj"):
-            projected = nn.Dense(
+            projected = checkpoint_name(nn.Dense(
                 inner + conv_width + heads, use_bias=False, dtype=self.dtype,
                 kernel_init=dense_init, name="in_proj",
-            )(u)
+            )(u), "mamba2_in_proj")
             z, xbc, dt = jnp.split(projected, [inner, inner + conv_width], axis=-1)
         with jax.named_scope("mamba2/conv"):
             kernel = self.param(

@@ -23,6 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from tensor2robot_tpu.ops import flash_attention as flash_lib
 from tensor2robot_tpu.parallel import mesh as mesh_lib
@@ -422,7 +423,12 @@ class RMSNorm(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """W_down (silu(W_gate x) * (W_up x)), no bias."""
+    """W_down (silu(W_gate x) * (W_up x)), no bias.
+
+    The two wide products carry `checkpoint_name`s ("mlp_gate", "mlp_up"):
+    a `jax.checkpoint` with a names policy may keep them; anywhere else the
+    names lower to nothing.
+    """
 
     hidden_dim: int
     dtype: Optional[jnp.dtype] = None
@@ -437,10 +443,9 @@ class SwiGLU(nn.Module):
             )
 
         with jax.named_scope("mlp"):
-            hidden = nn.silu(dense(self.hidden_dim, "gate")(x)) * dense(
-                self.hidden_dim, "up"
-            )(x)
-            return dense(x.shape[-1], "down")(hidden)
+            gate = checkpoint_name(dense(self.hidden_dim, "gate")(x), "mlp_gate")
+            up = checkpoint_name(dense(self.hidden_dim, "up")(x), "mlp_up")
+            return dense(x.shape[-1], "down")(nn.silu(gate) * up)
 
 
 class HybridBlock(nn.Module):

@@ -36,8 +36,22 @@ from tensor2robot_tpu.specs import ExtendedTensorSpec, TensorSpecStruct
 #: Positions a piece of the cross-entropy takes at once.
 LOSS_CHUNK = 2048
 
-#: A block whose forward is recomputed in the backward pass.
-_RematBlock = nn.remat(HybridBlock)
+#: What a block keeps of its forward for the backward pass, by
+#: `checkpoint_name`: positions x width x 2 bytes a layer at bfloat16.
+KEPT_RESIDUALS = (
+    "mlp_gate",  # SwiGLU's W_gate x: width shared_intermediate_size
+    "mlp_up",  # SwiGLU's W_up x: the same
+    # Mamba-2's [z, xBC, dt]: width 2 x inner + 2 x groups x state + heads
+    "mamba2_in_proj",
+)
+
+#: A block whose forward is recomputed in the backward pass, but for the
+#: three products above: norms, convolution, scans, gated norm and the
+#: attention mixer run a second time.
+_RematBlock = nn.remat(
+    HybridBlock,
+    policy=jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS),
+)
 
 
 def chunked_cross_entropy(hidden, embedding, targets, loss_mask, *,
@@ -117,7 +131,15 @@ class HybridSequenceLMModel(FlaxT2RModel):
     `loss_mask` float32 [S]. There is no position input: the model has no
     positional encoding. `layer_types[:num_hidden_layers]` picks each
     layer's mixer. Every block is recomputed in the backward pass
-    (`nn.remat`) and the loss is taken `LOSS_CHUNK` positions at a time.
+    (`nn.remat`) but for `KEPT_RESIDUALS`, which a step keeps: SwiGLU's
+    gate and up products in every layer and the Mamba-2 input projection
+    (2 x positions x shared_intermediate_size x 2 bytes and positions x
+    in_proj width x 2 bytes a layer at bfloat16; 3.9 GB at 8,192 positions
+    x ten layers of granite-4.0-h-micro's widths). The scans and the
+    attention mixer are still recomputed: the scans' decay matrices are
+    four times that, and the attention kernel's residuals wait for a
+    roofline that counts them (docs/SEQUENCE_MODELS.md). The loss is taken
+    `LOSS_CHUNK` positions at a time.
     The step's metrics carry `tokens` (positions with a loss) and
     `pad_tokens` (positions of segment 0). With labels the network returns
     the loss; without (predict) the logits [S, vocab_size].

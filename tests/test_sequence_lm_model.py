@@ -1,12 +1,16 @@
 """`HybridSequenceLMModel` (ISSUE 28): specs, the layer pattern, the
-program against the benchmark's plain reference, per-layer recomputation,
-training from packed records through `train_eval_model` and through
-`CompiledModel.train_step`, the token counters, its `t2r-check` target,
-and the transformer family's outputs unchanged. CPU, tiny sizes, float32."""
+program against the benchmark's plain reference, per-layer recomputation
+and what a block keeps across it (ISSUE 31), training from packed records
+through `train_eval_model` and through `CompiledModel.train_step`, the
+token counters, its `t2r-check` target, and the transformer family's
+outputs unchanged. CPU, tiny sizes, float32."""
 
+import ast
+import collections
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,14 +55,18 @@ def test_layer_pattern_is_read_up_to_num_hidden_layers():
         _model(mamba_d_head=16)
 
 
-def _loss_and_grads(model, variables, features, labels):
+def _loss_fn(model, features, labels):
     def loss(params):
         outputs, _ = model.inference_network_fn(
             {"params": params}, features, "train", labels=labels
         )
         return model.model_train_fn(features, labels, outputs, "train")[0]
 
-    return jax.value_and_grad(loss)(variables["params"])
+    return loss
+
+
+def _loss_and_grads(model, variables, features, labels):
+    return jax.value_and_grad(_loss_fn(model, features, labels))(variables["params"])
 
 
 def test_per_layer_recomputation_changes_no_bit(monkeypatch):
@@ -78,6 +86,199 @@ def test_per_layer_recomputation_changes_no_bit(monkeypatch):
         lambda a, b: bool(jnp.array_equal(a, b)), grads_a, grads_b
     )
     assert all(jax.tree_util.tree_leaves(equal)), equal
+
+
+# -- what a block keeps across its recomputation (ISSUE 31) -------------------------
+
+
+@pytest.fixture(scope="module")
+def block_variants():
+    """{variant: (loss, gradients, jaxpr of the gradient)} of the tiny
+    model (mamba, attention, mamba) on one batch and one set of weights:
+    the model's block (named residuals kept), the whole block recomputed,
+    and no recomputation."""
+    import flax.linen as nn
+
+    from tensor2robot_tpu.layers.transformer import HybridBlock
+    from tensor2robot_tpu.models import sequence_lm_models
+
+    features, labels = _batch()
+    variables = _model().init_variables(jax.random.PRNGKey(0), features)
+    blocks = {
+        "kept": sequence_lm_models._RematBlock,
+        "whole": nn.remat(HybridBlock),
+        "plain": HybridBlock,
+    }
+    out = {}
+    with pytest.MonkeyPatch.context() as patch:
+        for name, block in blocks.items():
+            patch.setattr(sequence_lm_models, "_RematBlock", block)
+            loss = _loss_fn(_model(), features, labels)
+            value, grads = jax.jit(jax.value_and_grad(loss))(variables["params"])
+            out[name] = (
+                value, grads, jax.make_jaxpr(jax.grad(loss))(variables["params"])
+            )
+    return out
+
+
+@pytest.mark.parametrize("one,other", [
+    ("kept", "whole"), ("kept", "plain"), ("whole", "plain"),
+])
+def test_kept_residuals_change_no_gradient(block_variants, one, other):
+    loss_a, grads_a, _ = block_variants[one]
+    loss_b, grads_b, _ = block_variants[other]
+    assert float(loss_a) == pytest.approx(float(loss_b), rel=1e-6)
+    leaves_a = jax.tree_util.tree_leaves_with_path(grads_a)
+    leaves_b = [np.asarray(leaf) for leaf in jax.tree_util.tree_leaves(grads_b)]
+    assert len(leaves_a) == len(leaves_b)
+    # A leaf's gap over the larger of its norm and the median leaf's: the
+    # gradients of `dt_bias` are sums that all but cancel (1e-12).
+    scale = float(np.median([np.linalg.norm(leaf) for leaf in leaves_b]))
+    for (path, a), b in zip(leaves_a, leaves_b):
+        gap = np.linalg.norm(np.asarray(a) - b)
+        assert gap <= 1e-6 * max(np.linalg.norm(b), scale), jax.tree_util.keystr(path)
+
+
+_SCOPES = (
+    "mamba2/in_proj", "mamba2/ssd", "mamba2/out_proj", "attention",
+    "attention_proj", "mlp", "lm_head",
+)
+
+
+def _equations(jaxpr, stack=""):
+    """(equation, its whole name stack) of a jaxpr and of every jaxpr
+    inside its equations' parameters."""
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        yield eqn, here
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, here)
+
+
+def _products_by_scope(closed):
+    """{scope: number of `dot_general`s} of a jaxpr (the scopes do not
+    nest; None counts the products outside all of them)."""
+    counts = collections.Counter()
+    for eqn, stack in _equations(closed.jaxpr):
+        if eqn.primitive.name != "dot_general":
+            continue
+        counts[next((s for s in _SCOPES if f"/{s}/" in f"{stack}/"), None)] += 1
+    return counts
+
+
+def test_the_policy_saves_three_products_a_block_and_nothing_else(block_variants):
+    kept, whole, plain = (
+        _products_by_scope(block_variants[name][2])
+        for name in ("kept", "whole", "plain")
+    )
+    blocks, mamba_blocks = 3, 2
+    assert whole["mlp"] - kept["mlp"] == 2 * blocks
+    assert whole["mamba2/in_proj"] - kept["mamba2/in_proj"] == mamba_blocks
+    assert sum(whole.values()) - sum(kept.values()) == 2 * blocks + mamba_blocks
+    # The products the policy keeps are not computed twice any more.
+    assert kept["mamba2/in_proj"] == plain["mamba2/in_proj"]
+    # The scans and the attention layer's scores and values still are.
+    for scope in ("mamba2/ssd", "attention"):
+        assert kept[scope] == whole[scope] > plain[scope], scope
+    for scope in set(whole) - {"mlp", "mamba2/in_proj"}:
+        assert kept[scope] == whole[scope], scope
+
+
+def _checkpoint_names_in_source():
+    from tensor2robot_tpu.layers import mamba2, transformer
+
+    names = []
+    for module in (mamba2, transformer):
+        with open(module.__file__) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            called = getattr(node.func, "id", getattr(node.func, "attr", None))
+            if called == "checkpoint_name":
+                assert isinstance(node.args[1], ast.Constant), ast.dump(node)
+                names.append(node.args[1].value)
+    return names
+
+
+def test_every_kept_name_is_emitted_and_every_emitted_name_is_kept(block_variants):
+    from tensor2robot_tpu.models.sequence_lm_models import KEPT_RESIDUALS
+
+    assert len(set(KEPT_RESIDUALS)) == len(KEPT_RESIDUALS)
+    assert sorted(_checkpoint_names_in_source()) == sorted(KEPT_RESIDUALS)
+    # And the tiny model's step really passes through each of them.
+    emitted = {
+        eqn.params["name"] for eqn, _ in _equations(block_variants["plain"][2].jaxpr)
+        if eqn.primitive.name == "name"
+    }
+    assert emitted == set(KEPT_RESIDUALS)
+
+
+def _nameless(monkeypatch):
+    """The two layer files as they were before their outputs had names."""
+    from tensor2robot_tpu.layers import mamba2, transformer
+
+    for module in (mamba2, transformer):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+
+
+def _lowered_text(jitted, *args):
+    """StableHLO of a jitted call; jax numbers its private functions
+    (`@_where_189`) by a counter of the process, which is left out."""
+    return re.sub(r"(@\w+?)_\d+\b", r"\1", jitted.lower(*args).as_text())
+
+
+def _layer_case(layer):
+    from tensor2robot_tpu.layers.mamba2 import Mamba2Mixer
+    from tensor2robot_tpu.layers.transformer import SwiGLU
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, 64), jnp.float32)
+    if layer == "swiglu":
+        return SwiGLU(128), (x,)
+    return (
+        Mamba2Mixer(num_heads=4, head_dim=32, state_size=16, chunk_size=16),
+        (x, jnp.asarray(_segments())),
+    )
+
+
+@pytest.mark.parametrize("layer", ["swiglu", "mamba2"])
+def test_a_named_layer_computes_what_its_nameless_twin_does(monkeypatch, layer):
+    module, inputs = _layer_case(layer)
+    variables = module.init(jax.random.PRNGKey(5), *inputs)
+
+    def apply():
+        # A function of its own each time: no trace is shared with the twin.
+        return jax.jit(lambda *args: module.apply(*args))
+
+    named = apply()(variables, *inputs)
+    named_text = _lowered_text(apply(), variables, *inputs)
+    assert "name[name=" in str(jax.make_jaxpr(apply())(variables, *inputs))
+    _nameless(monkeypatch)
+    assert "name[name=" not in str(jax.make_jaxpr(apply())(variables, *inputs))
+    assert bool(jnp.array_equal(named, apply()(variables, *inputs)))
+    assert bool(jnp.all(jnp.isfinite(named))) and float(jnp.abs(named).sum()) > 0
+    # Outside a policy the name is no operation: the lowering is the twin's.
+    assert named_text == _lowered_text(apply(), variables, *inputs)
+
+
+def test_the_predict_path_lowers_without_remat_or_names(monkeypatch):
+    features, _ = _batch()
+    model = _model()
+    variables = model.init_variables(jax.random.PRNGKey(0), features)
+
+    def lowered():
+        predict = jax.jit(
+            lambda v, f: _model().inference_network_fn(v, f, "predict")[0]["logits"]
+        )
+        return _lowered_text(predict, variables, features)
+
+    text = lowered()
+    assert "optimization_barrier" not in text
+    _nameless(monkeypatch)
+    assert text == lowered()
 
 
 def test_step_metrics_carry_tokens_and_pad_tokens():
