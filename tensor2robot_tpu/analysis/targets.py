@@ -86,6 +86,17 @@ def _hybrid_sequence_lm():
     )
 
 
+def _kimi_linear_lm():
+    from tensor2robot_tpu.models.sequence_lm_models import KimiLinearLMModel
+
+    # The defaults are a small model of the family's pattern (three KDA
+    # layers and a latent-attention one, the first dense, the others with 8
+    # experts): the spec contract is the hybrid model's.
+    return KimiLinearLMModel(
+        sequence_length=64, kda_chunk_size=16, device_type="cpu"
+    )
+
+
 def _mock_noop():
     from tensor2robot_tpu.utils.mocks import MockT2RModel
 
@@ -95,6 +106,7 @@ def _mock_noop():
 register_target("qtopt-grasping44", _qtopt_grasping44)
 register_target("transformer-bc", _transformer_bc)
 register_target("hybrid-sequence-lm", _hybrid_sequence_lm)
+register_target("kimi-linear-lm", _kimi_linear_lm)
 register_target("mock-noop", _mock_noop)
 # The policy server's request path: predict-mode specs are what the
 # server's submit() validates against and what the micro-batcher stacks

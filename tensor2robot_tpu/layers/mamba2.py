@@ -73,7 +73,7 @@ def causal_conv(x: jax.Array, kernel: jax.Array, bias: jax.Array,
     return out
 
 
-def _masked_exp(log_decay: jax.Array, mask: jax.Array) -> jax.Array:
+def masked_exp(log_decay: jax.Array, mask: jax.Array) -> jax.Array:
     """exp(log_decay) where mask, else 0, with no overflow (and no NaN in
     the gradient) where the masked-out difference is positive."""
     return jnp.where(mask, jnp.exp(jnp.where(mask, log_decay, 0.0)), 0.0)
@@ -115,7 +115,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, log_a: jax.Array,
     visible = (doc[..., :, None] == doc[..., None, :]) & jnp.tril(
         jnp.ones((chunk, chunk), bool)
     )
-    decay = _masked_exp(
+    decay = masked_exp(
         cum[..., :, None] - cum[..., None, :], visible[:, :, None]
     )
     scores = jnp.einsum(
@@ -131,7 +131,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, log_a: jax.Array,
 
     # 2. What each chunk's own tokens leave in the state at its end.
     last_doc = doc[..., -1]
-    to_end = _masked_exp(
+    to_end = masked_exp(
         cum[..., -1:] - cum, (doc == last_doc[..., None])[:, :, None]
     ).transpose(0, 1, 3, 2).reshape(batch, chunks, chunk, groups, per_group)
     left = jnp.einsum(
@@ -146,7 +146,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, log_a: jax.Array,
     before = jnp.pad(through, ((0, 0), (1, 0), (0, 0)))[:, :-1]
     doc_before = jnp.pad(last_doc, ((0, 0), (1, 0)), constant_values=-1)[:, :-1]
     earlier = jnp.tril(jnp.ones((chunks, chunks), bool), k=-1)
-    carried = _masked_exp(
+    carried = masked_exp(
         before[:, :, None] - through[:, None, :],         # [B, z, c, H]
         ((doc_before[:, :, None] == last_doc[:, None, :]) & earlier)[..., None],
     )
@@ -156,7 +156,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, log_a: jax.Array,
     ).reshape(batch, chunks, groups, per_group, head_dim, state)
 
     # 4. What the entering state adds at each position of its document.
-    from_start = _masked_exp(
+    from_start = masked_exp(
         cum, (doc == doc_before[..., None])[:, :, None]
     ).transpose(0, 1, 3, 2).reshape(batch, chunks, chunk, groups, per_group)
     y = y + jnp.einsum(
@@ -166,14 +166,14 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, log_a: jax.Array,
     return y.reshape(batch, seq, heads, head_dim).astype(dtype)
 
 
-def _uniform_log(low: float, high: float):
+def uniform_log(low: float, high: float):
     def init(key, shape, dtype=jnp.float32):
         return jnp.log(jax.random.uniform(key, shape, dtype, low, high))
 
     return init
 
 
-def _inverse_softplus_log_uniform(low: float, high: float):
+def inverse_softplus_log_uniform(low: float, high: float):
     """dt_bias such that softplus(dt_bias) is log-uniform in [low, high]."""
 
     def init(key, shape, dtype=jnp.float32):
@@ -226,10 +226,10 @@ class Mamba2Mixer(nn.Module):
             xbc = nn.silu(causal_conv(xbc, kernel, bias, doc))
             x, b, c = jnp.split(xbc, [inner, inner + bc_width], axis=-1)
         with jax.named_scope("mamba2/ssd"):
-            a_log = self.param("A_log", _uniform_log(1.0, 16.0), (heads,))
+            a_log = self.param("A_log", uniform_log(1.0, 16.0), (heads,))
             d_skip = self.param("D", nn.initializers.ones, (heads,))
             dt_bias = self.param(
-                "dt_bias", _inverse_softplus_log_uniform(1e-3, 1e-1), (heads,)
+                "dt_bias", inverse_softplus_log_uniform(1e-3, 1e-1), (heads,)
             )
             dt = nn.softplus(dt.astype(jnp.float32) + dt_bias)
             x = x.reshape(batch, seq, heads, head_dim)

@@ -409,6 +409,67 @@ class TransformerBlock(nn.Module):
         return x + h
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention without positions (MLA, `mla_use_nope`),
+    over packed documents, in its expanded (training) form:
+
+        q = W_q x                       heads of qk_nope_dim + qk_rope_dim
+        [c, k_pe] = W_kva x             a latent of kv_rank, qk_rope_dim shared
+        [k_nope, v] = W_kvb RMSNorm(c)  heads of qk_nope_dim + v_dim
+        k = [k_nope, k_pe]              k_pe the same for every head, never rotated
+
+    scores `q k^T (qk_nope_dim + qk_rope_dim)^-1/2`, causal and within
+    `segment_ids`' documents (`ops/flash_attention.segment_attention`, whose
+    values may be narrower than its keys), then `W_o`. The latent is what a
+    decode cache would hold; there is none here.
+    """
+
+    num_heads: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    kv_rank: int
+    epsilon: float = 1e-5
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: Callable = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x: jax.Array, segment_ids: jax.Array) -> jax.Array:
+        batch, seq, _ = x.shape
+        heads, nope, rope = self.num_heads, self.qk_nope_dim, self.qk_rope_dim
+
+        def project(width, name):
+            return nn.Dense(
+                width, use_bias=False, dtype=self.dtype,
+                kernel_init=self.kernel_init, name=name,
+            )
+
+        with jax.named_scope("mla/q_proj"):
+            q = checkpoint_name(
+                project(heads * (nope + rope), "q_proj")(x), "mla_q_proj"
+            ).reshape(batch, seq, heads, nope + rope)
+        with jax.named_scope("mla/kv_down"):
+            latent, k_pe = jnp.split(
+                project(self.kv_rank + rope, "kv_a")(x), [self.kv_rank], axis=-1
+            )
+            latent = RMSNorm(self.epsilon, name="kv_norm")(latent)
+        with jax.named_scope("mla/kv_up"):
+            kv = project(heads * (nope + self.v_dim), "kv_b")(latent).reshape(
+                batch, seq, heads, nope + self.v_dim
+            )
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(k_pe[:, :, None], (batch, seq, heads, rope)),
+            ], axis=-1)
+            v = kv[..., nope:]
+        with jax.named_scope("attention"):
+            out = flash_lib.segment_attention(q, k, v, segment_ids)
+        with jax.named_scope("attention_proj"):
+            return project(x.shape[-1], "o_proj")(
+                out.reshape(batch, seq, heads * self.v_dim)
+            )
+
+
 class RMSNorm(nn.Module):
     """x / rms(x) * scale over the last axis, statistics in float32."""
 
@@ -433,6 +494,8 @@ class SwiGLU(nn.Module):
     hidden_dim: int
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = nn.linear.default_kernel_init
+    # The `jax.named_scope` its device time is billed to.
+    scope_name: str = "mlp"
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -442,7 +505,7 @@ class SwiGLU(nn.Module):
                 kernel_init=self.kernel_init, name=name,
             )
 
-        with jax.named_scope("mlp"):
+        with jax.named_scope(self.scope_name):
             gate = checkpoint_name(dense(self.hidden_dim, "gate")(x), "mlp_gate")
             up = checkpoint_name(dense(self.hidden_dim, "up")(x), "mlp_up")
             return dense(x.shape[-1], "down")(nn.silu(gate) * up)

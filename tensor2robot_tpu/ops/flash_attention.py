@@ -238,6 +238,15 @@ SEGMENT_KERNEL_BLOCKS = dict(
     block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=1024,
 )
 
+#: The fused backward kernel writes one partial dq a block of keys, [heads,
+#: S / block_kv_dkv, S, D] in all, and sums them afterwards: 268 MB at
+#: granite's [32 x 8,192 x 64], 4 GB at latent attention's [32 x 16,384 x
+#: 192]. Over this many bytes the backward takes a dq kernel of its own
+#: (`SEGMENT_DQ_BLOCKS`), which holds no partials; the race of PR 29 read it
+#: a fifth slower as the layer runs it.
+SEGMENT_FUSED_BACKWARD_BYTES = 1 << 30
+SEGMENT_DQ_BLOCKS = dict(block_q_dq=1024, block_kv_dq=1024)
+
 
 def segment_attention(
     q: jax.Array,
@@ -249,8 +258,10 @@ def segment_attention(
     """Causal attention over packed documents: query i sees key j iff
     j <= i and segment_ids[j] == segment_ids[i].
 
-    q [B, S, H, D]; k, v [B, S, KVH, D] with H a multiple of KVH (the
-    group's keys are never repeated); segment_ids [B, S]. Softmax
+    q [B, S, H, D]; k [B, S, KVH, D] and v [B, S, KVH, DV] with H a
+    multiple of KVH (the group's keys are never repeated) and DV any width
+    (latent attention's values are narrower than its keys); the output is
+    [B, S, H, DV]; segment_ids [B, S]. Softmax
     statistics and every accumulation in float32, the probabilities
     rounded to the values' dtype for the second product only. Two
     implementations of that one contract, chosen by what the call can see:
@@ -322,7 +333,9 @@ def _segment_einsum(q, k, v, segment_ids, scale):
             q[:, start:stop], k[:, :stop], v[:, :stop],
             segment_ids[:, start:stop], segment_ids[:, :stop], start,
         ))
-    return jnp.concatenate(out, axis=1).reshape(batch, seq, heads, dim)
+    return jnp.concatenate(out, axis=1).reshape(
+        batch, seq, heads, v.shape[-1]
+    )
 
 
 def _segment_kernel(q, k, v, segment_ids, scale, interpret=False):
@@ -343,12 +356,18 @@ def _segment_kernel(q, k, v, segment_ids, scale, interpret=False):
     batch, seq, heads, dim = q.shape
     kv_heads = k.shape[2]
     group = heads // kv_heads
+    partials = (
+        heads * (seq // SEGMENT_KERNEL_BLOCKS["block_kv_dkv"]) * seq * dim
+        * q.dtype.itemsize
+    )
+    if partials <= SEGMENT_FUSED_BACKWARD_BYTES:
+        # dq, dk and dv from one backward kernel: it won the race too.
+        backward = {"use_fused_bwd_kernel": True}
+    else:
+        backward = SEGMENT_DQ_BLOCKS
     kernel = splash.make_splash_mqa_single_device(
         splash_mask.MultiHeadMask([splash_mask.CausalMask((seq, seq))] * group),
-        # dq, dk and dv from one backward kernel: it won the race too.
-        block_sizes=splash.BlockSizes(
-            **{"use_fused_bwd_kernel": True, **SEGMENT_KERNEL_BLOCKS}
-        ),
+        block_sizes=splash.BlockSizes(**backward, **SEGMENT_KERNEL_BLOCKS),
         interpret=interpret,
     )
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -361,7 +380,9 @@ def _segment_kernel(q, k, v, segment_ids, scale, interpret=False):
         return jax.vmap(kernel, in_axes=(0, 0, 0, None))(q, k, v, ids)
 
     out = jax.vmap(one_sequence)(q, k, v, segment_ids.astype(jnp.int32))
-    return out.transpose(0, 3, 1, 2, 4).reshape(batch, seq, heads, dim)
+    return out.transpose(0, 3, 1, 2, 4).reshape(
+        batch, seq, heads, v.shape[-1]
+    )
 
 
 def _flash_body(

@@ -1232,40 +1232,58 @@ def _host_path_record(before, after, steps: int) -> Dict[str, float]:
 
 
 _TOKEN_KEYS = ("tokens", "pad_tokens")
+#: Counts a model with routed experts adds to them (layers/moe.RoutedExperts).
+_MOE_KEYS = (
+    "moe_routed_rows", "moe_max_expert_rows", "moe_peak_rows", "moe_positions"
+)
 
 
 def add_token_counts(sums, metrics):
     """Token-sequence models put `tokens` (positions with a loss) and
-    `pad_tokens` into a step's metrics. Adds one step's counts (or a
-    scanned chunk's stacked ones) to the running sums on the device, which
-    the next log reads back with the metrics it reads anyway. `sums` comes
-    back as it was for any other model."""
+    `pad_tokens` into a step's metrics, and those with routed experts the
+    layers' `_MOE_KEYS`. Adds one step's counts (or a scanned chunk's
+    stacked ones) to the running sums on the device, which the next log
+    reads back with the metrics it reads anyway. `sums` comes back as it was
+    for any other model."""
     if any(key not in metrics for key in _TOKEN_KEYS):
         return sums
     counts = {
         key: jnp.round(jnp.sum(metrics[key])).astype(jnp.int32)
-        for key in _TOKEN_KEYS
+        for key in _TOKEN_KEYS + _MOE_KEYS if key in metrics
     }
     if sums is None:
         return counts
-    return {key: sums[key] + counts[key] for key in _TOKEN_KEYS}
+    return {key: sums[key] + counts[key] for key in counts}
 
 
 def token_log_record(token_sums, seconds: float) -> Dict[str, float]:
     """From the counts summed over an interval's steps, already read back:
     the recorder's counters `train.tokens` and `train.pad_tokens` grow by
     them, and the log record gets `tokens_per_s` and `pad_share` (padding
-    over the positions that are a token or padding). Empty where no step
+    over the positions that are a token or padding). Where the steps counted
+    routed experts, `moe.routed_rows` and `moe.max_expert_rows` grow too and
+    the record gets `moe_rows_per_token` (pairs routed to held experts over
+    positions routed, summed over layers) and `moe_imbalance` (the fullest
+    held expert's rows over the mean held expert's). Empty where no step
     counted tokens."""
     if token_sums is None:
         return {}
     tokens, pad = (int(token_sums[key]) for key in _TOKEN_KEYS)
     tracing.count("train.tokens", tokens)
     tracing.count("train.pad_tokens", pad)
-    return {
+    record = {
         "tokens_per_s": tokens / max(seconds, 1e-9),
         "pad_share": pad / max(tokens + pad, 1),
     }
+    if all(key in token_sums for key in _MOE_KEYS):
+        routed, fullest, peak, positions = (
+            int(token_sums[key]) for key in _MOE_KEYS
+        )
+        tracing.count("moe.routed_rows", routed)
+        tracing.count("moe.max_expert_rows", fullest)
+        record["moe_rows_per_token"] = routed / max(positions, 1)
+        record["moe_imbalance"] = peak / max(routed, 1)
+    return record
 
 
 def train_eval_model(

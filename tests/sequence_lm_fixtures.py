@@ -1,12 +1,17 @@
-"""What the two files of sequence-model tests share: a packing of
-documents into two rows, a tiny model and a batch (ISSUE 28)."""
+"""What the files of sequence-model tests share: a packing of documents
+into two rows, a tiny model of each family and a batch (ISSUE 28, 32)."""
 
+import importlib.util
+import json
 import os
 
 import jax.numpy as jnp
 import numpy as np
 
-from tensor2robot_tpu.models.sequence_lm_models import HybridSequenceLMModel
+from tensor2robot_tpu.models.sequence_lm_models import (
+    HybridSequenceLMModel,
+    KimiLinearLMModel,
+)
 from tensor2robot_tpu.specs import TensorSpecStruct
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -62,3 +67,37 @@ def batch(seed=0):
         TensorSpecStruct({"tokens": tokens, "segment_ids": ids}),
         TensorSpecStruct({"targets": targets, "loss_mask": loss_mask}),
     )
+
+
+KIMI_LINEAR = {
+    "kda_layers": [1, 2, 3, 5], "full_attn_layers": [4], "num_heads": 4,
+    "head_dim": 16, "short_conv_kernel_size": 4,
+}
+KIMI_TINY = dict(
+    vocab_size=96, hidden_size=64, intermediate_size=128, num_hidden_layers=5,
+    num_attention_heads=4, linear_attn_config=KIMI_LINEAR, first_k_dense_replace=1,
+    num_experts=4, router_experts=16, first_expert=0, num_experts_per_token=4,
+    num_shared_experts=1, moe_intermediate_size=32, routed_scaling_factor=2.446,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    kda_chunk_size=16, sequence_length=SEQ, device_type="cpu",
+)
+
+
+def kimi_model(**overrides):
+    return KimiLinearLMModel(**{**KIMI_TINY, **overrides})
+
+
+def kimi_reference():
+    """(the benchmark's plain reference of the Kimi-Linear share, its
+    configuration at `KIMI_TINY`'s sizes)."""
+    path = os.path.join(REPO, "benchmark", "reference", "kimi_linear_48b_a3b_s1.py")
+    spec = importlib.util.spec_from_file_location("kimi_reference_for_tests", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    with open(os.path.join(REPO, "benchmark", "configs", "kimi_linear_48b_a3b_s1.json")) as f:
+        config = json.load(f)
+    keys = set(config["model"]) | {"kda_chunk_size"}
+    config["model"] = {
+        **config["model"], **{k: v for k, v in KIMI_TINY.items() if k in keys},
+    }
+    return module, config

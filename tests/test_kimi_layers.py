@@ -1,0 +1,404 @@
+"""The layers `KimiLinearLMModel` brought (ISSUE 32): the chunked delta rule
+against the stepped recurrence, values and gradients, under a decay that
+would overflow a factored form and across document resets; latent
+attention's widths through `segment_attention`, kernel and einsum; routed
+experts that drop no token whatever the imbalance and whose shares add up
+to the uncut layer. The model itself is in tests/test_kimi_linear.py. CPU,
+tiny sizes, float32."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from tensor2robot_tpu.layers import kda
+from tensor2robot_tpu.layers.moe import RoutedExperts
+from tensor2robot_tpu.ops import flash_attention as flash_lib
+from tensor2robot_tpu.ops import moe as moe_ops
+from tensor2robot_tpu.train import train_eval
+from tests.sequence_lm_fixtures import (
+    SEQ,
+    kimi_reference as _reference,
+    segments as _segments,
+)
+
+
+# -- the delta rule ----------------------------------------------------------------
+
+
+def _recurrence(q, k, v, g, beta, doc):
+    """S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T, o_t = S_t^T q_t,
+    stepped; exp(g) is 0 at a document's first token."""
+    first = jnp.concatenate(
+        [jnp.ones_like(doc[:, :1], bool), doc[:, 1:] != doc[:, :-1]], axis=1)
+    a = jnp.where(first[..., None, None], 0.0, jnp.exp(g))
+    highest = lax.Precision.HIGHEST
+
+    def step(state, inputs):
+        q_t, k_t, v_t, a_t, b_t = inputs
+        state = a_t[..., None] * state
+        erased = jnp.einsum("bhk,bhkv->bhv", k_t, state, precision=highest)
+        state = state + (b_t[..., None] * k_t)[..., None] * (v_t - erased)[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t, precision=highest)
+
+    swap = lambda t: jnp.swapaxes(t, 0, 1)
+    batch, _, heads, width = q.shape
+    _, out = lax.scan(
+        step, jnp.zeros((batch, heads, width, v.shape[-1])),
+        (swap(q), swap(k), swap(v), swap(a), swap(beta)))
+    return swap(out)
+
+
+def _delta_inputs(strength, resets, seq=128):
+    rng = np.random.RandomState(3)
+    batch, heads, width = 2, 3, 8
+    draw = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+    q, k, v = (draw(batch, seq, heads, width) for _ in range(3))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * width ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -strength * jnp.asarray(rng.rand(batch, seq, heads, width), jnp.float32)
+    beta = jnp.asarray(rng.rand(batch, seq, heads), jnp.float32)
+    ids = np.ones((batch, seq), np.int32)
+    if resets:
+        ids[0, 37:] = 2      # inside a sub-block
+        ids[0, 64:] = 3      # at a chunk's first position
+        ids[0, 100:] = 0     # padding
+        ids[1, 5:] = 2
+    return q, k, v, g, beta, kda.document_index(jnp.asarray(ids))
+
+
+# A decay of 8 a step and channel is exp(-1,000) over a chunk of 128
+# channels' worth: `exp(-G)` of a factored form overflows float32 at 89.
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_chunked_delta_rule_is_the_stepped_recurrence(resets, strength, chunk):
+    q, k, v, g, beta, doc = _delta_inputs(strength, resets)
+    with jax.default_matmul_precision("highest"):
+        want = _recurrence(q, k, v, g, beta, doc)
+        got = kda.kda_chunked(q, k, v, g, beta, doc, chunk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_chunked_delta_rule_gradients_are_the_recurrences(resets, strength):
+    *inputs, doc = _delta_inputs(strength, resets)
+    weight = jnp.asarray(np.random.RandomState(5).randn(2, 128, 3, 8), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(
+            lambda *a: jnp.sum(_recurrence(*a, doc) * weight), argnums=range(5)
+        )(*inputs)
+        got = jax.grad(
+            lambda *a: jnp.sum(kda.kda_chunked(*a, doc, 64) * weight),
+            argnums=range(5),
+        )(*inputs)
+    for name, g, w in zip(("q", "k", "v", "g", "beta"), got, want):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=1e-4, err_msg=name
+        )
+
+
+def test_delta_rule_refuses_a_ragged_sequence():
+    q, k, v, g, beta, doc = _delta_inputs(0.1, False, seq=48)
+    with pytest.raises(ValueError, match="multiple of the chunk"):
+        kda.kda_chunked(q, k, v, g, beta, doc, 32)
+
+
+@pytest.mark.parametrize("size", [8, 16, 64])
+def test_unit_lower_inverse_inverts(size):
+    rng = np.random.RandomState(size)
+    a = jnp.asarray(
+        np.tril(rng.randn(3, 2, size, size), k=-1) * 2.0 / size, jnp.float32)
+    got = kda.unit_lower_inverse(a)
+    eye = np.eye(size, dtype=np.float32)
+    product = np.einsum("...ij,...jk->...ik", np.asarray(a) + eye, np.asarray(got))
+    np.testing.assert_allclose(product, np.broadcast_to(eye, product.shape), atol=1e-5)
+
+
+def test_pair_scores_go_a_slab_of_chunks_at_a_time(monkeypatch):
+    q, k, v, g, beta, doc = _delta_inputs(0.5, True)
+    with jax.default_matmul_precision("highest"):
+        whole = kda.kda_chunked(q, k, v, g, beta, doc, 32)
+        monkeypatch.setattr(kda, "PAIR_SLAB_ELEMENTS", 1)   # one chunk a slab
+        jaxpr = jax.make_jaxpr(
+            lambda *a: kda.kda_chunked(*a, doc, 32))(q, k, v, g, beta)
+        slabbed = kda.kda_chunked(q, k, v, g, beta, doc, 32)
+    np.testing.assert_allclose(np.asarray(slabbed), np.asarray(whole), atol=1e-6)
+    assert "length=4" in str(jaxpr)                          # 128 / 32 slabs
+
+
+def test_heads_go_a_group_at_a_time(monkeypatch):
+    q, k, v, g, beta, doc = _delta_inputs(0.5, True)
+    weight = jnp.asarray(np.random.RandomState(5).randn(2, 128, 3, 8), jnp.float32)
+    run = lambda: jax.value_and_grad(
+        lambda q: jnp.sum(kda.kda_chunked(q, k, v, g, beta, doc, 32) * weight))(q)
+    with jax.default_matmul_precision("highest"):
+        whole, whole_grad = run()
+        monkeypatch.setattr(kda, "HEAD_GROUP_ELEMENTS", 2 * 128 * 8)   # one head
+        grouped, grouped_grad = run()
+    assert float(whole) == pytest.approx(float(grouped), rel=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(grouped_grad), np.asarray(whole_grad), atol=1e-6)
+
+
+# -- latent attention's widths through segment_attention ---------------------------
+
+
+def _masked_attention(q, k, v, segments, scale):
+    highest = lax.Precision.HIGHEST
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=highest) * scale
+    seq = q.shape[1]
+    mask = jnp.tril(jnp.ones((seq, seq), bool))[None] & (
+        segments[:, :, None] == segments[:, None, :]
+    )
+    probs = jax.nn.softmax(jnp.where(mask[:, None], logits, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision=highest)
+
+
+KERNEL_SEQ = 256
+
+
+def _wide_qkv(dtype=jnp.float32):
+    rng = np.random.RandomState(7)
+    draw = lambda dim: jnp.asarray(rng.randn(2, KERNEL_SEQ, 2, dim), dtype)
+    segments = jnp.asarray(_segments(((100, 156), (190, 66)), KERNEL_SEQ))
+    return draw(192), draw(192), draw(128), segments
+
+
+@pytest.fixture
+def kernel_tiles(monkeypatch):
+    monkeypatch.setattr(
+        flash_lib, "SEGMENT_KERNEL_BLOCKS",
+        dict.fromkeys(flash_lib.SEGMENT_KERNEL_BLOCKS, 128),
+    )
+    monkeypatch.setattr(
+        flash_lib, "SEGMENT_DQ_BLOCKS", dict.fromkeys(flash_lib.SEGMENT_DQ_BLOCKS, 128)
+    )
+    monkeypatch.setattr(flash_lib, "SEGMENT_BLOCK_Q", 64)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_segment_attention_takes_values_narrower_than_keys(
+    kernel_tiles, monkeypatch, fused
+):
+    if not fused:   # the dq kernel of its own, as at 16,384 x 192
+        monkeypatch.setattr(flash_lib, "SEGMENT_FUSED_BACKWARD_BYTES", 0)
+    q, k, v, segments = _wide_qkv()
+    scale = 192 ** -0.5
+    weight = jnp.asarray(np.random.RandomState(1).randn(2, KERNEL_SEQ, 2, 128), jnp.float32)
+
+    def both(attend):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v, segments, scale) * weight),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    want_out = _masked_attention(q, k, v, segments, scale)
+    assert want_out.shape == (2, KERNEL_SEQ, 2, 128)
+    for attend in (
+        flash_lib._segment_einsum,
+        lambda *a: flash_lib._segment_kernel(*a, interpret=True),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(attend(q, k, v, segments, scale)), np.asarray(want_out),
+            rtol=1e-5, atol=1e-5,
+        )
+        (_, got), (_, want) = both(attend), both(_masked_attention)
+        for name, g, w in zip("qkv", got, want):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4, err_msg=name
+            )
+
+
+def test_the_fused_backward_goes_where_its_partials_outgrow_a_gigabyte(monkeypatch):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+    )
+
+    seen = []
+    real = splash.BlockSizes
+
+    def spy(**kwargs):
+        seen.append(kwargs)
+        return real(**kwargs)
+
+    monkeypatch.setattr(splash, "BlockSizes", spy)
+    shape = lambda seq, heads, dim: jax.ShapeDtypeStruct((1, seq, heads, dim), jnp.bfloat16)
+    ids = lambda seq: jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    for seq, kv_heads, dim, dim_v in ((8192, 8, 64, 64), (16384, 32, 192, 128)):
+        jax.eval_shape(
+            lambda q, k, v, s: flash_lib._segment_kernel(q, k, v, s, 0.1),
+            shape(seq, 32, dim), shape(seq, kv_heads, dim), shape(seq, kv_heads, dim_v),
+            ids(seq),
+        )
+    granite, latent = seen
+    assert granite == {"use_fused_bwd_kernel": True, **flash_lib.SEGMENT_KERNEL_BLOCKS}
+    assert latent == {**flash_lib.SEGMENT_DQ_BLOCKS, **flash_lib.SEGMENT_KERNEL_BLOCKS}
+
+
+# -- routed experts ------------------------------------------------------------------
+
+
+def _experts(seed=0, tokens=64, features=16, hidden=8, held=4, scored=16):
+    rng = np.random.RandomState(seed)
+    draw = lambda *shape: jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+    return dict(
+        x=jnp.asarray(rng.randn(tokens, features), jnp.float32),
+        router=draw(features, scored),
+        gate=draw(scored, features, hidden), up=draw(scored, features, hidden),
+        down=draw(scored, hidden, features),
+    )
+
+
+def _dense_share(p, bias, first, count, k, scaling):
+    """Every held expert on every token, masked by the routing: the plain
+    form of one share."""
+    scores = jax.nn.sigmoid(p["x"] @ p["router"])
+    _, ids = lax.top_k(scores + bias, k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    y = jnp.zeros_like(p["x"])
+    for e in range(first, first + count):
+        w = jnp.sum(jnp.where(ids == e, weights, 0.0), axis=-1)
+        y = y + w[:, None] * (
+            (jax.nn.silu(p["x"] @ p["gate"][e]) * (p["x"] @ p["up"][e])) @ p["down"][e]
+        )
+    return y
+
+
+def _share(p, bias, first, count, k=4, scaling=2.446, row_buffer=None):
+    held = slice(first, first + count)
+    return moe_ops.routed_experts(
+        p["x"], p["router"], bias, p["gate"][held], p["up"][held], p["down"][held],
+        held=(first, count), num_selected=k, scaling=scaling, row_buffer=row_buffer,
+    )
+
+
+@pytest.mark.parametrize("row_buffer", [None, 16, 7])
+def test_routed_experts_are_the_masked_dense_share(row_buffer):
+    p, bias = _experts(), jnp.zeros((16,))
+    with jax.default_matmul_precision("highest"):
+        y, counts = _share(p, bias, 4, 4, row_buffer=row_buffer)
+        want = _dense_share(p, bias, 4, 4, 4, 2.446)
+        got = jax.grad(
+            lambda p: jnp.sum(jnp.sin(_share(p, bias, 4, 4, row_buffer=row_buffer)[0])))(p)
+        wanted = jax.grad(
+            lambda p: jnp.sum(jnp.sin(_dense_share(p, bias, 4, 4, 4, 2.446))))(p)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-6)
+    assert 0 < int(counts["routed_rows"]) < 64 * 4
+    for name in p:
+        np.testing.assert_allclose(
+            np.asarray(got[name]), np.asarray(wanted[name]), atol=2e-5, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("row_buffer", [None, 32, 5])
+def test_no_token_is_dropped_when_every_token_goes_to_held_experts(row_buffer):
+    """A selection bias that sends all four choices of every token to the
+    four experts held: 256 rows where an even router sends 64."""
+    p = _experts(seed=1)
+    bias = jnp.where(jnp.arange(16) < 4, 10.0, 0.0)
+    ref, config = _reference()
+    s = dict(
+        ref._settings(config), num_experts=4, first_expert=0, router_experts=16,
+        num_experts_per_token=4, routed_scaling_factor=2.446,
+    )
+    with jax.default_matmul_precision("highest"):
+        y, counts = _share(p, bias, 0, 4, row_buffer=row_buffer)
+        want = ref.routed_share(
+            {"moe/router": p["router"], "moe/selection_bias": bias,
+             "moe/gate": p["gate"][:4], "moe/up": p["up"][:4],
+             "moe/down": p["down"][:4]}, p["x"], s)
+    assert int(counts["routed_rows"]) == 64 * 4
+    assert int(counts["max_expert_rows"]) == 64
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(_dense_share(p, bias, 0, 4, 4, 2.446)), atol=2e-6
+    )
+
+
+def test_selection_bias_moves_the_choice_and_not_the_weights():
+    p = _experts(seed=2)
+    bias = jnp.zeros((16,)).at[9].set(10.0)       # expert 9 into every choice
+    plain_ids, _ = moe_ops.sigmoid_top_k(p["x"], p["router"], jnp.zeros((16,)), 4, 2.446)
+    ids, weights = moe_ops.sigmoid_top_k(p["x"], p["router"], bias, 4, 2.446)
+    assert bool(jnp.all(jnp.any(ids == 9, axis=-1)))
+    assert not bool(jnp.all(jnp.any(plain_ids == 9, axis=-1)))
+    scores = jax.nn.sigmoid(p["x"] @ p["router"])     # no bias in them
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(weights),
+        np.asarray(2.446 * chosen / chosen.sum(-1, keepdims=True)), rtol=1e-5,
+    )
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 2.446, rtol=1e-5)
+    # And no gradient reaches it.
+    grad = jax.grad(lambda b: jnp.sum(_share(p, b, 8, 4)[0]))(bias)
+    assert not np.any(np.asarray(grad))
+
+
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """16 experts over 4 chips of 4: the four shares' routed parts, with the
+    shared expert and the residual counted once, are what the uncut
+    reference layer gives."""
+    ref, config = _reference()
+    s = ref._settings(config)
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(2, SEQ, 64), jnp.float32)
+    uncut = {**s, "num_experts": 16, "router_experts": 16, "first_expert": 0}
+    shapes = {k: v for k, v in ref.layer_shapes(uncut, ("kda", "moe")).items()
+              if k.startswith("moe/")}
+    whole = {
+        name: jnp.asarray(rng.randn(*shape) * 0.1, jnp.float32)
+        for name, shape in sorted(shapes.items())
+    }
+    with jax.default_matmul_precision("highest"):
+        want = x + ref.moe_ffn(whole, x, uncut)
+        routed = jnp.zeros_like(x)
+        shared = None
+        for first in range(0, 16, 4):
+            layer = RoutedExperts(
+                num_experts=4, router_experts=16, first_expert=first,
+                hidden_dim=32, num_selected=4, scaling=2.446,
+            )
+            held = slice(first, first + 4)
+            variables = {"params": {
+                "router": whole["moe/router"],
+                "selection_bias": whole["moe/selection_bias"],
+                "gate": whole["moe/gate"][held], "up": whole["moe/up"][held],
+                "down": whole["moe/down"][held],
+                "shared": {n: {"kernel": whole[f"moe/shared/{n}/kernel"]}
+                           for n in ("gate", "up", "down")},
+            }}
+            y, counts = layer.apply(variables, x)
+            without = layer.clone(shared_experts=0).apply(
+                {"params": {k: v for k, v in variables["params"].items() if k != "shared"}},
+                x,
+            )[0]
+            routed = routed + without
+            shared = y - without
+            assert float(counts[3]) == 2 * SEQ
+    np.testing.assert_allclose(
+        np.asarray(x + routed + shared), np.asarray(want), atol=2e-5
+    )
+
+
+def test_a_share_reports_its_rows():
+    layer = RoutedExperts(
+        num_experts=4, router_experts=16, hidden_dim=8, num_selected=4)
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 32, 16), jnp.float32)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    _, counts = layer.apply(variables, x)
+    routed, fullest, peak, positions = (float(c) for c in counts)
+    assert RoutedExperts.COUNT_NAMES == (
+        "moe_routed_rows", "moe_max_expert_rows", "moe_peak_rows", "moe_positions")
+    assert train_eval._MOE_KEYS == RoutedExperts.COUNT_NAMES   # the trainer's copy
+    assert positions == 64 and peak == 4 * fullest
+    assert 0 < fullest <= routed <= peak
+    with pytest.raises(ValueError, match="expert matrices"):
+        moe_ops.routed_experts(
+            x[0], jnp.zeros((16, 16)), jnp.zeros((16,)), jnp.zeros((3, 16, 8)),
+            jnp.zeros((3, 16, 8)), jnp.zeros((3, 8, 16)), held=(0, 4), num_selected=4,
+        )

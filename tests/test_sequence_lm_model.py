@@ -188,10 +188,10 @@ def test_the_policy_saves_three_products_a_block_and_nothing_else(block_variants
 
 
 def _checkpoint_names_in_source():
-    from tensor2robot_tpu.layers import mamba2, transformer
+    from tensor2robot_tpu.layers import kda, mamba2, transformer
 
     names = []
-    for module in (mamba2, transformer):
+    for module in (mamba2, transformer, kda):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
@@ -205,10 +205,16 @@ def _checkpoint_names_in_source():
 
 
 def test_every_kept_name_is_emitted_and_every_emitted_name_is_kept(block_variants):
-    from tensor2robot_tpu.models.sequence_lm_models import KEPT_RESIDUALS
+    from tensor2robot_tpu.models.sequence_lm_models import (
+        KEPT_RESIDUALS,
+        KIMI_KEPT_RESIDUALS,
+    )
 
     assert len(set(KEPT_RESIDUALS)) == len(KEPT_RESIDUALS)
-    assert sorted(_checkpoint_names_in_source()) == sorted(KEPT_RESIDUALS)
+    # The layer files name what either model keeps, and nothing else.
+    kimi = {name for names in KIMI_KEPT_RESIDUALS.values() for name in names}
+    assert sorted(_checkpoint_names_in_source()) == sorted(
+        set(KEPT_RESIDUALS) | kimi)
     # And the tiny model's step really passes through each of them.
     emitted = {
         eqn.params["name"] for eqn, _ in _equations(block_variants["plain"][2].jaxpr)
@@ -393,8 +399,10 @@ def test_trains_from_packed_records_through_train_eval_model(
     before = tracing.counters()
     train_eval_model(
         model,
+        # Seeded: one unseeded order in seventy ends on a batch of four
+        # records of the row that packs full, whose pad_share is 0.
         input_generator_train=DefaultRecordInputGenerator(
-            file_patterns=path, batch_size=4
+            file_patterns=path, batch_size=4, seed=0
         ),
         model_dir=str(tmp_path / "run"), max_train_steps=12, eval_steps=None,
         save_checkpoints_steps=100, log_every_steps=log_every_steps,
