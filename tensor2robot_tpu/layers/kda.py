@@ -30,14 +30,30 @@ cut once more, into sub-blocks of `SUB_BLOCK` positions: between two
 sub-blocks the decay is taken through the later one's first position
 (`exp(G_i - G_ref) exp(G_ref - G_j)`, both factors <= 1) and the scores are
 a matrix product; inside a sub-block the [c, c, K] differences are formed
-and summed at once. `(I + A)^-1` is forward substitution inside a
+and summed at once. Where they live depends on the platform the program is
+lowered for (`_pair_scores`): on a TPU, with bfloat16 operands and K a
+multiple of 128, in VMEM, a grid step of `ops/kda_pair_scores`' two Pallas
+kernels (forward, and a hand-written backward that forms them again) at a
+time, never in HBM; everywhere else in HBM as float32 arrays of XLA's, a
+slab of chunks at a time, each slab recomputed in the backward pass
+(`_pair_scores_slabs`). `(I + A)^-1` is forward substitution inside a
 sub-block, all sub-blocks at once, and the block-triangular formula above
 it. The carry between chunks is a `lax.scan` over two products a chunk;
-everything else is one einsum over all chunks. The backward is autodiff,
+everything else is one einsum over all chunks. The backward is autodiff
+(but for the pair scores' kernels),
 a group of heads at a time, each group recomputed from q, k, v, g and beta
 (`kda_chunked`); the output carries the `checkpoint_name` "kda_out" so
 that a block under `nn.remat` can keep it and not run the rule a third
 time.
+
+The rule is one jitted function, `kda_chunked`, shared by every layer that
+calls it at the same shapes: a step program of several KDA layers traces
+it once (jax's trace cache answers the other layers, and with it the JVP,
+the partial evaluation and the transpose of that one jaxpr) and lowers a
+forward and a backward program once each, which every layer calls and XLA
+inlines; the kernels' bodies are lowered for Mosaic once a program, not
+once a layer and pass. Its arguments are all the arrays it reads; what it
+reads of this module's globals is read when it is first traced.
 
 Packed documents: at a document's first token `a_t` is 0. In the chunked
 form every decay whose span crosses a boundary is masked to zero (from
@@ -66,6 +82,7 @@ from tensor2robot_tpu.layers.mamba2 import (
     document_index,
 )
 from tensor2robot_tpu.layers.transformer import RMSNorm
+from tensor2robot_tpu.ops import kda_pair_scores
 
 #: Positions of a sub-block: the [c, c, K] decay differences are formed
 #: inside one only, and forward substitution runs over its c rows.
@@ -78,8 +95,9 @@ def _highest(dtype):
     return lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
-#: Elements of the [c, c, K] decay differences alive at once: the pair scores
-#: are taken this many chunks' worth at a time (256 MB in float32).
+#: Elements of the [c, c, K] decay differences alive at once in the XLA form:
+#: the pair scores are taken this many chunks' worth at a time (256 MB in
+#: float32). The kernels' grid takes its place on a TPU.
 PAIR_SLAB_ELEMENTS = 1 << 26
 
 
@@ -90,10 +108,33 @@ def _pair_scores(x, k, cum, visible):
 
     x [B, N, H, R, C, K] (R stacked row operands), k [B, N, H, C, K] in the
     compute dtype, cum [B, N, H, C, K] float32 and non-increasing along C,
-    visible [B, N, 1, C, C]. Taken a slab of chunks at a time, each slab
-    recomputed in the backward pass: the decay differences of one slab are
-    all that is ever alive, forward and backward.
+    visible [B, N, 1, C, C]. Two implementations of that one contract,
+    chosen by what the call can see:
+
+    * `ops/kda_pair_scores.pair_scores`, Pallas kernels (forward and a
+      hand-written backward) that form a sub-block's decay differences in
+      VMEM, where the program is lowered for a TPU
+      (`lax.platform_dependent`) and the operands tile
+      (`kda_pair_scores.tiles`: bfloat16, K a multiple of 128, whole chunks
+      of whole heads on 128 lanes);
+    * `_pair_scores_slabs`, the XLA form, everywhere else: other platforms,
+      float32 (the kernel's products would round it), narrow heads.
     """
+    sub = min(SUB_BLOCK, k.shape[-2])
+    if kda_pair_scores.tiles(x, k, sub):
+        return lax.platform_dependent(
+            x, k, cum, visible,
+            tpu=functools.partial(kda_pair_scores.pair_scores, sub=sub),
+            default=_pair_scores_slabs,
+        )
+    return _pair_scores_slabs(x, k, cum, visible)
+
+
+def _pair_scores_slabs(x, k, cum, visible):
+    """`_pair_scores` a slab of chunks at a time, each slab recomputed in
+    the backward pass: the decay differences of one slab ([c, c, K] a
+    sub-block, float32 in HBM) are all that is ever alive, forward and
+    backward."""
     batch, chunks, heads, chunk, width = k.shape
     sub = min(SUB_BLOCK, chunk)
     per_chunk = batch * heads * chunk * sub * width
@@ -205,6 +246,16 @@ def unit_lower_inverse(a):
 HEAD_GROUP_ELEMENTS = 1 << 25
 
 
+def head_groups(batch: int, seq: int, heads: int, width: int) -> int:
+    """The fewest groups of heads of at most `HEAD_GROUP_ELEMENTS` each."""
+    return min(
+        n for n in range(1, heads + 1)
+        if heads % n == 0
+        and (n == heads or batch * seq * (heads // n) * width <= HEAD_GROUP_ELEMENTS)
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, doc: jax.Array, chunk: int = 64) -> jax.Array:
     """o_t = S_t^T q_t of the recurrence above, for all t at once.
@@ -219,6 +270,9 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     the output across its own recomputation (`checkpoint_name` "kda_out")
     then runs the rule twice a step, forward and once more for the
     backward, and holds one group's intermediates.
+
+    Jitted here and not by its caller, so that a model's layers share one
+    trace and one lowering of it (the module's docstring).
     """
     batch, seq, heads, width = q.shape
     if seq % chunk or (chunk > SUB_BLOCK and chunk % SUB_BLOCK):
@@ -226,11 +280,7 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
             f"sequence length {seq} is not a multiple of the chunk {chunk}, "
             f"or the chunk not of {SUB_BLOCK}"
         )
-    groups = min(
-        n for n in range(1, heads + 1)
-        if heads % n == 0
-        and (n == heads or batch * seq * (heads // n) * width <= HEAD_GROUP_ELEMENTS)
-    )
+    groups = head_groups(batch, seq, heads, width)
     rule = jax.checkpoint(functools.partial(_kda_heads, doc=doc, chunk=chunk))
     if groups == 1:
         return rule(q, k, v, g, beta)
@@ -271,7 +321,8 @@ def _kda_heads(q, k, v, g, beta, *, doc, chunk):
     through = (last_doc == doc_before)[:, :, None, None]            # [B, N, 1, 1]
 
     cum = jnp.cumsum(g, axis=-2)                            # [B, N, H, C, K]
-    scores = _pair_scores(jnp.stack([q, k], axis=3), k, cum, same)
+    with jax.named_scope("kda/pair_scores"):
+        scores = _pair_scores(jnp.stack([q, k], axis=3), k, cum, same)
     b_scores = scores[..., 0, :, :]
     a_scores = beta * jnp.tril(scores[..., 1, :, :], k=-1)
     solve = unit_lower_inverse(a_scores).astype(dtype)      # T [B, N, H, C, C]

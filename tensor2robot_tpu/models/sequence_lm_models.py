@@ -383,12 +383,18 @@ class KimiLinearBlock(nn.Module):
 
 
 @functools.lru_cache(maxsize=None)
+def _kept_policy(kept: Tuple[str, ...]):
+    """One policy object a set of names: jax keys what it derives from a
+    jitted function under a `jax.checkpoint` (the delta rule's forward and
+    backward programs, `layers/kda.kda_chunked`) on the policy's identity,
+    so blocks that keep the same names must share it to share those."""
+    return jax.checkpoint_policies.save_only_these_names(*kept)
+
+
+@functools.lru_cache(maxsize=None)
 def _remat_kimi_block(mixer: str, ffn: str):
     kept = KIMI_KEPT_RESIDUALS[mixer] + KIMI_KEPT_RESIDUALS[ffn]
-    return nn.remat(
-        KimiLinearBlock,
-        policy=jax.checkpoint_policies.save_only_these_names(*kept),
-    )
+    return nn.remat(KimiLinearBlock, policy=_kept_policy(kept))
 
 
 class _KimiLinearLMNet(nn.Module):

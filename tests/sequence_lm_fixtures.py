@@ -83,6 +83,17 @@ KIMI_TINY = dict(
 )
 
 
+def loss_fn(model, features, labels):
+    """params -> the model's training loss on the batch."""
+    def loss(params):
+        outputs, _ = model.inference_network_fn(
+            {"params": params}, features, "train", labels=labels
+        )
+        return model.model_train_fn(features, labels, outputs, "train")[0]
+
+    return loss
+
+
 def kimi_model(**overrides):
     return KimiLinearLMModel(**{**KIMI_TINY, **overrides})
 

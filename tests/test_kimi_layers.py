@@ -1,10 +1,13 @@
 """The layers `KimiLinearLMModel` brought (ISSUE 32): the chunked delta rule
 against the stepped recurrence, values and gradients, under a decay that
-would overflow a factored form and across document resets; latent
-attention's widths through `segment_attention`, kernel and einsum; routed
-experts that drop no token whatever the imbalance and whose shares add up
-to the uncut layer. The model itself is in tests/test_kimi_linear.py. CPU,
-tiny sizes, float32."""
+would overflow a factored form and across document resets; the pair scores'
+Pallas kernels (ISSUE 33, 34) in interpret mode against the XLA form, the
+choice between them, and the rule as one jitted function that every KDA
+layer of a step program shares; latent attention's widths through
+`segment_attention`, kernel and einsum; routed experts that drop no token
+whatever the imbalance and whose shares add up to the uncut layer. The model
+itself is in tests/test_kimi_linear.py. CPU, tiny sizes, float32 but for the
+kernels' operands."""
 
 import jax
 import jax.numpy as jnp
@@ -14,12 +17,17 @@ from jax import lax
 
 from tensor2robot_tpu.layers import kda
 from tensor2robot_tpu.layers.moe import RoutedExperts
+from tensor2robot_tpu.ops import kda_pair_scores
 from tensor2robot_tpu.ops import flash_attention as flash_lib
 from tensor2robot_tpu.ops import moe as moe_ops
 from tensor2robot_tpu.train import train_eval
 from tests.sequence_lm_fixtures import (
+    KIMI_LINEAR,
     SEQ,
+    batch as _batch,
+    kimi_model as _model,
     kimi_reference as _reference,
+    loss_fn as _loss_fn,
     segments as _segments,
 )
 
@@ -50,9 +58,22 @@ def _recurrence(q, k, v, g, beta, doc):
     return swap(out)
 
 
-def _delta_inputs(strength, resets, seq=128):
+@pytest.fixture
+def patch_kda(monkeypatch):
+    """Sets a global that `kda.kda_chunked` reads (of layers/kda.py, or of
+    `module`) for one test. The rule is jitted and jax's trace cache does
+    not see globals, so what was traced before the change, and after it, is
+    dropped."""
+    def patch(name, value, module=kda):
+        monkeypatch.setattr(module, name, value)
+        kda.kda_chunked.clear_cache()
+
+    yield patch
+    kda.kda_chunked.clear_cache()
+
+
+def _delta_inputs(strength, resets, seq=128, batch=2, heads=3, width=8):
     rng = np.random.RandomState(3)
-    batch, heads, width = 2, 3, 8
     draw = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
     q, k, v = (draw(batch, seq, heads, width) for _ in range(3))
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * width ** -0.5
@@ -64,7 +85,7 @@ def _delta_inputs(strength, resets, seq=128):
         ids[0, 37:] = 2      # inside a sub-block
         ids[0, 64:] = 3      # at a chunk's first position
         ids[0, 100:] = 0     # padding
-        ids[1, 5:] = 2
+        ids[-1, 5:] = 2
     return q, k, v, g, beta, kda.document_index(jnp.asarray(ids))
 
 
@@ -118,11 +139,11 @@ def test_unit_lower_inverse_inverts(size):
     np.testing.assert_allclose(product, np.broadcast_to(eye, product.shape), atol=1e-5)
 
 
-def test_pair_scores_go_a_slab_of_chunks_at_a_time(monkeypatch):
+def test_pair_scores_go_a_slab_of_chunks_at_a_time(patch_kda):
     q, k, v, g, beta, doc = _delta_inputs(0.5, True)
     with jax.default_matmul_precision("highest"):
         whole = kda.kda_chunked(q, k, v, g, beta, doc, 32)
-        monkeypatch.setattr(kda, "PAIR_SLAB_ELEMENTS", 1)   # one chunk a slab
+        patch_kda("PAIR_SLAB_ELEMENTS", 1)                   # one chunk a slab
         jaxpr = jax.make_jaxpr(
             lambda *a: kda.kda_chunked(*a, doc, 32))(q, k, v, g, beta)
         slabbed = kda.kda_chunked(q, k, v, g, beta, doc, 32)
@@ -130,18 +151,269 @@ def test_pair_scores_go_a_slab_of_chunks_at_a_time(monkeypatch):
     assert "length=4" in str(jaxpr)                          # 128 / 32 slabs
 
 
-def test_heads_go_a_group_at_a_time(monkeypatch):
+def test_heads_go_a_group_at_a_time(patch_kda):
     q, k, v, g, beta, doc = _delta_inputs(0.5, True)
     weight = jnp.asarray(np.random.RandomState(5).randn(2, 128, 3, 8), jnp.float32)
     run = lambda: jax.value_and_grad(
         lambda q: jnp.sum(kda.kda_chunked(q, k, v, g, beta, doc, 32) * weight))(q)
     with jax.default_matmul_precision("highest"):
         whole, whole_grad = run()
-        monkeypatch.setattr(kda, "HEAD_GROUP_ELEMENTS", 2 * 128 * 8)   # one head
+        patch_kda("HEAD_GROUP_ELEMENTS", 2 * 128 * 8)        # one head
         grouped, grouped_grad = run()
     assert float(whole) == pytest.approx(float(grouped), rel=1e-6)
     np.testing.assert_allclose(
         np.asarray(grouped_grad), np.asarray(whole_grad), atol=1e-6)
+
+
+# -- the pair scores' kernels (interpret mode) against the XLA form ----------------
+
+
+def _pair_inputs(strength, resets, chunk, heads, chunks=2):
+    """Operands of `_pair_scores` at K = 128, bfloat16: x [1, N, H, 2, C, K]."""
+    rng = np.random.RandomState(11)
+    width = 128
+    draw = lambda *shape: jnp.asarray(
+        rng.randn(*shape) / np.sqrt(width), jnp.bfloat16)
+    x = draw(1, chunks, heads, 2, chunk, width)
+    k = draw(1, chunks, heads, chunk, width)
+    cum = jnp.cumsum(-strength * jnp.asarray(
+        rng.rand(1, chunks, heads, chunk, width), jnp.float32), axis=-2)
+    ids = np.ones((1, chunks * chunk), np.int32)
+    if resets:
+        ids[0, 5:] = 2                   # a boundary inside a sub-block
+        ids[0, 6:] = 3                   # a document of one token
+        ids[0, 7:] = 4                   # and another
+        ids[0, chunk:] = 5               # at a chunk's first position
+        ids[0, chunk + chunk // 2 + 3:] = 0   # padding
+    doc = kda.document_index(jnp.asarray(ids)).reshape(1, chunks, chunk)
+    visible = (doc[..., :, None] == doc[..., None, :])[:, :, None]
+    return x, k, cum, visible
+
+
+def _kernel_scores(x, k, cum, visible):
+    return kda_pair_scores.pair_scores(
+        x, k, cum, visible, min(kda.SUB_BLOCK, k.shape[-2]), interpret=True)
+
+
+@pytest.mark.parametrize("chunk,heads", [(16, 8), (64, 2)])
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_pair_scores_kernel_is_the_xla_form(resets, strength, chunk, heads):
+    x, k, cum, visible = _pair_inputs(strength, resets, chunk, heads)
+    assert x.shape[3] == 2 and kda_pair_scores.tiles(x, k, min(16, chunk))
+    want = kda._slab_pair_scores(x, k, cum, visible)
+    got = _kernel_scores(x, k, cum, visible)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    assert bool(jnp.all(jnp.isfinite(got)))
+    size = float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5 * size)
+    hidden = np.broadcast_to(~np.asarray(visible)[:, :, :, None], got.shape)
+    assert not np.asarray(got)[hidden].any()
+    if strength == 8.0:
+        # A decay of up to 8 a step and channel: eight positions apart all
+        # but the slowest channels have underflowed, and none to `inf` or `nan`.
+        far = np.tril(np.ones((chunk, chunk), bool), k=-8)
+        assert np.abs(np.asarray(got)[..., far]).max() < 1e-4 * size
+
+
+@pytest.mark.parametrize("chunk,heads", [(16, 8), (64, 2)])
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_pair_scores_kernel_gradients_are_the_xla_forms(resets, strength, chunk, heads):
+    x, k, cum, visible = _pair_inputs(strength, resets, chunk, heads)
+    weight = jnp.asarray(
+        np.random.RandomState(5).randn(1, 2, heads, 2, chunk, chunk), jnp.float32)
+    both = lambda scores: jax.grad(
+        lambda x, k, cum: jnp.sum(scores(x, k, cum, visible) * weight),
+        argnums=(0, 1, 2),
+    )(x, k, cum)
+    got, want = both(_kernel_scores), both(kda._slab_pair_scores)
+    # Autodiff rounds the gradients of the two between-sub-block operands to
+    # bfloat16 on their way back (2^-9 of each term); the kernel keeps them
+    # in float32 and rounds the scores' gradient for those products instead.
+    # Inside a sub-block (all there is at chunk 16) both are float32.
+    for name, g, w in zip(("x", "k", "cum"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), name
+        tolerance = 0.01 if chunk > kda.SUB_BLOCK or name != "cum" else 1e-5
+        assert np.abs(g - w).max() <= tolerance * np.abs(w).max(), name
+
+
+@pytest.fixture
+def kernel_pair_scores(patch_kda):
+    """`kda_chunked` through the kernels, interpreted, where the operands tile."""
+    taken = []
+
+    def scores(x, k, cum, visible):
+        taken.append(x.shape)
+        return _kernel_scores(x, k, cum, visible)
+
+    patch_kda("_pair_scores", scores)
+    return taken
+
+
+def _wide_delta_inputs(strength, resets):
+    """`_delta_inputs` at K = 128, two heads, q, k, v rounded to bfloat16."""
+    q, k, v, g, beta, doc = _delta_inputs(
+        strength, resets, batch=1, heads=2, width=128)
+    rounded = lambda t: t.astype(jnp.bfloat16)
+    return rounded(q), rounded(k), rounded(v), g, beta, doc
+
+
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_delta_rule_through_the_kernels_is_the_stepped_recurrence(
+    kernel_pair_scores, resets, strength
+):
+    q, k, v, g, beta, doc = _wide_delta_inputs(strength, resets)
+    weight = jnp.asarray(np.random.RandomState(5).randn(1, 128, 2, 128), jnp.float32)
+    f32 = lambda t: t.astype(jnp.float32)
+
+    def both(rule, *operands):
+        """(the rule's output, its gradients under `weight`)."""
+        def loss(*a):
+            out = f32(rule(*a))
+            return jnp.sum(out * weight), out
+
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=range(5), has_aux=True)(*operands)
+        return out, grads
+
+    with jax.default_matmul_precision("highest"):
+        want_out, want = both(
+            lambda *a: _recurrence(*a, doc), f32(q), f32(k), f32(v), g, beta)
+    got_out, got = both(lambda *a: kda.kda_chunked(*a, doc, 64), q, k, v, g, beta)
+    assert kernel_pair_scores and kernel_pair_scores[0] == (1, 2, 2, 2, 64, 128)
+    size = float(jnp.abs(want_out).max())
+    assert np.abs(np.asarray(f32(got_out)) - np.asarray(want_out)).max() < 0.03 * size
+    for name, g_, w in zip(("q", "k", "v", "g", "beta"), got, want):
+        g_, w = np.asarray(g_, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g_).all(), name
+        assert np.abs(g_ - w).max() < 0.05 * np.abs(w).max(), name
+
+
+def test_pair_scores_take_the_kernels_on_a_tpu_only_and_only_where_they_tile(patch_kda):
+    x, k, cum, visible = _pair_inputs(0.1, True, 64, 2)
+    scores = lambda x, k, cum: kda._pair_scores(x, k, cum, visible)
+    # Tiled and bfloat16: the platform chooses, and this one is no TPU. The
+    # kernels were traced without `interpret`, so lowering them here would raise.
+    assert "platform_index" in str(jax.make_jaxpr(scores)(x, k, cum))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(scores)(x, k, cum)),
+        np.asarray(kda._pair_scores_slabs(x, k, cum, visible)),
+    )
+    # Lowered for a TPU, one kernel forward and one more with the backward: no
+    # [.., 16, 16, 128] differences in the program.
+    lowered = jax.jit(scores).trace(x, k, cum).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert "16x16x128" not in lowered.as_text()
+    both = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(scores(*a)), argnums=(0, 1, 2),
+    )).trace(x, k, cum).lower(lowering_platforms=("tpu",)).as_text()
+    assert both.count("tpu_custom_call") == 2    # forward; dx, dk and dcum fused
+    assert "16x16x128" not in both
+
+    # float32, narrow heads, or an odd number of them: the XLA form alone.
+    def kernel_refused(*args, **kwargs):
+        raise AssertionError("the kernel path was traced")
+
+    patch_kda("pair_scores", kernel_refused, module=kda_pair_scores)
+    f32 = lambda t: t.astype(jnp.float32)
+    for operands in (
+        (f32(x), f32(k), cum),
+        (x[..., :64], k[..., :64], cum[..., :64]),
+        (x[:, :, :1], k[:, :, :1], cum[:, :, :1]),
+    ):
+        assert "platform_index" not in str(jax.make_jaxpr(scores)(*operands))
+    q, k4, v, g, beta, doc = _delta_inputs(0.5, True)          # float32, K = 8
+    assert "platform_index" not in str(jax.make_jaxpr(
+        lambda *a: kda.kda_chunked(*a, doc, 64))(q, k4, v, g, beta))
+
+
+# -- one jitted rule, shared by the KDA layers of a step program -------------------
+
+
+def _kda_model_loss(layers):
+    """(loss(params) of a bfloat16 model of `layers` KDA layers whose heads
+    tile (2 heads x 128 channels, one chunk of 64), its parameters)."""
+    linear = {**KIMI_LINEAR, "kda_layers": list(range(1, layers + 1)),
+              "full_attn_layers": [], "num_heads": 2, "head_dim": 128}
+    model = _model(num_hidden_layers=layers, linear_attn_config=linear,
+                   kda_chunk_size=64, device_type="tpu")
+    features, labels = _batch()
+    params = model.init_variables(jax.random.PRNGKey(0), features)["params"]
+    return _loss_fn(model, features, labels), params
+
+
+def _lowered_for_a_tpu(layers):
+    loss, params = _kda_model_loss(layers)
+    return jax.jit(jax.value_and_grad(loss)).trace(params).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_a_step_program_holds_the_kernels_once_however_many_layers_call_them():
+    import re
+
+    two, three = _lowered_for_a_tpu(2), _lowered_for_a_tpu(3)
+    bodies = lambda text, kernel: len(re.findall(f'kernel_name = "{kernel}"', text))
+    calls = lambda text: len(re.findall(r"call @kda_chunked", text))
+    programs = lambda text: len(re.findall(r"func\.func private @kda_chunked", text))
+    for text in (two, three):
+        # The rule's forward program and its backward program, which holds
+        # the forward kernel once more: the rule recomputes itself there.
+        assert programs(text) == 2
+        assert bodies(text, "kda_pair_scores") == 2
+        assert bodies(text, "kda_pair_scores_backward") == 1
+        assert "16x16x128" not in text
+    # A call of each program a layer.
+    assert (calls(two), calls(three)) == (4, 6)
+
+
+def test_the_shared_jit_changes_no_bit(monkeypatch):
+    loss, params = _kda_model_loss(2)
+    # A new function each: jax caches a trace on the function it is given.
+    assert "kda_chunked" in str(jax.make_jaxpr(lambda p: loss(p))(params))
+    shared = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(kda, "kda_chunked", kda.kda_chunked.__wrapped__)
+    inlined = jax.jit(jax.value_and_grad(loss))(params)
+    assert "kda_chunked" not in str(jax.make_jaxpr(lambda p: loss(p))(params))
+    assert float(shared[0]) == float(inlined[0])
+    equal = jax.tree_util.tree_map(
+        lambda a, b: bool(jnp.array_equal(a, b)), shared[1], inlined[1])
+    assert all(jax.tree_util.tree_leaves(equal)), equal
+
+
+@pytest.fixture(scope="module")
+def one_described_chip():
+    """A v5e that is described and not attached: the chip's own compiler
+    takes the kernels at the cell's shapes (no time, no result)."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topology = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as error:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+    return SingleDeviceSharding(topology.devices[0])
+
+
+def test_the_chips_compiler_takes_the_kernels_at_the_cells_shapes(one_described_chip):
+    # A group of 16 heads of one layer of kimi_linear_48b_a3b_s1 at 16,384.
+    shape = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_described_chip)
+    x = shape((1, 256, 16, 2, 64, 128), jnp.bfloat16)
+    k = shape((1, 256, 16, 64, 128), jnp.bfloat16)
+    cum = shape((1, 256, 16, 64, 128), jnp.float32)
+    visible = shape((1, 256, 1, 64, 64), jnp.bool_)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda x, k, cum, visible: jnp.sum(kda._pair_scores(x, k, cum, visible)),
+        argnums=(0, 1, 2),
+    )).lower(x, k, cum, visible).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 # -- latent attention's widths through segment_attention ---------------------------

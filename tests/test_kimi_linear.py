@@ -25,6 +25,7 @@ from tests.sequence_lm_fixtures import (
     batch as _batch,
     kimi_model as _model,
     kimi_reference as _reference,
+    loss_fn as _loss_fn,
     model as _hybrid_model,
     segments as _segments,
     spans as _spans,
@@ -47,16 +48,6 @@ def _nest(flat):
             node = node.setdefault(name, {})
         node[leaf] = value
     return tree
-
-
-def _loss_fn(model, features, labels):
-    def loss(params):
-        outputs, _ = model.inference_network_fn(
-            {"params": params}, features, "train", labels=labels
-        )
-        return model.model_train_fn(features, labels, outputs, "train")[0]
-
-    return loss
 
 
 # -- packed documents against the same documents alone ----------------------------

@@ -23,6 +23,7 @@ from tests.sequence_lm_fixtures import (
     REPO as _REPO,
     SEQ,
     batch as _batch,
+    loss_fn as _loss_fn,
     model as _model,
     segments as _segments,
 )
@@ -53,16 +54,6 @@ def test_layer_pattern_is_read_up_to_num_hidden_layers():
         _model(layer_types=("mamba",), num_hidden_layers=2)
     with pytest.raises(ValueError, match="mamba_expand"):
         _model(mamba_d_head=16)
-
-
-def _loss_fn(model, features, labels):
-    def loss(params):
-        outputs, _ = model.inference_network_fn(
-            {"params": params}, features, "train", labels=labels
-        )
-        return model.model_train_fn(features, labels, outputs, "train")[0]
-
-    return loss
 
 
 def _loss_and_grads(model, variables, features, labels):
