@@ -21,7 +21,13 @@ of its own (`limits` in the cell's file, set from chip readings, PERF.md):
                ill-conditioned sum that any rounding upsets (PERF.md)
 
 The reference side is plain: value_and_grad of the reference's loss, a
-hand-written momentum or Adam update, float32 at `highest` precision.
+hand-written momentum or Adam update, float32 at `highest` precision. On
+a cell of several chips it follows the one global batch: the rows are laid
+over the cell's devices, the weights and the optimizer's state on each, and
+the same jitted step is partitioned by the compiler, so the statistics, the
+loss and the gradient are those of all the rows (the critic's float32 step
+takes 12.3 GB at 256 rows: a chip holds its own share of the rows and no
+more). On one device nothing is placed.
 `quant` lowers the precision (the control); `fault` plants one of the
 faults a training cell can have into the reference put in the program's
 place (tests and the control script read them).
@@ -152,26 +158,45 @@ _delta_norms = jax.jit(
 )
 
 
+def _placements(devices):
+    """(rows, whole): a batch with its leading axis split over `devices`,
+    and a tree held whole on each of them. The identity on one device."""
+    if devices is None or len(devices) < 2:
+        return (lambda tree: tree), (lambda tree: tree)
+    mesh = jax.sharding.Mesh(np.asarray(devices), ("rows",))
+    split = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("rows"))
+    whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    return (
+        lambda tree: jax.device_put(tree, split),
+        lambda tree: jax.device_put(tree, whole),
+    )
+
+
 def reference_readings(ref, config, params0, batches, base_key, *,
-                       quant=None, fault=None):
+                       quant=None, fault=None, devices=None):
     """Follows STEPS plain steps; returns {"loss": [..], "grad_norms": {..},
     "update_norms": {..}} as host floats.
 
     `batches`: the raw batch of each step (the same object STEPS times
     where the batch is resident). `base_key`: the key the program's step
     folds its step number into. `fault`: None, "state_unchanged" or
-    "half_batch".
+    "half_batch". `devices`: the cell's devices; with more than one the
+    rows of each batch are split over them (the batch's rows divide by
+    their number, as the program's do).
     """
     step = _reference_step(ref, config, quant)
+    rows, whole = _placements(devices)
+    params0 = whole(params0)
+    base_key = whole(base_key)
     params = params0
-    opt = _optimizer_init(ref.optimizer(config), params0)
+    opt = whole(_optimizer_init(ref.optimizer(config), params0))
     losses, grad_norms = [], None
     for index in range(STEPS):
         batch = batches[index]
         if fault == "half_batch":
             batch = jax.tree_util.tree_map(lambda x: x[: len(x) // 2], batch)
         new_params, new_opt, loss, norms = step(
-            params, opt, batch, base_key, jnp.asarray(index, jnp.int32)
+            params, opt, rows(batch), base_key, whole(jnp.asarray(index, jnp.int32))
         )
         if fault != "state_unchanged":
             params, opt = new_params, new_opt

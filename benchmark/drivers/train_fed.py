@@ -175,10 +175,24 @@ def run(run):
         generator = cfg.get_configurable("DefaultRecordInputGenerator")()
     timed = TimedGenerator(generator, window, keep=compare.STEPS)
     train_eval_model = cfg.get_configurable("train_eval_model")
+    # The trainer's default mesh spans every device jax shows. Where that
+    # is more than the cell was handed, it gets a mesh over those alone.
+    shown = len(jax.devices())
+    mesh = {}
+    if shown != len(run.devices):
+        from tensor2robot_tpu.parallel.mesh import make_mesh
+
+        mesh["mesh"] = make_mesh(devices=run.devices)
+    run.reporter.say(
+        f"mesh: data-parallel over {len(run.devices)} devices, "
+        f"{cell['batch']} rows each, {batch_size} a step (jax shows {shown}: "
+        + ("a mesh over the cell's own)" if mesh else "the trainer's default mesh)")
+    )
     train_eval_model(
         t2r_model=model,
         input_generator_train=timed,
         hook_builders=[_hook_builder(window, readings, run, timed)],
+        **mesh,
     )
     window.close()
 

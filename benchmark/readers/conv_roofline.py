@@ -12,8 +12,11 @@ def read(run):
     summary = run.trace_summary
     if not summary or not summary["steps"] or not summary["conv_s"]:
         return None
-    by_flops = run.flops["step_flops"] / run.peaks["bf16_flops_per_s"]
-    by_bytes = run.flops["step_bytes"] / run.peaks["hbm_bytes_per_s"]
+    # The counts are of the whole step's batch, the measured time a
+    # device's: each of the step's devices has its share of the rows.
+    chips = summary["devices"]
+    by_flops = run.flops["step_flops"] / chips / run.peaks["bf16_flops_per_s"]
+    by_bytes = run.flops["step_bytes"] / chips / run.peaks["hbm_bytes_per_s"]
     least = max(by_flops, by_bytes)
     measured = summary["conv_s"] / summary["steps"]
     run.reporter.say(

@@ -58,7 +58,7 @@ def read_seed(cell, config, seed, devices, reporter, *, control, also=()):
     )
     started = time.perf_counter()
     expected = compare.reference_readings(
-        reference, config, weights, batches, base_key
+        reference, config, weights, batches, base_key, devices=devices
     )
     reference_s = time.perf_counter() - started
     numbers, leaves = compare.compared_numbers(run.program_readings, expected)
@@ -73,13 +73,15 @@ def read_seed(cell, config, seed, devices, reporter, *, control, also=()):
         out["control"] = {}
         for quant in list(config["control"]) + list(also):
             other = compare.reference_readings(
-                reference, config, weights, batches, base_key, quant=quant
+                reference, config, weights, batches, base_key, quant=quant,
+                devices=devices,
             )
             out["control"][quant] = compare.compared_numbers(other, expected)[0]
         out["faults"] = {}
         for fault in FAULTS:
             other = compare.reference_readings(
-                reference, config, weights, batches, base_key, fault=fault
+                reference, config, weights, batches, base_key, fault=fault,
+                devices=devices,
             )
             out["faults"][fault] = compare.compared_numbers(other, expected)[0]
     return out

@@ -377,8 +377,9 @@ def loss_fn(params, batch, key, config, quant=None):
 def kernel_costs(config, batch, seq, bytes_per_element):
     """{"ssd": .., "attention": ..}: `flops.count` over this file's scan and
     attention core at the cell's shapes, times the layers of each kind: the
-    train step's model FLOPs and operand-and-result bytes of those products
-    alone, whatever the program computes them with."""
+    train step's model FLOPs of those products alone, whatever the program
+    computes them with, and the bytes a pass has to move: for the scan the
+    products' operands and results, for attention its inputs and outputs."""
     import flops
 
     s = _settings(config)
@@ -400,6 +401,15 @@ def kernel_costs(config, batch, seq, bytes_per_element):
         {"q": shape(batch, seq, kv, s["num_attention_heads"] // kv, hd),
          "k": shape(batch, seq, kv, hd), "v": shape(batch, seq, kv, hd)},
         ids, bytes_per_element=bytes_per_element,
+    )
+    # Attention's bytes are what a tiled kernel has to move: q, k, v, the
+    # output and a log-sum-exp a query head, once a pass (forward, and the
+    # two passes of their gradients), not the [heads, seq, seq] logits and
+    # probabilities of the products above, which such a kernel keeps on the
+    # chip (as `kernel_costs["mla"]` of kimi_linear_48b_a3b_s1.py counts).
+    q_heads = s["num_attention_heads"]
+    attention["step_bytes"] = 3 * bytes_per_element * batch * seq * (
+        2 * q_heads * hd + 2 * kv * hd + q_heads
     )
     layers = s["layer_types"]
     scale = lambda counted, times: {

@@ -150,7 +150,7 @@ def run_cell(cell, config, args, devices, reporter):
             run.check_inputs() if callable(run.check_inputs) else run.check_inputs
         )
         expected = compare.reference_readings(
-            reference, config, weights, batches, base_key
+            reference, config, weights, batches, base_key, devices=devices
         )
         numbers, leaves = compare.compared_numbers(run.program_readings, expected)
         numbers.update(run.extra_numbers)
@@ -207,7 +207,7 @@ def run_cell(cell, config, args, devices, reporter):
             )
             reporter.say("device time by category, ms a step: " + ", ".join(
                 f"{name} {1e3 * seconds / max(summary['steps'], 1):.2f}"
-                for name, seconds in list(summary["category_s"].items())[:6]
+                for name, seconds in list(summary["category_s"].items())[:12]
             ) + "; idle by host span, ms a step: " + ", ".join(
                 f"{name} {1e3 * seconds / max(summary['steps'], 1):.2f}"
                 for name, seconds in summary["idle_by_span_s"].items()

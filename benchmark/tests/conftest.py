@@ -9,11 +9,6 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-# The chip runs the pools' native backward (ops/pooling.py picks it by
-# platform); the CPU's default is the scatter-free one, which under jit
-# gives other gradients than the same code run eagerly (PERF.md, Open
-# questions). The rehearsals follow the chip.
-os.environ.setdefault("T2R_POOL_BACKWARD", "native")
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 for path in (BENCH_DIR, ROOT):

@@ -263,6 +263,14 @@ def test_scan_and_attention_counts_against_hand_counts():
     attention = 2 * rows * q_heads * SEQ * SEQ * head_dim * 2
     assert costs["attention"]["forward_flops"] == attention
     assert costs["attention"]["step_flops"] == 3 * attention
+    # Its bytes are a tiled kernel's: q and o (4 heads), k and v (2 heads)
+    # and a log-sum-exp a query head, at bf16, once a pass; no logits.
+    kv_heads = 2
+    assert costs["attention"]["step_bytes"] == 3 * 2 * rows * SEQ * (
+        2 * q_heads * head_dim + 2 * kv_heads * head_dim + q_heads)
+    twice = ref.kernel_costs(config, rows, 2 * SEQ, 2)["attention"]
+    assert twice["step_bytes"] == 2 * costs["attention"]["step_bytes"]
+    assert twice["step_flops"] > 2 * costs["attention"]["step_flops"]
     whole = flops.count(
         lambda p, b: ref.loss_fn(p, b, jax.random.PRNGKey(0), config),
         flops.abstract(ref.init_params(jax.random.PRNGKey(0), config)),

@@ -362,5 +362,5 @@ def test_counter_readers_on_a_hand_made_run():
 def test_every_new_metric_has_its_file_and_reader():
     names = [m["name"] for m in manifest.benchmark_json()["per_layer"]
              if m.get("workloads") == [CELL]]
-    assert len(names) == 13
+    assert len(names) >= 13
     assert {entry["name"] for entry, _, _ in manifest.per_layer(CELL)} >= set(names)

@@ -52,6 +52,46 @@ def test_every_cell_has_its_files():
         assert config["file"].startswith(tuple(p + "/" for p in B["paths"]))
 
 
+def test_at_most_a_quarter_of_the_cells_ask_for_four_chips():
+    # A four-chip cell costs four times the chip time in every later check:
+    # a quarter of the cells at most, rounded down, and one always may.
+    names = [w["name"] for w in B["workloads"]]
+    four = [w["name"] for w in B["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(names) // 4)
+    assert all(len(w["why"]) <= 200 for w in B["workloads"])
+
+
+def test_the_parked_four_chip_cell_is_the_fed_cell_on_four_chips():
+    """`critic_c64.train_fed_dp4` has its files and no entry (PERF.md
+    section 7: the program's init_state does not survive its first batch).
+    It comes back by entries alone, so the file has to stay the cell that
+    ISSUE 35 describes."""
+    name = "critic_c64.train_fed_dp4"
+    assert name not in [w["name"] for w in B["workloads"]]
+    cell = manifest.cell(name, listed=False)
+    one = manifest.cell("critic_c64.train_fed")
+    assert cell["chips"] == 4 and cell["config"] == one["config"] == "critic_c64"
+    assert cell["driver"] == one["driver"] and cell["batch"] == one["batch"] == 256
+    # Four times the records (six steps an epoch, as on one chip) and an id
+    # scale that holds them; nothing else of the traffic differs.
+    assert cell["traffic"] == dict(
+        one["traffic"], records=4 * one["traffic"]["records"], id_scale=8192
+    )
+    assert cell["trainer"] == {} and cell["environment"] == one["environment"]
+    assert cell["warmup_steps"] == one["warmup_steps"]
+    assert cell["end_to_end"] == one["end_to_end"]
+    # Its own per-layer metric waits with it: file and reader, no entry.
+    with open(os.path.join(
+        manifest.BENCH_DIR, "metrics", "collectives.exposed_ms_per_step.json"
+    )) as f:
+        metric = json.load(f)
+    assert metric["layer"] == "Device" and metric["source"] == "device_trace"
+    assert callable(manifest._load_module("readers", metric["reader"]).read)
+    assert "collectives.exposed_ms_per_step" not in [
+        m["name"] for m in B["per_layer"]
+    ]
+
+
 def test_metric_files_agree_with_the_manifest():
     for entry in B["per_layer"]:
         with open(os.path.join(

@@ -176,8 +176,9 @@ READINGS = {
     "host_input.h2d_put_ms_per_step": 0.015,
     # closed in the traced part: 20 and 40 us
     "host_input.read_ms_per_batch": 0.030,
-    # closed in it: -100..400 and 300..500 (the third closes after it)
-    "host_input.parse_ms_per_batch": 0.350,
+    # closed in it: -100..400 and 300..500 (the third closes after it): 700
+    # us of workers' time over 8 records, times 256
+    "host_input.parse_ms_per_batch": 0.700 / 8 * 256,
     "host_input.decode_share": 100.0 * 500 / 700,
     # 0..400 + 300..500 + 800..1000 over 2 workers x 1000 us
     "host_input.workers_busy_share": 40.0,

@@ -42,6 +42,33 @@ def test_decode_ms_per_image_is_decode_time_over_images(tmp_path, monkeypatch):
     assert read(_run(tmp_path)) == pytest.approx(0.65 / 10)
 
 
+def test_parse_ms_is_workers_time_per_256_records_slice_or_batch(tmp_path, monkeypatch):
+    """One batch of 8 records in 700 us of workers' time, as one span or as
+    four slices of 2: the same milliseconds per 256 records."""
+    def only(parse_spans):
+        return lambda spans: [
+            s for s in spans if s["name"] != "data.parse_chunk"
+        ] + parse_spans
+
+    read = _reader("host_input.parse_ms_per_batch").read
+    _with(monkeypatch, spans=only([
+        _span(40, "data.parse_chunk", W1, 100, 800, ordinal=1, records=8),
+    ]))
+    whole = read(_run(tmp_path))
+    _with(monkeypatch, spans=only([
+        _span(40 + i, "data.parse_chunk", W1 + i, 100, 275, ordinal=1,
+              first_row=2 * i, records=2)
+        for i in range(4)
+    ]))
+    assert read(_run(tmp_path)) == pytest.approx(whole)
+    assert whole == pytest.approx(0.700 / 8 * 256)
+    # Spans that count no records (a recorder older than PR 26's counts).
+    _with(monkeypatch, spans=only([
+        _span(40, "data.parse_chunk", W1, 100, 800, ordinal=1),
+    ]))
+    assert read(_run(tmp_path)) is None
+
+
 def test_sliced_batch_share_is_a_ratio_of_two_counters(tmp_path, monkeypatch):
     read = _reader("host_input.sliced_batch_share").read
     _with(monkeypatch, **{"data.parse_batches": 8, "data.parse_batches_sliced": 6})

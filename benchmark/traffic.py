@@ -16,8 +16,9 @@ cell's `traffic` block says what to make:
       image is a window of one of `base_images` larger camera-like frames
       at an offset of its own, encoded to jpeg in threads; its scalars are
       seeded; `world_vector[0]` carries the record's number (id / 4096, an
-      exact float32), so that the comparison can tell which record a
-      parsed row came from.
+      exact float32; `id_scale` names another power of two where a mix has
+      more than 4,095 records), so that the comparison can tell which
+      record a parsed row came from.
 
 Specs are read from the model's preprocessor: they are the program's
 public contract for what its input must look like.
@@ -31,6 +32,10 @@ import os
 import numpy as np
 
 ID_SCALE = 4096.0
+
+
+def _id_scale(params):
+    return float(params.get("id_scale", ID_SCALE))
 
 
 def _flat_specs(model):
@@ -115,8 +120,11 @@ def jpeg_records(model, seed, params, path):
     image_key = image_keys[0]
     height, width, _ = specs[image_key].shape
     count = int(params["records"])
-    if count >= ID_SCALE:
-        raise ValueError(f"at most {int(ID_SCALE) - 1} records")
+    id_scale = _id_scale(params)
+    if count >= id_scale:
+        raise ValueError(
+            f"at most {int(id_scale) - 1} records at id_scale {id_scale:g}"
+        )
     bases = int(params.get("base_images", 48))
     slack = int(params.get("window_slack", 64))
     quality = int(params.get("jpeg_quality", 90))
@@ -142,7 +150,7 @@ def jpeg_records(model, seed, params, path):
             else:
                 row[key] = rng.uniform(-1.0, 1.0, shape).astype(np.float32)
         id_key = params.get("id_field", "features/action/world_vector")
-        row[id_key][0] = np.float32(index / ID_SCALE)
+        row[id_key][0] = np.float32(index / id_scale)
         values.append(row)
 
     def encode(index):
@@ -228,7 +236,9 @@ def reference_batches(first_batches, records, image_key, params):
             "features": _flat_group(batch["features"]),
             "labels": _flat_group(batch["labels"]),
         }
-        ids = np.rint(program[id_group][id_name][:, 0] * ID_SCALE).astype(int)
+        ids = np.rint(
+            program[id_group][id_name][:, 0] * _id_scale(params)
+        ).astype(int)
         if ids.min() < 0 or ids.max() >= len(records):
             raise ValueError("a parsed row names no record that was written")
         mine = {"features": {}, "labels": {}}

@@ -82,6 +82,7 @@ class Window:
         self._dispatch_t0 = None
         self.spans = []                # (name, start, end), epoch ns, traced part
         self._compiles_at_open = None
+        self._compiles_at_last_stamp = None
         self.compiles_in_window = None
         # traced part
         self.trace_state = "off" if trace_dir is None else "waiting"
@@ -121,6 +122,7 @@ class Window:
             if self.steps_done == self.warmup_steps:
                 jax.block_until_ready(state)
                 self._compiles_at_open = self.reporter.backend_compiles
+                self._compiles_at_last_stamp = self._compiles_at_open
                 self.opened_at = time.perf_counter()
             return
         self._pending.append(metrics["loss"])
@@ -144,8 +146,12 @@ class Window:
         if self.trace_state == "on":
             self._stop_trace(self._last_state)
         self.drain()
+        # The window ends at its last stamp. What compiles after it (the
+        # trainer's closing checkpoint slices every array of a state that
+        # lies on several devices, a small program a shape and device) is
+        # not inside it.
         self.compiles_in_window = (
-            self.reporter.backend_compiles - self._compiles_at_open
+            self._compiles_at_last_stamp - self._compiles_at_open
         )
         self._last_state = None
 
@@ -157,6 +163,7 @@ class Window:
             value = float(loss)
         self.stamps.append(time.perf_counter())
         self.losses.append(value)
+        self._compiles_at_last_stamp = self.reporter.backend_compiles
 
     def _drive_trace(self, state):
         now = time.perf_counter()

@@ -32,6 +32,12 @@ import glob
 import os
 
 SYNC_LINE = "XLA Ops"
+#: HLO categories of the operations that move data between chips; the
+#: halves of an asynchronous one carry the name with `-start` / `-done`.
+COLLECTIVES = (
+    "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+    "all-to-all",
+)
 WINDOW_SPAN = "bench.trace_window"
 HOST_SPANS = ("bench.host_input.next", "bench.dispatch", "bench.readback")
 
@@ -217,6 +223,10 @@ def _is_device(plane):
     return plane.name.startswith("/device:TPU:")
 
 
+def is_collective(category):
+    return category.startswith(COLLECTIVES)
+
+
 def _op_label(stats, name):
     """`<hlo category>:<layer path>` of a device op: the categoriser's name."""
     category = stats.get("hlo_category", "uncategorized")
@@ -281,6 +291,7 @@ def summarize(planes, steps=None, epoch_spans=()):
     window_ps = hi - lo
 
     busy_ps, category_ps, label_ps = [], collections.Counter(), collections.Counter()
+    collective_ps, collective_n = collections.Counter(), collections.Counter()
     modules = 0
     for plane, ops in zip(devices, per_device_ops):
         intervals = _clip([(s, s + d) for _, s, d in ops], lo, hi)
@@ -291,8 +302,13 @@ def summarize(planes, steps=None, epoch_spans=()):
             if clipped <= 0:
                 continue
             stats = plane.event_stats.get(ident, {})
-            category_ps[stats.get("hlo_category", "uncategorized")] += clipped
+            category = stats.get("hlo_category", "uncategorized")
+            category_ps[category] += clipped
             label_ps[_op_label(stats, plane.event_names.get(ident, "?"))] += clipped
+            if is_collective(category):
+                op = (plane.event_names.get(ident, "?"), category)
+                collective_ps[op] += clipped
+                collective_n[op] += 1
         for name, evs in plane.lines:
             if name == "XLA Modules":
                 modules += sum(1 for _, s, d in evs if s >= lo and s + d <= hi)
@@ -340,6 +356,10 @@ def summarize(planes, steps=None, epoch_spans=()):
         "modules_in_window": modules,
         "category_s": {k: v / n_dev / 1e12 for k, v in category_ps.most_common()},
         "conv_s": conv_ps / n_dev / 1e12,
+        "collectives": {
+            name: [category, ps / n_dev / 1e12, collective_n[name, category] / n_dev]
+            for (name, category), ps in collective_ps.most_common()
+        },
         "device_ops": [
             [k, v / n_dev / 1e12] for k, v in label_ps.most_common(10)
         ],
