@@ -1380,7 +1380,14 @@ def train_eval_model(
         shard_weight_update=shard_weight_update,
         plan=plan,
     )
-    state = restore_or_init_state(manager, compiled, rng_init, first_batch)
+    # init_state runs the preprocessor and the model's init eagerly: over
+    # the mesh like every later batch, not over the global batch on the
+    # first device, which holds one device's share and no more. The
+    # laid-over copy is let go with the call; `first_batch` goes on into
+    # `host_batches` as the host arrays it is.
+    state = restore_or_init_state(
+        manager, compiled, rng_init, compiled.shard_batch(first_batch)
+    )
     start_step = int(jax.device_get(state.step))
 
     writer = MetricsWriter(
@@ -1549,7 +1556,8 @@ def train_eval_model(
                 with tracing.span("train.hooks", ordinal=step):
                     for hook in hooks:
                         hook.before_step(ctx)
-                with tracing.span("train.dispatch", ordinal=step):
+                with tracing.span("train.dispatch", ordinal=step) as dispatch:
+                    dispatch.add(late=infeed.late_at_dispatch(batch))
                     state, metrics = compiled.train_step(state, batch, rng_train)
                     token_sums = add_token_counts(token_sums, metrics)
                     # The enqueued step holds the batch from here. The
@@ -1611,7 +1619,8 @@ def train_eval_model(
                 with tracing.span("train.hooks", ordinal=step):
                     for hook in hooks:
                         hook.before_step(ctx)
-                with tracing.span("train.dispatch", ordinal=step):
+                with tracing.span("train.dispatch", ordinal=step) as dispatch:
+                    dispatch.add(late=infeed.late_at_dispatch(device_chunk))
                     state, stacked_metrics = compiled.train_scan(
                         state, device_chunk, rng_train
                     )

@@ -8,6 +8,9 @@ span of that thread, the ordinal of the batch it works on (inherited from
 the enclosing span when not given) and the counts known at the boundary.
 `add(**counts)` adds to the innermost open span of the calling thread and
 does nothing outside one; `count(name, n)` adds to a cumulative counter.
+`since(name, start_ns, ...)` records a span that another thread opened at
+`start_ns` and the calling thread closes now: what starts on the train
+thread and ends in a runtime's own threads (a transfer) is seen to its end.
 A closed span also adds its duration and 1 to the counters `<name>.ns`
 and `<name>.n`, so a rate over any interval is a difference of two
 `counters()` reads and does not depend on what the ring still holds.
@@ -114,14 +117,31 @@ class Recorder:
         with self._lock:
             self._counters[name] += n
 
+    def _record(self, name, ordinal, counts, thread, start_ns, end_ns) -> None:
+        span = Span(self, name, ordinal, counts)
+        span.ident = next(self._idents)
+        span.thread = thread
+        span.start_ns, span.end_ns = start_ns, end_ns
+        self._close(span)
+
     def adopt(self, span: Dict[str, Any]) -> None:
         """Records a span that closed in another process (a parse worker
         ships `Span.as_dict()` home); its id and parent stay behind."""
-        adopted = Span(self, span["name"], span["ordinal"], dict(span["counts"]))
-        adopted.ident = next(self._idents)
-        adopted.thread = span["thread"]
-        adopted.start_ns, adopted.end_ns = span["start_ns"], span["end_ns"]
-        self._close(adopted)
+        self._record(
+            span["name"], span["ordinal"], dict(span["counts"]),
+            span["thread"], span["start_ns"], span["end_ns"],
+        )
+
+    def since(
+        self, name: str, start_ns: int, ordinal: Optional[int] = None, **counts
+    ) -> None:
+        """Records a span from `start_ns` (a `time.time_ns()` stamp, taken
+        by whichever thread began the work) to now, under the calling
+        thread and no parent."""
+        self._record(
+            name, ordinal, counts, threading.get_ident(), start_ns,
+            time.time_ns(),
+        )
 
     def counters(self) -> Dict[str, int]:
         with self._lock:
@@ -145,5 +165,6 @@ span = RECORDER.span
 add = RECORDER.add
 count = RECORDER.count
 adopt = RECORDER.adopt
+since = RECORDER.since
 counters = RECORDER.counters
 snapshot = RECORDER.snapshot

@@ -2,12 +2,15 @@
 learner's host path writes into it: dataset, infeed, train loop."""
 
 import concurrent.futures
+import gc
 import json
 import os
 import sys
 import threading
 import time
+import weakref
 
+import jax
 import numpy as np
 import pytest
 
@@ -172,6 +175,25 @@ class TestRecorder:
         )
         assert recorder.counters()["data.parse_chunk.n"] == 1
         json.dumps(recorder.snapshot())  # what spans.jsonl writes
+
+
+    def test_since_closes_on_the_calling_thread_what_another_opened(self):
+        recorder = tracing.Recorder()
+        start = time.time_ns()
+        with recorder.span("enclosing", ordinal=1):
+            worker = threading.Thread(
+                target=lambda: recorder.since("flight", start, ordinal=4, bytes=9)
+            )
+            worker.start()
+            worker.join(timeout=30)
+        flight, enclosing = recorder.snapshot()["spans"]
+        assert flight["name"] == "flight" and flight["start_ns"] == start
+        assert start <= flight["end_ns"] <= enclosing["end_ns"]
+        assert flight["thread"] == worker.ident != enclosing["thread"]
+        # No parent and no inherited ordinal: it is not the thread's nesting.
+        assert flight["parent"] is None and flight["ordinal"] == 4
+        assert flight["counts"] == {"bytes": 9}
+        assert recorder.counters()["flight.n"] == 1
 
 
 def write_jpeg_records(tmp_path, n=24, first_chunk=4):
@@ -415,4 +437,199 @@ class TestTrainLoopSpans:
             "input/parse_ms_per_batch": 30.0,
             "input/prefetch_empty_share": 0.25,
             "checkpoint/stall_ms": 7.0,
+        }
+
+
+class _Placed:
+    """What a fake `shard_fn` returns: one leaf that is resident once
+    `arrive()` has been called, and says so as a `jax.Array` would."""
+
+    def __init__(self):
+        self._arrived = threading.Event()
+
+    def arrive(self):
+        self._arrived.set()
+
+    def is_ready(self):
+        return self._arrived.is_set()
+
+    def block_until_ready(self):
+        assert self._arrived.wait(timeout=30)
+        return self
+
+
+def _wait_for(predicate, seconds=30.0):
+    deadline = time.monotonic() + seconds
+    while not predicate() and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return predicate()
+
+
+def _transfers(mark, name="infeed"):
+    return by_name(tracing.snapshot(since_ns=mark)["spans"], name + ".transfer")
+
+
+def _watchers(name="infeed"):
+    return [
+        t for t in threading.enumerate() if t.name == name + ".transfer"
+    ]
+
+
+class TestTransferSpans:
+    def test_a_transfer_closes_at_the_arrival_and_not_at_the_enqueue(self):
+        mark = time.time_ns()
+        placed = []
+
+        def shard_fn(item):
+            placed.append(_Placed())
+            return placed[-1]
+
+        batches = [np.zeros((4, 3), np.uint8), np.ones((4, 3), np.uint8)]
+        it = infeed.device_prefetch(
+            iter(batches), shard_fn, depth=2, ordinals=iter([7, 8, 9])
+        )
+        first = next(it)  # both are enqueued; the consumer was not held up
+        assert first is placed[0] and len(placed) == 2
+        mine = threading.get_ident()
+        puts = [
+            s for s in by_name(tracing.snapshot(since_ns=mark)["spans"], "infeed.h2d")
+            if s["thread"] == mine
+        ]
+        assert [s["ordinal"] for s in puts] == [7, 8]
+        # infeed.h2d is what it was: the enqueue, with the bytes handed over.
+        assert all(s["counts"] == {"bytes": 12} for s in puts)
+        time.sleep(0.05)
+        assert _transfers(mark) == []  # nothing has arrived yet
+
+        placed[0].arrive()
+        assert _wait_for(lambda: len(_transfers(mark)) == 1)
+        (transfer,) = _transfers(mark)
+        assert transfer["ordinal"] == 7
+        assert transfer["counts"] == {"bytes": 12, "devices": 1}
+        assert transfer["thread"] != mine and transfer["parent"] is None
+        # It opens where the enqueue opens and outlasts it.
+        assert transfer["start_ns"] == puts[0]["start_ns"]
+        assert transfer["end_ns"] >= puts[0]["end_ns"] + 40e6
+        placed[1].arrive()
+        assert _wait_for(lambda: len(_transfers(mark)) == 2)
+        assert [s["ordinal"] for s in _transfers(mark)] == [7, 8]
+        assert list(it) == [placed[1]]
+
+    def test_the_watcher_lets_a_batch_go_when_it_has_arrived(self):
+        mark = time.time_ns()
+        it = infeed.device_prefetch(
+            iter([np.zeros(3)]), lambda item: _Placed(), depth=1
+        )
+        batch = next(it)
+        held = weakref.ref(batch)
+        batch.arrive()
+        assert _wait_for(lambda: len(_transfers(mark)) == 1)
+        del batch
+        gc.collect()
+        assert held() is None
+
+    def test_the_watcher_ends_with_the_iterator(self):
+        before = set(_watchers())
+        placed = []
+
+        def shard_fn(item):
+            placed.append(_Placed())
+            return placed[-1]
+
+        it = infeed.device_prefetch(
+            iter([np.zeros(3), np.zeros(3), np.zeros(3)]), shard_fn, depth=1
+        )
+        next(it)
+        (watcher,) = set(_watchers()) - before
+        assert watcher.daemon and watcher.is_alive()
+        it.close()  # the consumer left early, as the train loop's break does
+        # It ends once what was handed to it has arrived, and not before.
+        watcher.join(timeout=0.05)
+        assert watcher.is_alive() and len(placed) == 2
+        for batch in placed:
+            batch.arrive()
+        watcher.join(timeout=30)
+        assert not watcher.is_alive()
+
+        drained = infeed.device_prefetch(
+            iter([np.zeros(3)]), lambda item: item, depth=2, name="drained"
+        )
+        assert len(list(drained)) == 1
+        assert _wait_for(lambda: not _watchers("drained"))
+
+    def test_a_real_batch_says_how_many_devices_it_lies_over(self):
+        from tensor2robot_tpu.parallel import mesh as mesh_lib
+
+        mark = time.time_ns()
+        mesh = mesh_lib.make_mesh(devices=jax.devices()[:4])
+        batch = {"x": np.arange(32, dtype=np.float32).reshape(8, 4)}
+        (placed,) = infeed.device_prefetch(
+            iter([batch]), lambda b: mesh_lib.shard_batch(b, mesh), depth=2,
+            name="mesh_infeed",
+        )
+        assert _wait_for(lambda: len(_transfers(mark, "mesh_infeed")) == 1)
+        (transfer,) = _transfers(mark, "mesh_infeed")
+        assert transfer["counts"]["devices"] == 4
+        assert transfer["counts"]["bytes"] == 128
+        assert transfer["counts"].get("last_device", 0) in {
+            d.id for d in mesh.devices.flat
+        }
+        np.testing.assert_array_equal(np.asarray(placed["x"]), batch["x"])
+
+    @pytest.mark.parametrize("ready,late", [(True, 0), (False, 1)])
+    def test_late_at_dispatch_counts_a_batch_that_is_not_there(self, ready, late):
+        before = tracing.counters()
+        leaf = _Placed()
+        if ready:
+            leaf.arrive()
+        batch = {"features": {"image": leaf}, "host_side": np.zeros(2)}
+        assert infeed.late_at_dispatch(batch) == late
+        after = tracing.counters()
+        assert after["infeed.dispatched"] - before.get("infeed.dispatched", 0) == 1
+        assert (
+            after["infeed.late_at_dispatch"]
+            - before.get("infeed.late_at_dispatch", 0)
+        ) == late
+
+    @pytest.mark.parametrize("iterations_per_loop", [1, 4])
+    def test_both_train_loops_count_their_dispatches(
+        self, tmp_path, iterations_per_loop
+    ):
+        mark = time.time_ns()
+        before = tracing.counters()
+        train_eval.train_eval_model(
+            t2r_model=MockT2RModel(device_type="cpu"),
+            input_generator_train=MockInputGenerator(batch_size=8),
+            model_dir=str(tmp_path / "run"),
+            max_train_steps=8,
+            save_checkpoints_steps=8,
+            iterations_per_loop=iterations_per_loop,
+        )
+        after = tracing.counters()
+        spans = tracing.snapshot(since_ns=mark)["spans"]
+        dispatches = by_name(spans, "train.dispatch")
+        assert len(dispatches) == 8 // iterations_per_loop
+        assert all(s["counts"]["late"] in (0, 1) for s in dispatches)
+        assert (
+            after["infeed.dispatched"] - before.get("infeed.dispatched", 0)
+            == len(dispatches)
+        )
+        assert (
+            after["infeed.late_at_dispatch"]
+            - before.get("infeed.late_at_dispatch", 0)
+        ) == sum(s["counts"]["late"] for s in dispatches)
+        # Every batch the loop took has a transfer of its ordinal, laid
+        # over the trainer's mesh, that opened with its enqueue.
+        assert _wait_for(lambda: len(_transfers(mark)) >= len(dispatches))
+        puts = {
+            s["ordinal"]: s for s in by_name(spans, "infeed.h2d")
+        }
+        for transfer in _transfers(mark):
+            assert transfer["start_ns"] == puts[transfer["ordinal"]]["start_ns"]
+            assert transfer["counts"]["devices"] == len(jax.devices())
+            assert transfer["counts"]["bytes"] == (
+                puts[transfer["ordinal"]]["counts"]["bytes"]
+            )
+        assert {s["ordinal"] for s in dispatches} <= {
+            s["ordinal"] for s in _transfers(mark)
         }

@@ -132,6 +132,83 @@ class TestTrainEvalModel:
         )
         assert final_metrics["accuracy"] > 0.8, final_metrics
 
+    @pytest.mark.parametrize("model_name", ["mock", "critic"])
+    def test_init_state_gets_its_batch_laid_over_the_mesh(
+        self, tmp_path, monkeypatch, model_name
+    ):
+        """The eager preprocessor and model init of `init_state` run over
+        the mesh, on each device its share of the first batch, and the
+        state for a seed is bit for bit the one a single device gives and
+        the one the host batch gave."""
+        from tensor2robot_tpu.data.input_generators import (
+            DefaultRandomInputGenerator,
+        )
+        from tensor2robot_tpu.parallel.mesh import make_mesh
+        from tensor2robot_tpu.research.qtopt.t2r_models import (
+            Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom,
+        )
+
+        def make():
+            if model_name == "mock":
+                return (
+                    MockT2RModel(device_type="cpu"),
+                    MockInputGenerator(batch_size=8),
+                )
+            return (
+                Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom(
+                    device_type="tpu", image_size=(96, 96), num_convs=(2, 2, 1)
+                ),
+                DefaultRandomInputGenerator(batch_size=8),
+            )
+
+        handed, states = [], []
+        real_init_state = train_eval.CompiledModel.init_state
+
+        def spy(self, rng, example_batch):
+            handed.append({
+                len(leaf.sharding.device_set)
+                for leaf in jax.tree_util.tree_leaves(example_batch)
+            })
+            state = real_init_state(self, rng, example_batch)
+            states.append(jax.device_get(state))
+            return state
+
+        monkeypatch.setattr(train_eval.CompiledModel, "init_state", spy)
+        for count in (4, 1):
+            model, generator = make()
+            train_eval.train_eval_model(
+                t2r_model=model,
+                input_generator_train=generator,
+                model_dir=str(tmp_path / f"devices{count}"),
+                max_train_steps=0,
+                mesh=make_mesh(devices=jax.devices()[:count]),
+                seed=11,
+            )
+        assert handed == [{4}, {1}]
+
+        # As the trainer did it before: the host batch, one device.
+        model, generator = make()
+        wrapped = train_eval.maybe_wrap_for_tpu(model)
+        train_eval.provide_input_generator_with_model_information(
+            generator, wrapped, "train"
+        )
+        host_batch = next(iter(generator.create_dataset("train")))
+        compiled = train_eval.CompiledModel(
+            wrapped, mesh=make_mesh(devices=jax.devices()[:1])
+        )
+        rng_init, _ = jax.random.split(jax.random.PRNGKey(11))
+        before = jax.device_get(
+            real_init_state(compiled, rng_init, host_batch)
+        )
+        four, one = states
+        for other in (one, before):
+            leaves, others = (
+                jax.tree_util.tree_leaves(tree) for tree in (four, other)
+            )
+            assert len(leaves) == len(others) > 4
+            for a, b in zip(leaves, others):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_ema_params(self, tmp_path):
         model = MockT2RModel(device_type="cpu", use_avg_model_params=True)
         final_metrics = train_eval.train_eval_model(
