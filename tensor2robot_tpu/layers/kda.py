@@ -38,9 +38,18 @@ time, never in HBM; everywhere else in HBM as float32 arrays of XLA's, a
 slab of chunks at a time, each slab recomputed in the backward pass
 (`_pair_scores_slabs`). `(I + A)^-1` is forward substitution inside a
 sub-block, all sub-blocks at once, and the block-triangular formula above
-it. The carry between chunks is a `lax.scan` over two products a chunk;
-everything else is one einsum over all chunks. The backward is autodiff
-(but for the pair scores' kernels),
+it. W and U are one einsum over all chunks. The walk over the chunks (the
+state from chunk to chunk, and `V'` and `o`, which read the state that enters
+a chunk) has two forms too, chosen the same way (`_chunk_outputs`): on a TPU,
+with bfloat16 operands, K and V multiples of 128 and a group's heads a
+multiple of eight, `ops/kda_carry`'s Pallas kernels: the state stays in VMEM
+as float32 for the whole walk, a grid step a chunk of eight heads forms `V'`,
+`o` and the next state where it is, and only the backward's residual, the
+entering states in float32, is written to HBM (by the forward that the
+backward pass runs; the backward kernel walks the chunks in reverse);
+everywhere else a `lax.scan` over two products a chunk that stacks the
+entering states in HBM, and three einsums over all chunks that read them.
+The backward is autodiff (but for the kernels' own),
 a group of heads at a time, each group recomputed from q, k, v, g and beta
 (`kda_chunked`); the output carries the `checkpoint_name` "kda_out" so
 that a block under `nn.remat` can keep it and not run the rule a third
@@ -82,7 +91,7 @@ from tensor2robot_tpu.layers.mamba2 import (
     document_index,
 )
 from tensor2robot_tpu.layers.transformer import RMSNorm
-from tensor2robot_tpu.ops import kda_pair_scores
+from tensor2robot_tpu.ops import kda_carry, kda_pair_scores
 
 #: Positions of a sub-block: the [c, c, K] decay differences are formed
 #: inside one only, and forward substitution runs over its c rows.
@@ -255,6 +264,84 @@ def head_groups(batch: int, seq: int, heads: int, width: int) -> int:
     )
 
 
+def _chunk_outputs(w, u, k_end, kept, q_start, scores):
+    """The walk over the chunks: out [B, N, H, C, V] in the compute dtype
+    where, per head and with S = 0 [K, V] float32 entering chunk 0, for n =
+    0 .. N - 1
+
+        S~     = S rounded to the compute dtype
+        fresh  = u[n] - (w[n] @ S~) rounded to the compute dtype
+        out[n] = q_start[n] @ S~ + b_scores[n] @ fresh      (float32 sums)
+        S      = kept[n][:, None] * S + k_end[n]^T @ fresh
+
+    w, k_end, q_start [B, N, H, C, K] and u [B, N, H, C, V] in the compute
+    dtype, kept [B, N, H, K] float32, b_scores row 0 of scores [B, N, H, R,
+    C, C] float32 (`_pair_scores`' stacked rows, handed on whole: the kernels
+    cut the row out themselves, where XLA would write the slice to HBM); the
+    document masks are inside kept, k_end, q_start and the scores already. Two
+    implementations of that one contract, chosen as `_pair_scores` chooses:
+
+    * `ops/kda_carry.chunk_outputs`, Pallas kernels (forward, and a backward
+      that walks the chunks in reverse) that keep S in VMEM for the whole
+      walk and form `fresh` and `out` where the state is, where the program
+      is lowered for a TPU (`lax.platform_dependent`) and the operands tile
+      (`kda_carry.tiles`: bfloat16, K and V multiples of 128, C of 16, the
+      heads a multiple of the heads a grid step takes);
+    * `_chunk_outputs_scan`, the XLA form and the definition, everywhere
+      else: other platforms, float32, narrow heads, odd head counts.
+    """
+    if kda_carry.tiles(w, u):
+        return lax.platform_dependent(
+            w, u, k_end, kept, q_start, scores,
+            tpu=kda_carry.chunk_outputs, default=_chunk_outputs_scan,
+        )
+    return _chunk_outputs_scan(w, u, k_end, kept, q_start, scores)
+
+
+def _chunk_outputs_scan(w, u, k_end, kept, q_start, scores):
+    """`_chunk_outputs` in XLA: the carry a `lax.scan` over two products a
+    chunk that stacks every chunk's entering state ([B, N, H, K, V] in the
+    compute dtype, in HBM), then one einsum each over all chunks for `fresh`
+    (a second time) and the output's two products. Backward by autodiff."""
+    batch, _, heads, _, width = w.shape
+    dtype = w.dtype
+    f32 = jnp.float32
+    precision = _highest(dtype)
+
+    def carry(state, inputs):
+        """state [B, H, K, V] float32 enters the chunk; the next leaves."""
+        w_n, u_n, k_end_n, kept_n = inputs
+        entering = state.astype(dtype)
+        fresh = u_n - jnp.einsum(
+            "bhck,bhkv->bhcv", w_n, entering, precision=precision,
+            preferred_element_type=f32,
+        ).astype(dtype)
+        left = kept_n[..., None] * state + jnp.einsum(
+            "bhck,bhcv->bhkv", k_end_n, fresh, precision=precision,
+            preferred_element_type=f32,
+        )
+        return left, entering
+
+    chunk_major = lambda t: jnp.moveaxis(t, 1, 0)
+    _, states = lax.scan(
+        carry, jnp.zeros((batch, heads, width, u.shape[-1]), f32),
+        (chunk_major(w), chunk_major(u), chunk_major(k_end), chunk_major(kept)),
+    )
+    states = jnp.moveaxis(states, 0, 1)                     # [B, N, H, K, V]
+    fresh = u - jnp.einsum(
+        "bnhck,bnhkv->bnhcv", w, states, precision=precision,
+        preferred_element_type=f32,
+    ).astype(dtype)
+    out = jnp.einsum(
+        "bnhck,bnhkv->bnhcv", q_start, states, precision=precision,
+        preferred_element_type=f32,
+    ) + jnp.einsum(
+        "bnhij,bnhjv->bnhiv", scores[..., 0, :, :].astype(dtype), fresh,
+        precision=precision, preferred_element_type=f32,
+    )
+    return out.astype(dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, doc: jax.Array, chunk: int = 64) -> jax.Array:
@@ -323,7 +410,6 @@ def _kda_heads(q, k, v, g, beta, *, doc, chunk):
     cum = jnp.cumsum(g, axis=-2)                            # [B, N, H, C, K]
     with jax.named_scope("kda/pair_scores"):
         scores = _pair_scores(jnp.stack([q, k], axis=3), k, cum, same)
-    b_scores = scores[..., 0, :, :]
     a_scores = beta * jnp.tril(scores[..., 1, :, :], k=-1)
     solve = unit_lower_inverse(a_scores).astype(dtype)      # T [B, N, H, C, C]
 
@@ -342,38 +428,9 @@ def _kda_heads(q, k, v, g, beta, *, doc, chunk):
         to_end, jnp.exp(cum[..., -1:, :] - cum), 0.0)).astype(dtype)
     kept = jnp.where(through, jnp.exp(cum[..., -1, :]), 0.0)        # [B, N, H, K]
 
-    def carry(state, inputs):
-        """state [B, H, K, V] float32 enters the chunk; the next leaves."""
-        w_n, u_n, k_end_n, kept_n = inputs
-        entering = state.astype(dtype)
-        fresh = u_n - jnp.einsum(
-            "bhck,bhkv->bhcv", w_n, entering, precision=precision,
-            preferred_element_type=f32,
-        ).astype(dtype)
-        left = kept_n[..., None] * state + jnp.einsum(
-            "bhck,bhcv->bhkv", k_end_n, fresh, precision=precision,
-            preferred_element_type=f32,
-        )
-        return left, entering
-
-    chunk_major = lambda t: jnp.moveaxis(t, 1, 0)
-    _, states = lax.scan(
-        carry, jnp.zeros((batch, heads, width, v.shape[-1]), f32),
-        (chunk_major(w), chunk_major(u), chunk_major(k_end), chunk_major(kept)),
-    )
-    states = jnp.moveaxis(states, 0, 1)                     # [B, N, H, K, V]
-    fresh = u - jnp.einsum(
-        "bnhck,bnhkv->bnhcv", w, states, precision=precision,
-        preferred_element_type=f32,
-    ).astype(dtype)
-    out = jnp.einsum(
-        "bnhck,bnhkv->bnhcv", q_start, states, precision=precision,
-        preferred_element_type=f32,
-    ) + jnp.einsum(
-        "bnhij,bnhjv->bnhiv", b_scores.astype(dtype), fresh,
-        precision=precision, preferred_element_type=f32,
-    )
-    return jnp.moveaxis(out, 2, 3).reshape(batch, seq, heads, -1).astype(dtype)
+    with jax.named_scope("kda/carry"):
+        out = _chunk_outputs(w, u, k_end, kept, q_start, scores)
+    return jnp.moveaxis(out, 2, 3).reshape(batch, seq, heads, -1)
 
 
 def _unit_norm(x: jax.Array) -> jax.Array:

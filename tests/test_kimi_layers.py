@@ -1,13 +1,16 @@
 """The layers `KimiLinearLMModel` brought (ISSUE 32): the chunked delta rule
 against the stepped recurrence, values and gradients, under a decay that
 would overflow a factored form and across document resets; the pair scores'
-Pallas kernels (ISSUE 33, 34) in interpret mode against the XLA form, the
-choice between them, and the rule as one jitted function that every KDA
-layer of a step program shares; latent attention's widths through
+Pallas kernels (ISSUE 33, 34) and the kernels of the walk over the chunks
+(ISSUE 37: the carry and the products that read the chunks' states) in
+interpret mode against the XLA forms, the choice between them, and the rule
+as one jitted function that every KDA layer of a step program shares; latent attention's widths through
 `segment_attention`, kernel and einsum; routed experts that drop no token
 whatever the imbalance and whose shares add up to the uncut layer. The model
 itself is in tests/test_kimi_linear.py. CPU, tiny sizes, float32 but for the
 kernels' operands."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +20,7 @@ from jax import lax
 
 from tensor2robot_tpu.layers import kda
 from tensor2robot_tpu.layers.moe import RoutedExperts
+from tensor2robot_tpu.ops import kda_carry
 from tensor2robot_tpu.ops import kda_pair_scores
 from tensor2robot_tpu.ops import flash_attention as flash_lib
 from tensor2robot_tpu.ops import moe as moe_ops
@@ -240,6 +244,160 @@ def test_pair_scores_kernel_gradients_are_the_xla_forms(resets, strength, chunk,
         assert np.abs(g - w).max() <= tolerance * np.abs(w).max(), name
 
 
+# -- the walk over the chunks: its kernels (interpret mode) against the XLA form ---
+
+
+def _packed_ids(seq):
+    """One row of `seq` >= 256 positions: a document that ends inside a chunk
+    (and inside a sub-block), one that ends at a chunk's edge, one that spans
+    several chunks, a short one and padding."""
+    ids = np.ones((1, seq), np.int32)
+    ids[0, 37:] = 2
+    ids[0, 64:] = 3
+    ids[0, 200:] = 4
+    ids[0, 230:] = 0
+    return ids
+
+
+@functools.lru_cache(maxsize=None)     # eager, op by op: seconds a call
+def _walk_inputs(strength, resets, chunk, heads, seq=256):
+    """Operands of `_chunk_outputs` as `_kda_heads` makes them at K = V = 128
+    from bfloat16 q, k, v: (w, u, k_end, kept, q_start, scores)."""
+    q, k, v, g, beta, doc = _delta_inputs(
+        strength, False, seq=seq, batch=1, heads=heads, width=128)
+    if resets:
+        doc = kda.document_index(jnp.asarray(_packed_ids(seq)))
+    held = []
+
+    def capture(*operands):
+        held.extend(operands)
+        return kda._chunk_outputs_scan(*operands)
+
+    walk, kda._chunk_outputs = kda._chunk_outputs, capture
+    try:
+        rounded = lambda t: t.astype(jnp.bfloat16)
+        kda._kda_heads(rounded(q), rounded(k), rounded(v), g, beta, doc=doc, chunk=chunk)
+    finally:
+        kda._chunk_outputs = walk
+    return tuple(held)
+
+
+def _kernel_walk(*operands, heads_a_step=8):
+    return kda_carry.chunk_outputs(*operands, heads_a_step, True)
+
+
+WALK_SHAPES = [(16, 8, 8), (64, 16, 16)]       # (chunk, heads, heads a grid step)
+WALK_OPERANDS = ("w", "u", "k_end", "kept", "q_start", "scores")
+
+
+@pytest.mark.parametrize("chunk,heads,hb", WALK_SHAPES)
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_carry_kernel_is_the_xla_form(resets, strength, chunk, heads, hb):
+    operands = _walk_inputs(strength, resets, chunk, heads)
+    assert kda_carry.tiles(operands[0], operands[1], hb)
+    want = kda._chunk_outputs_scan(*operands)
+    got = _kernel_walk(*operands, heads_a_step=hb)
+    assert got.dtype == want.dtype == jnp.bfloat16 and got.shape == want.shape
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    # The same roundings at the same points; the sums inside a product run in
+    # another order, and a state that rounds the other way moves what follows
+    # (under a weak decay for many chunks): a bfloat16 step of the outputs.
+    size = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) <= 2 ** -7 * size
+
+
+@pytest.mark.parametrize("chunk,heads,hb", WALK_SHAPES)
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_carry_kernel_gradients_are_the_xla_forms(resets, strength, chunk, heads, hb):
+    operands = _walk_inputs(strength, resets, chunk, heads)
+    weight = jnp.asarray(
+        np.random.RandomState(5).randn(*operands[1].shape), jnp.float32)
+
+    def both(walk):
+        return jax.grad(
+            lambda *a: jnp.sum(walk(*a).astype(jnp.float32) * weight),
+            argnums=range(6))(*operands)
+
+    got = both(lambda *a: _kernel_walk(*a, heads_a_step=hb))
+    want = both(kda._chunk_outputs_scan)
+    # Autodiff rounds every product's gradient to bfloat16 and sums the
+    # rounded parts; the kernel sums in float32 and rounds once. The rows of
+    # the scores that the walk does not read get zeros from both.
+    assert not np.asarray(got[5][:, :, :, 1:]).any()
+    for name, g, w in zip(WALK_OPERANDS, got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), name
+        assert np.abs(g - w).max() <= 0.02 * np.abs(w).max(), name
+
+
+def _parents_walk(w, u, k_end, kept, q_start, scores):
+    """The carry and the three products of `_kda_heads` as they stood before
+    ISSUE 37, line for line, from its slice of the scores to its rounding
+    of the output (which it did after a transpose)."""
+    b_scores = scores[..., 0, :, :]
+    batch, _, heads, _, width = w.shape
+    dtype = w.dtype
+    f32 = jnp.float32
+    precision = kda._highest(dtype)
+
+    def carry(state, inputs):
+        w_n, u_n, k_end_n, kept_n = inputs
+        entering = state.astype(dtype)
+        fresh = u_n - jnp.einsum(
+            "bhck,bhkv->bhcv", w_n, entering, precision=precision,
+            preferred_element_type=f32,
+        ).astype(dtype)
+        left = kept_n[..., None] * state + jnp.einsum(
+            "bhck,bhcv->bhkv", k_end_n, fresh, precision=precision,
+            preferred_element_type=f32,
+        )
+        return left, entering
+
+    chunk_major = lambda t: jnp.moveaxis(t, 1, 0)
+    _, states = lax.scan(
+        carry, jnp.zeros((batch, heads, width, u.shape[-1]), f32),
+        (chunk_major(w), chunk_major(u), chunk_major(k_end), chunk_major(kept)),
+    )
+    states = jnp.moveaxis(states, 0, 1)
+    fresh = u - jnp.einsum(
+        "bnhck,bnhkv->bnhcv", w, states, precision=precision,
+        preferred_element_type=f32,
+    ).astype(dtype)
+    out = jnp.einsum(
+        "bnhck,bnhkv->bnhcv", q_start, states, precision=precision,
+        preferred_element_type=f32,
+    ) + jnp.einsum(
+        "bnhij,bnhjv->bnhiv", b_scores.astype(dtype), fresh,
+        precision=precision, preferred_element_type=f32,
+    )
+    return out.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_xla_form_of_the_walk_changes_no_bit(dtype):
+    operands = _walk_inputs(0.5, True, 64, 8)
+    cast = lambda t: t if t.dtype == jnp.float32 else t.astype(dtype)
+    operands = tuple(cast(t) for t in operands)
+    weight = jnp.asarray(
+        np.random.RandomState(5).randn(*operands[1].shape), jnp.float32)
+    both = lambda walk: jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(walk(*a).astype(jnp.float32) * weight),
+        argnums=range(6)))(*operands)
+    # On this platform the choice is the XLA form whatever the operands.
+    (got, got_grads), (want, want_grads) = both(kda._chunk_outputs), both(_parents_walk)
+    assert float(got) == float(want)
+    for name, g, w in zip(WALK_OPERANDS, got_grads, want_grads):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=name)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(kda._chunk_outputs)(*operands)),
+        np.asarray(jax.jit(_parents_walk)(*operands)))
+
+
+
 @pytest.fixture
 def kernel_pair_scores(patch_kda):
     """`kda_chunked` through the kernels, interpreted, where the operands tile."""
@@ -261,13 +419,10 @@ def _wide_delta_inputs(strength, resets):
     return rounded(q), rounded(k), rounded(v), g, beta, doc
 
 
-@pytest.mark.parametrize("strength", [0.1, 8.0])
-@pytest.mark.parametrize("resets", [False, True])
-def test_delta_rule_through_the_kernels_is_the_stepped_recurrence(
-    kernel_pair_scores, resets, strength
-):
-    q, k, v, g, beta, doc = _wide_delta_inputs(strength, resets)
-    weight = jnp.asarray(np.random.RandomState(5).randn(1, 128, 2, 128), jnp.float32)
+def _assert_the_rule_is_the_recurrence(q, k, v, g, beta, doc):
+    """`kda_chunked` of bfloat16 q, k, v at chunk 64 against the stepped
+    recurrence in float32, values and five gradients, to bfloat16's step."""
+    weight = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.float32)
     f32 = lambda t: t.astype(jnp.float32)
 
     def both(rule, *operands):
@@ -284,13 +439,21 @@ def test_delta_rule_through_the_kernels_is_the_stepped_recurrence(
         want_out, want = both(
             lambda *a: _recurrence(*a, doc), f32(q), f32(k), f32(v), g, beta)
     got_out, got = both(lambda *a: kda.kda_chunked(*a, doc, 64), q, k, v, g, beta)
-    assert kernel_pair_scores and kernel_pair_scores[0] == (1, 2, 2, 2, 64, 128)
     size = float(jnp.abs(want_out).max())
     assert np.abs(np.asarray(f32(got_out)) - np.asarray(want_out)).max() < 0.03 * size
     for name, g_, w in zip(("q", "k", "v", "g", "beta"), got, want):
         g_, w = np.asarray(g_, np.float32), np.asarray(w, np.float32)
         assert np.isfinite(g_).all(), name
         assert np.abs(g_ - w).max() < 0.05 * np.abs(w).max(), name
+
+
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_delta_rule_through_the_kernels_is_the_stepped_recurrence(
+    kernel_pair_scores, resets, strength
+):
+    _assert_the_rule_is_the_recurrence(*_wide_delta_inputs(strength, resets))
+    assert kernel_pair_scores and kernel_pair_scores[0] == (1, 2, 2, 2, 64, 128)
 
 
 def test_pair_scores_take_the_kernels_on_a_tpu_only_and_only_where_they_tile(patch_kda):
@@ -331,14 +494,87 @@ def test_pair_scores_take_the_kernels_on_a_tpu_only_and_only_where_they_tile(pat
         lambda *a: kda.kda_chunked(*a, doc, 64))(q, k4, v, g, beta))
 
 
+@pytest.fixture
+def kernel_walk(kernel_pair_scores, patch_kda):
+    """`kda_chunked` through both kernel pairs, interpreted."""
+    taken = []
+
+    def walk(*operands):
+        taken.append(operands[0].shape)
+        return _kernel_walk(*operands)
+
+    patch_kda("_chunk_outputs", walk)
+    return taken
+
+
+@pytest.mark.parametrize("strength", [0.1, 8.0])
+@pytest.mark.parametrize("resets", [False, True])
+def test_delta_rule_through_both_kernel_pairs_is_the_stepped_recurrence(
+    kernel_walk, kernel_pair_scores, resets, strength
+):
+    seq, heads = 256, 8
+    q, k, v, g, beta, doc = _delta_inputs(
+        strength, False, seq=seq, batch=1, heads=heads, width=128)
+    if resets:   # ends inside a chunk, at a chunk's edge, spans several chunks
+        doc = kda.document_index(jnp.asarray(_packed_ids(seq)))
+    rounded = lambda t: t.astype(jnp.bfloat16)
+    _assert_the_rule_is_the_recurrence(
+        rounded(q), rounded(k), rounded(v), g, beta, doc)
+    assert kernel_pair_scores and kernel_pair_scores[0] == (1, 4, 8, 2, 64, 128)
+    assert kernel_walk and kernel_walk[0] == (1, 4, 8, 64, 128)
+
+
+def test_the_walk_takes_the_kernels_on_a_tpu_only_and_only_where_they_tile(patch_kda):
+    operands = _walk_inputs(0.1, True, 64, 8)
+    walk = kda._chunk_outputs
+    # Tiled and bfloat16: the platform chooses, and this one is no TPU. The
+    # kernels were traced without `interpret`, so lowering them here would raise.
+    assert "platform_index" in str(jax.make_jaxpr(walk)(*operands))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(walk)(*operands)),
+        np.asarray(kda._chunk_outputs_scan(*operands)),
+    )
+    # Lowered for a TPU, one kernel forward, and with the backward the forward
+    # that keeps the states and the reverse walk: no loop over the chunks and
+    # no product outside the kernels.
+    lowered = jax.jit(walk).trace(*operands).lower(lowering_platforms=("tpu",)).as_text()
+    assert lowered.count("tpu_custom_call") == 1
+    assert "stablehlo.while" not in lowered and "dot_general" not in lowered
+    both = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(walk(*a).astype(jnp.float32)), argnums=range(6),
+    )).trace(*operands).lower(lowering_platforms=("tpu",)).as_text()
+    assert both.count("tpu_custom_call") == 2
+    assert "stablehlo.while" not in both and "dot_general" not in both
+    assert "4x8x128x128xf32" in both               # the states the backward reads
+
+    # float32, narrow heads, or a number of them the grid step does not divide:
+    # the XLA form alone.
+    def kernel_refused(*args, **kwargs):
+        raise AssertionError("the kernel path was traced")
+
+    patch_kda("chunk_outputs", kernel_refused, module=kda_carry)
+    f32 = lambda t: t.astype(jnp.float32)
+    w, u, k_end, kept, q_start, scores = operands
+    for refused in (
+        tuple(f32(t) for t in operands),
+        (w[..., :64], u, k_end[..., :64], kept[..., :64], q_start[..., :64], scores),
+        (w, u[..., :64], k_end, kept, q_start, scores),
+        tuple(t[:, :, :6] for t in operands),
+    ):
+        assert "platform_index" not in str(jax.make_jaxpr(walk)(*refused))
+        assert "scan[" in str(jax.make_jaxpr(walk)(*refused))
+
+
+
 # -- one jitted rule, shared by the KDA layers of a step program -------------------
 
 
-def _kda_model_loss(layers):
+def _kda_model_loss(layers, heads=2):
     """(loss(params) of a bfloat16 model of `layers` KDA layers whose heads
-    tile (2 heads x 128 channels, one chunk of 64), its parameters)."""
+    tile (`heads` x 128 channels, one chunk of 64: the pair scores take two
+    heads a grid step, the walk over the chunks eight), its parameters)."""
     linear = {**KIMI_LINEAR, "kda_layers": list(range(1, layers + 1)),
-              "full_attn_layers": [], "num_heads": 2, "head_dim": 128}
+              "full_attn_layers": [], "num_heads": heads, "head_dim": 128}
     model = _model(num_hidden_layers=layers, linear_attn_config=linear,
                    kda_chunk_size=64, device_type="tpu")
     features, labels = _batch()
@@ -347,7 +583,7 @@ def _kda_model_loss(layers):
 
 
 def _lowered_for_a_tpu(layers):
-    loss, params = _kda_model_loss(layers)
+    loss, params = _kda_model_loss(layers, heads=8)
     return jax.jit(jax.value_and_grad(loss)).trace(params).lower(
         lowering_platforms=("tpu",)).as_text()
 
@@ -365,6 +601,8 @@ def test_a_step_program_holds_the_kernels_once_however_many_layers_call_them():
         assert programs(text) == 2
         assert bodies(text, "kda_pair_scores") == 2
         assert bodies(text, "kda_pair_scores_backward") == 1
+        assert bodies(text, "kda_carry") == 2
+        assert bodies(text, "kda_carry_backward") == 1
         assert "16x16x128" not in text
     # A call of each program a layer.
     assert (calls(two), calls(three)) == (4, 6)
@@ -401,18 +639,31 @@ def one_described_chip():
     return SingleDeviceSharding(topology.devices[0])
 
 
-def test_the_chips_compiler_takes_the_kernels_at_the_cells_shapes(one_described_chip):
+@pytest.mark.parametrize("kernels", ["pair_scores", "walk"])
+def test_the_chips_compiler_takes_the_kernels_at_the_cells_shapes(
+    one_described_chip, kernels
+):
     # A group of 16 heads of one layer of kimi_linear_48b_a3b_s1 at 16,384.
     shape = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_described_chip)
-    x = shape((1, 256, 16, 2, 64, 128), jnp.bfloat16)
-    k = shape((1, 256, 16, 64, 128), jnp.bfloat16)
-    cum = shape((1, 256, 16, 64, 128), jnp.float32)
-    visible = shape((1, 256, 1, 64, 64), jnp.bool_)
+    wide = shape((1, 256, 16, 64, 128), jnp.bfloat16)
+    if kernels == "pair_scores":
+        operands = (
+            shape((1, 256, 16, 2, 64, 128), jnp.bfloat16), wide,
+            shape((1, 256, 16, 64, 128), jnp.float32),
+            shape((1, 256, 1, 64, 64), jnp.bool_),
+        )
+        loss = lambda *a: jnp.sum(kda._pair_scores(*a))
+        differentiated = (0, 1, 2)
+    else:
+        operands = (
+            wide, wide, wide, shape((1, 256, 16, 128), jnp.float32), wide,
+            shape((1, 256, 16, 2, 64, 64), jnp.float32),
+        )
+        loss = lambda *a: jnp.sum(kda._chunk_outputs(*a).astype(jnp.float32))
+        differentiated = range(6)
     compiled = jax.jit(jax.value_and_grad(
-        lambda x, k, cum, visible: jnp.sum(kda._pair_scores(x, k, cum, visible)),
-        argnums=(0, 1, 2),
-    )).lower(x, k, cum, visible).compile()
+        loss, argnums=differentiated)).lower(*operands).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 

@@ -46,6 +46,27 @@ sys.path.insert(
 from tools.race_segment_attention import packed_segment_ids  # noqa: E402
 
 
+def staged(fn, *operands):
+    """(the compiled `fn`, seconds to trace and lower it, to compile it)."""
+    started = time.perf_counter()
+    lowered = fn.lower(*operands)
+    lowered_at = time.perf_counter()
+    compiled = lowered.compile()
+    return compiled, lowered_at - started, time.perf_counter() - lowered_at
+
+
+def milliseconds_a_call(iters, compiled, *operands):
+    """Milliseconds a call of `compiled` over `iters` calls, after one."""
+    import jax
+
+    jax.block_until_ready(compiled(*operands))
+    started = time.perf_counter()
+    for _ in range(iters):
+        out = compiled(*operands)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - started) / iters * 1e3
+
+
 def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--seq", type=int, default=16384)
@@ -100,21 +121,7 @@ def main() -> int:
         keys[5], (1, chunks, group, 2, chunk, chunk), jnp.float32)
     print(f"pair scores a group of {group} heads: x {x_c.shape}", flush=True)
 
-    def staged(fn, *operands):
-        """(the compiled `fn`, seconds to trace and lower it, to compile it)."""
-        started = time.perf_counter()
-        lowered = fn.lower(*operands)
-        lowered_at = time.perf_counter()
-        compiled = lowered.compile()
-        return compiled, lowered_at - started, time.perf_counter() - lowered_at
-
-    def milliseconds(compiled, *operands):
-        jax.block_until_ready(compiled(*operands))
-        started = time.perf_counter()
-        for _ in range(args.iters):
-            out = compiled(*operands)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - started) / args.iters * 1e3
+    milliseconds = functools.partial(milliseconds_a_call, args.iters)
 
     def forward_and_both(scores):
         def loss(x, k, cum):
