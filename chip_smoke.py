@@ -83,34 +83,32 @@ PARITY_RMS_SHARE_OF_SIGNAL = 0.5
 
 class Reporter:
     """Prints each line tagged with the platform, and splits a stage's wall
-    time into compile seconds (jax's own trace/lower/compile events,
-    summed across threads) and the rest."""
-
-    _COMPILE_EVENTS = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
+    time into compile seconds (the program's own `jit.*` spans of jax's
+    trace/lower/compile events, utils/build_trace.py, summed across
+    threads) and the rest."""
 
     def __init__(self, platform: str):
-        from jax import monitoring
+        from tensor2robot_tpu.utils import build_trace, tracing
 
+        build_trace.install()
         self.platform = platform
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
+        self._counters = tracing.counters
 
-    def _on_duration(self, name, secs, **_):
-        if name in self._COMPILE_EVENTS:
-            self.compile_s += secs
+    @property
+    def compile_s(self) -> float:
+        counters = self._counters()
+        return sum(
+            counters.get(f"jit.{kind}.ns", 0)
+            for kind in ("trace", "lower", "compile")
+        ) / 1e9
 
-    def _on_event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
+    @property
+    def cache_hits(self) -> int:
+        return self._counters().get("jit.cache_hits", 0)
+
+    @property
+    def cache_misses(self) -> int:
+        return self._counters().get("jit.cache_misses", 0)
 
     def say(self, text: str) -> None:
         print(f"[chip_smoke {self.platform}] {text}", flush=True)

@@ -11,4 +11,10 @@ pytrees, device placement is a `jax.sharding.Mesh`, collectives are XLA's, and
 the hot ops compile through jit/pjit (with Pallas kernels where profitable).
 """
 
+import time
+
+#: When this process first touched the package, epoch nanoseconds: where
+#: train/train_eval.py's `program.import` span starts.
+IMPORT_START_NS = time.time_ns()
+
 __version__ = "0.1.0"

@@ -19,13 +19,16 @@ utils/train_eval.py:423-612 (TPUEstimator + train_and_evaluate):
 
 from __future__ import annotations
 
+import time
+
+_IMPORT_BEGAN_NS = time.time_ns()
+
 import contextlib
 import itertools
 import os
 import threading
 
 from tensor2robot_tpu.testing import locksmith
-import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence
 
 import jax
@@ -35,6 +38,7 @@ import numpy as np
 import optax
 import orbax.checkpoint as ocp
 
+import tensor2robot_tpu
 from tensor2robot_tpu import flags
 from tensor2robot_tpu.hooks.golden_values_hook_builder import GOLDEN_PREFIX
 from tensor2robot_tpu.hooks.hook_builder import Hook, HookBuilder, HookContext
@@ -58,7 +62,7 @@ from tensor2robot_tpu.train.metrics import (
     collective_record,
 )
 from tensor2robot_tpu.train.state import TrainState, create_train_state, update_ema
-from tensor2robot_tpu.utils import tracing
+from tensor2robot_tpu.utils import build_trace, tracing
 
 
 #: Metric-key prefixes whose values carry a leading batch dimension
@@ -81,11 +85,31 @@ _DISPATCH_LOCK = locksmith.make_lock("train_eval._DISPATCH_LOCK", budget_ms=0)
 
 def _serialize_dispatch(fn):
     """Routes calls to a jitted mesh program through _DISPATCH_LOCK; jit
-    introspection (`lower`) passes through for AOT/census tests."""
+    introspection (`lower`) passes through for AOT/census tests.
+
+    A call inside which the thread built something (the program's first
+    call, or a recompile: utils/build_trace.py) is recorded whole as a
+    `train.build` span labelled with the program's name, a sibling of the
+    `jit.*` spans inside it (all are children of the thread's open span,
+    a `train.dispatch` say), with what those account for of it as counts.
+    What is left of its length is what no jax event covers: sharding
+    inference, the compile cache's key over the module, pjit's
+    bookkeeping, the enqueue of the first execution. A call that builds nothing pays a clock read and two
+    thread-local reads."""
+    label = fn.__name__
 
     def locked(*args, **kwargs):
+        start_ns = time.time_ns()
+        built = build_trace.totals()
         with _DISPATCH_LOCK:
-            return fn(*args, **kwargs)
+            out = fn(*args, **kwargs)
+        now = build_trace.totals()
+        if now is not built:
+            tracing.between(
+                "train.build", start_ns, time.time_ns(), label=label,
+                **now.since(built),
+            )
+        return out
 
     locked.lower = fn.lower
     locked.__wrapped__ = fn
@@ -396,6 +420,7 @@ class CompiledModel:
           default, and the T2R_PLAN=off path) keeps the explicit kwargs
           exactly as before.
         """
+        build_trace.install()
         self.model = model
         self.plan = plan
         if plan is not None:
@@ -835,21 +860,22 @@ class CompiledModel:
     def init_state(self, rng: jax.Array, example_batch) -> TrainState:
         # Host time of the call (hundreds of small eager programs traced,
         # compiled or loaded, and enqueued), not the device's.
-        with tracing.span("train.init_state"):
+        # Each span also counts the programs the thread built inside it.
+        with build_trace.span("train.init_state"):
             return self._init_state(rng, example_batch)
 
     def _init_state(self, rng: jax.Array, example_batch) -> TrainState:
         # The model initializes at its own (post-preprocess) contract: run the
         # preprocessor on the example batch outside jit once, in TRAIN mode so
         # init shapes match exactly what train_step will feed the network.
-        with tracing.span("train.init_state.preprocess"):
+        with build_trace.span("train.init_state.preprocess"):
             features, _ = self.preprocessor.preprocess(
                 example_batch["features"],
                 _batch_labels(example_batch),
                 mode=MODE_TRAIN,
                 rng=jax.random.PRNGKey(0),
             )
-        with tracing.span("train.init_state.model_init"):
+        with build_trace.span("train.init_state.model_init"):
             state = create_train_state(
                 self.model, rng, features, self.optimizer,
             )
@@ -1209,8 +1235,11 @@ def _host_path_record(before, after, steps: int) -> Dict[str, float]:
     step the train thread waited for a batch, placed it and dispatched;
     milliseconds of parse workers' time one batch took (the sum over its
     slices where it was parsed in slices); the share of batches
-    for which the dataset's prefetch queue was empty when asked; and the
-    milliseconds the loop stood in `checkpoint_and_eval`."""
+    for which the dataset's prefetch queue was empty when asked; the
+    milliseconds the loop stood in `checkpoint_and_eval`; and the programs
+    the process built and the seconds that took (`jit.*` spans of
+    utils/build_trace.py, every thread): after the first interval, which
+    holds the step's own build, anything but 0 is a recompile in the loop."""
 
     def delta(key):
         return after.get(key, 0) - before.get(key, 0)
@@ -1228,6 +1257,11 @@ def _host_path_record(before, after, steps: int) -> Dict[str, float]:
             delta("data.prefetch_empty") / max(delta("data.prefetch_gets"), 1)
         ),
         "checkpoint/stall_ms": delta("train.checkpoint.ns") / 1e6,
+        "compile/programs_built": float(delta("jit.compile.n")),
+        "compile/seconds": (
+            delta("jit.trace.ns") + delta("jit.lower.ns")
+            + delta("jit.compile.ns")
+        ) / 1e9,
     }
 
 
@@ -1329,6 +1363,7 @@ def train_eval_model(
     hand-wired kwargs path, byte-for-byte; a preset name or 'auto'
     resolves a plan through parallel/planner.py).
     """
+    build_trace.install()
     model = maybe_wrap_for_tpu(t2r_model)
     print_specification(model)
     os.makedirs(model_dir, exist_ok=True)
@@ -1722,3 +1757,17 @@ def predict_from_model(
     yield predict(first)
     for batch in batches:
         yield predict(batch)
+
+
+# What importing the training stack (jax, flax, optax, orbax, this package)
+# cost the process, from the package's first touch to here (`own_ns`: of
+# it, this module's first line to its last; the rest is whatever the caller
+# imported and did between the two); and when the process began, so that
+# what ran before that touch is a difference.
+tracing.since(
+    "program.import", tensor2robot_tpu.IMPORT_START_NS,
+    own_ns=time.time_ns() - _IMPORT_BEGAN_NS,
+)
+_PROCESS_START_NS = build_trace.process_start_ns()
+if _PROCESS_START_NS is not None:
+    tracing.count("process.start_ns", _PROCESS_START_NS)

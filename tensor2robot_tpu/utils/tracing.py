@@ -40,14 +40,15 @@ class Span:
 
     __slots__ = (
         "_recorder", "name", "ident", "parent", "thread", "ordinal",
-        "start_ns", "end_ns", "counts",
+        "start_ns", "end_ns", "counts", "label",
     )
 
-    def __init__(self, recorder, name, ordinal, counts):
+    def __init__(self, recorder, name, ordinal, counts, label=None):
         self._recorder = recorder
         self.name = name
         self.ordinal = ordinal
         self.counts = counts
+        self.label = label
         self.parent = None
         self.end_ns = None
 
@@ -80,7 +81,7 @@ class Span:
             "name": self.name, "id": self.ident, "parent": self.parent,
             "thread": self.thread, "ordinal": self.ordinal,
             "start_ns": self.start_ns, "end_ns": self.end_ns,
-            "counts": dict(self.counts),
+            "counts": dict(self.counts), "label": self.label,
         }
 
 
@@ -117,9 +118,13 @@ class Recorder:
         with self._lock:
             self._counters[name] += n
 
-    def _record(self, name, ordinal, counts, thread, start_ns, end_ns) -> None:
-        span = Span(self, name, ordinal, counts)
+    def _record(
+        self, name, ordinal, counts, thread, start_ns, end_ns,
+        label=None, parent=None,
+    ) -> None:
+        span = Span(self, name, ordinal, counts, label)
         span.ident = next(self._idents)
+        span.parent = parent
         span.thread = thread
         span.start_ns, span.end_ns = start_ns, end_ns
         self._close(span)
@@ -130,17 +135,34 @@ class Recorder:
         self._record(
             span["name"], span["ordinal"], dict(span["counts"]),
             span["thread"], span["start_ns"], span["end_ns"],
+            label=span.get("label"),
         )
 
     def since(
-        self, name: str, start_ns: int, ordinal: Optional[int] = None, **counts
+        self, name: str, start_ns: int, ordinal: Optional[int] = None,
+        label: Optional[str] = None, **counts
     ) -> None:
         """Records a span from `start_ns` (a `time.time_ns()` stamp, taken
         by whichever thread began the work) to now, under the calling
         thread and no parent."""
         self._record(
             name, ordinal, counts, threading.get_ident(), start_ns,
-            time.time_ns(),
+            time.time_ns(), label=label,
+        )
+
+    def between(
+        self, name: str, start_ns: int, end_ns: int,
+        label: Optional[str] = None, **counts
+    ) -> None:
+        """Records a span whose two ends were stamped elsewhere on the epoch
+        clock, under the calling thread, as a child of that thread's
+        innermost open span (whose ordinal it takes)."""
+        stack = self._stack()
+        enclosing = stack[-1] if stack else None
+        self._record(
+            name, enclosing.ordinal if enclosing else None, counts,
+            threading.get_ident(), start_ns, end_ns, label=label,
+            parent=enclosing.ident if enclosing else None,
         )
 
     def counters(self) -> Dict[str, int]:
@@ -166,5 +188,6 @@ add = RECORDER.add
 count = RECORDER.count
 adopt = RECORDER.adopt
 since = RECORDER.since
+between = RECORDER.between
 counters = RECORDER.counters
 snapshot = RECORDER.snapshot

@@ -429,6 +429,8 @@ class TestTrainLoopSpans:
             "data.parse_chunk.n": 36, "data.parse_batches": 3,
             "data.prefetch_gets": 14,
             "data.prefetch_empty": 1, "train.checkpoint.ns": 7_000_000,
+            "jit.trace.ns": 300_000_000, "jit.lower.ns": 200_000_000,
+            "jit.compile.ns": 1_000_000_000, "jit.compile.n": 2,
         }
         assert train_eval._host_path_record(before, after, steps=4) == {
             "infeed/wait_ms_per_step": 5.0,
@@ -437,6 +439,8 @@ class TestTrainLoopSpans:
             "input/parse_ms_per_batch": 30.0,
             "input/prefetch_empty_share": 0.25,
             "checkpoint/stall_ms": 7.0,
+            "compile/programs_built": 2.0,
+            "compile/seconds": 1.5,
         }
 
 
