@@ -26,7 +26,8 @@ from typing import Any, Callable, Dict, Mapping, Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
+
+from tensor2robot_tpu.train import durability
 
 
 def path_str(key_path) -> str:
@@ -98,6 +99,7 @@ def load_checkpoint_variables(
     swapping-saver semantics: warm starts consume the averaged weights).
     """
     root, resolved = _checkpoint_root_and_step(checkpoint_path, step)
+    ocp = durability.checkpointer()
     manager = ocp.CheckpointManager(root)
     try:
         # Restore against the checkpoint's own metadata with host-placed

@@ -23,7 +23,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Union
 
 import jax
-import orbax.checkpoint as ocp
 
 from tensor2robot_tpu.models.abstract_model import MODE_EVAL, AbstractT2RModel
 from tensor2robot_tpu.train import durability
@@ -157,6 +156,7 @@ def restore_state_from_backup(
     """Restores a TrainState from a backed-up checkpoint root."""
     if abstract is None:
         abstract = abstract_state_template(compiled, example_batch)
+    ocp = durability.checkpointer()
     manager = ocp.CheckpointManager(backup_root)
     try:
         return manager.restore(step, args=ocp.args.StandardRestore(abstract))

@@ -49,8 +49,12 @@ import json
 import logging
 import os
 import shutil
+import sys
+import threading
 import time
 from typing import List, Optional, Tuple
+
+from tensor2robot_tpu.utils import tracing
 
 MANIFEST_NAME = "t2r_durable.json"
 QUARANTINE_DIRNAME = "checkpoints.quarantine"
@@ -59,6 +63,26 @@ QUARANTINE_DIRNAME = "checkpoints.quarantine"
 # fleet health probes run in slim processes).
 _ORBAX_TMP_MARKER = ".orbax-checkpoint-tmp-"
 _STEP_METADATA = "_CHECKPOINT_METADATA"
+
+_CHECKPOINTER_LOCK = threading.Lock()
+
+
+def checkpointer():
+    """`orbax.checkpoint`, imported where a checkpoint is made or read.
+
+    The import costs seconds (its `google.cloud.logging` most of them), so
+    a process that never checkpoints never pays it. The process's one
+    import that actually runs is the span `checkpoint.import`, a child of
+    the calling thread's open span: its counters say whether and where the
+    checkpointer was paid.
+    """
+    with _CHECKPOINTER_LOCK:
+        if "orbax.checkpoint" not in sys.modules:
+            with tracing.span("checkpoint.import"):
+                import orbax.checkpoint  # noqa: F401
+    import orbax.checkpoint as ocp
+
+    return ocp
 
 
 def checkpoint_root(model_dir: str) -> str:

@@ -29,14 +29,15 @@ import os
 import threading
 
 from tensor2robot_tpu.testing import locksmith
-from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence
+from typing import (
+    TYPE_CHECKING, Any, Callable, Dict, Iterator, List, Optional, Sequence,
+)
 
 import jax
 import jax.flatten_util  # registers jax.flatten_util.ravel_pytree
 import jax.numpy as jnp
 import numpy as np
 import optax
-import orbax.checkpoint as ocp
 
 import tensor2robot_tpu
 from tensor2robot_tpu import flags
@@ -63,6 +64,17 @@ from tensor2robot_tpu.train.metrics import (
 )
 from tensor2robot_tpu.train.state import TrainState, create_train_state, update_ema
 from tensor2robot_tpu.utils import build_trace, tracing
+
+if TYPE_CHECKING:
+    import orbax.checkpoint as ocp
+
+
+def __getattr__(name: str):
+    # `train_eval.ocp` for its importers, imported on first read: the
+    # module itself imports orbax only where a checkpoint is made or read.
+    if name == "ocp":
+        return durability.checkpointer()
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 #: Metric-key prefixes whose values carry a leading batch dimension
@@ -861,7 +873,10 @@ class CompiledModel:
         # Host time of the call (hundreds of small eager programs traced,
         # compiled or loaded, and enqueued), not the device's.
         # Each span also counts the programs the thread built inside it.
-        with build_trace.span("train.init_state"):
+        # Its eager programs span the mesh like the steps, so they enqueue
+        # under the same lock (see _DISPATCH_LOCK): a threaded trainer and
+        # an in-process eval job initialise at the same moment.
+        with _DISPATCH_LOCK, build_trace.span("train.init_state"):
             return self._init_state(rng, example_batch)
 
     def _init_state(self, rng: jax.Array, example_batch) -> TrainState:
@@ -1084,6 +1099,7 @@ def create_checkpoint_manager(
     save_interval_steps: int,
     keep_checkpoint_max: int = 5,
 ) -> ocp.CheckpointManager:
+    ocp = durability.checkpointer()
     return ocp.CheckpointManager(
         os.path.abspath(os.path.join(model_dir, "checkpoints")),
         options=ocp.CheckpointManagerOptions(
@@ -1112,7 +1128,9 @@ def restore_or_init_state(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
             state,
         )
-        state = manager.restore(latest, args=ocp.args.StandardRestore(abstract))
+        state = manager.restore(
+            latest, args=durability.checkpointer().args.StandardRestore(abstract)
+        )
     return state
 
 
@@ -1551,7 +1569,10 @@ def train_eval_model(
             # Async save: orbax snapshots device arrays to host memory before
             # returning, then writes in the background — the next scan window
             # dispatches immediately instead of stalling on serialization.
-            manager.save(step, args=ocp.args.StandardSave(state), force=True)
+            manager.save(
+                step, args=durability.checkpointer().args.StandardSave(state),
+                force=True,
+            )
             # Issuing this save was the commit barrier for the PREVIOUS one
             # (orbax serializes saves): publish its durability manifest.
             # No-op when no prior save exists (previous_saved is start_step
@@ -1759,7 +1780,7 @@ def predict_from_model(
         yield predict(batch)
 
 
-# What importing the training stack (jax, flax, optax, orbax, this package)
+# What importing the training stack (jax, flax, optax, this package)
 # cost the process, from the package's first touch to here (`own_ns`: of
 # it, this module's first line to its last; the rest is whatever the caller
 # imported and did between the two); and when the process began, so that

@@ -8,7 +8,9 @@ five that read set-up (`setup.step_build_s`, `.step_trace_lower_s`,
 need no trace: this runs the cell exactly as `benchmark/run.py` does, with
 whatever `--trace` says, and has the readers say their earlier lines and
 their values as soon as the driver returns, beside the driver's own
-`set-up:` stage marks. The result line is the harness's own.
+`set-up:` stage marks, and whether the process imported the checkpointer
+before its window (the recorder's `checkpoint.import` counters: 0 where the
+cell never checkpoints). The result line is the harness's own.
 """
 
 from __future__ import annotations
@@ -21,7 +23,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
 
 import manifest  # noqa: E402
+import program_spans  # noqa: E402
 import run as bench_run  # noqa: E402
+import setup_spans  # noqa: E402
 
 METRICS = (
     "setup.step_build_s", "setup.step_trace_lower_s", "setup.step_compile_s",
@@ -48,6 +52,16 @@ def saying_the_split(find_driver):
                 data = manifest._load_json("metrics", f"{metric}.json")
                 value = manifest._load_module("readers", data["reader"]).read(run)
                 run.reporter.say(f"metric {metric}: {value!r} {data['unit']}")
+            imports = [
+                s for s in setup_spans.before_window(run)
+                if s["name"] == "checkpoint.import"
+            ]
+            counters = (program_spans.recorded(run) or {}).get("counters", {})
+            run.reporter.say(
+                f"checkpoint.import: {len(imports)} before the window, "
+                f"{sum(s['end_ns'] - s['start_ns'] for s in imports) / 1e9:.3f} s; "
+                f"n={counters.get('checkpoint.import.n', 0)} in the process"
+            )
 
         return types.SimpleNamespace(run=run)
 
