@@ -97,6 +97,15 @@ def _kimi_linear_lm():
     )
 
 
+def _deepseek_v3_lm():
+    from tensor2robot_tpu.models.sequence_lm_models import DeepseekV3LMModel
+
+    # The defaults are a small model of the family's pattern (three
+    # rotary latent-attention layers, the first dense, the others with 8
+    # experts): the spec contract is the hybrid model's.
+    return DeepseekV3LMModel(sequence_length=64, device_type="cpu")
+
+
 def _mock_noop():
     from tensor2robot_tpu.utils.mocks import MockT2RModel
 
@@ -107,6 +116,7 @@ register_target("qtopt-grasping44", _qtopt_grasping44)
 register_target("transformer-bc", _transformer_bc)
 register_target("hybrid-sequence-lm", _hybrid_sequence_lm)
 register_target("kimi-linear-lm", _kimi_linear_lm)
+register_target("deepseek-v3-lm", _deepseek_v3_lm)
 register_target("mock-noop", _mock_noop)
 # The policy server's request path: predict-mode specs are what the
 # server's submit() validates against and what the micro-batcher stacks

@@ -22,6 +22,7 @@ from typing import Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
@@ -409,16 +410,55 @@ class TransformerBlock(nn.Module):
         return x + h
 
 
+def document_positions(segment_ids: jax.Array) -> jax.Array:
+    """[B, S] int32: each position's offset from the first position of its
+    document, 0 wherever `segment_ids` changes (and at position 0). The
+    padding after the last document counts on from its own first position;
+    attention masks it anyway."""
+    index = jnp.arange(segment_ids.shape[-1], dtype=jnp.int32)
+    starts = jnp.concatenate([
+        jnp.ones_like(segment_ids[..., :1], bool),
+        segment_ids[..., 1:] != segment_ids[..., :-1],
+    ], axis=-1)
+    return index - lax.cummax(jnp.where(starts, index, 0), axis=starts.ndim - 1)
+
+
+def rotary_interleaved(x: jax.Array, positions: jax.Array,
+                       theta: float) -> jax.Array:
+    """Rotary positions over interleaved pairs: channels (2i, 2i + 1) of the
+    last axis of x [B, S, ..., D] turn by `positions` [B, S] x
+    theta^(-2i / D). Angles, cos and sin are float32 (at position 16,383 a
+    bfloat16 angle is off by whole turns); the result has x's dtype.
+
+    The published form de-interleaves the channels (evens, then odds) and
+    applies `rotate_half`; rotating the pairs where they lie gives the same
+    scores, since queries and keys take the same permutation.
+    """
+    width = x.shape[-1]
+    inverse = (float(theta) ** (-np.arange(0, width, 2) / width)).astype(np.float32)
+    angles = positions.astype(jnp.float32)[..., None] * inverse        # [B, S, D/2]
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + angles.shape[2:])
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (width // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    turned = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
+    return turned.reshape(x.shape).astype(x.dtype)
+
+
 class LatentAttention(nn.Module):
-    """Multi-head latent attention without positions (MLA, `mla_use_nope`),
-    over packed documents, in its expanded (training) form:
+    """Multi-head latent attention over packed documents, in its expanded
+    (training) form:
 
         q = W_q x                       heads of qk_nope_dim + qk_rope_dim
         [c, k_pe] = W_kva x             a latent of kv_rank, qk_rope_dim shared
         [k_nope, v] = W_kvb RMSNorm(c)  heads of qk_nope_dim + v_dim
-        k = [k_nope, k_pe]              k_pe the same for every head, never rotated
+        k = [k_nope, k_pe]              k_pe the same for every head
 
-    scores `q k^T (qk_nope_dim + qk_rope_dim)^-1/2`, causal and within
+    With `rope_theta` None (`mla_use_nope`) nothing is rotated. Otherwise
+    the qk_rope_dim channels of each query head, and k_pe once before it is
+    shared by the heads, take `rotary_interleaved` at their offset within
+    their document (`document_positions`), under scope `mla/rope`.
+    Scores `q k^T (qk_nope_dim + qk_rope_dim)^-1/2`, causal and within
     `segment_ids`' documents (`ops/flash_attention.segment_attention`, whose
     values may be narrower than its keys), then `W_o`. The latent is what a
     decode cache would hold; there is none here.
@@ -432,6 +472,7 @@ class LatentAttention(nn.Module):
     epsilon: float = 1e-5
     dtype: Optional[jnp.dtype] = None
     kernel_init: Callable = nn.linear.default_kernel_init
+    rope_theta: Optional[float] = None
 
     @nn.compact
     def __call__(self, x: jax.Array, segment_ids: jax.Array) -> jax.Array:
@@ -453,6 +494,14 @@ class LatentAttention(nn.Module):
                 project(self.kv_rank + rope, "kv_a")(x), [self.kv_rank], axis=-1
             )
             latent = RMSNorm(self.epsilon, name="kv_norm")(latent)
+        if self.rope_theta is not None:
+            with jax.named_scope("mla/rope"):
+                positions = document_positions(segment_ids)
+                q = jnp.concatenate([
+                    q[..., :nope],
+                    rotary_interleaved(q[..., nope:], positions, self.rope_theta),
+                ], axis=-1)
+                k_pe = rotary_interleaved(k_pe, positions, self.rope_theta)
         with jax.named_scope("mla/kv_up"):
             kv = project(heads * (nope + self.v_dim), "kv_b")(latent).reshape(
                 batch, seq, heads, nope + self.v_dim

@@ -14,10 +14,12 @@ kimi_linear`): KDA gated-delta-rule layers (layers/kda.py) with a latent
 attention layer without positions wherever `linear_attn_config` says so, a
 dense SwiGLU in the leading layers and sigmoid-routed experts with a shared
 expert in the others (layers/moe.RoutedExperts, told which experts it
-holds), an untied head. Both models share the feature and label specs, the
-jitted init, Adam and the packing contract; each has a block and a network
-of its own, because nothing of one family's layer arguments is the
-other's.
+holds), an untied head. `DeepseekV3LMModel` is the DeepSeek-V3 family's
+decoder (`model_type: deepseek_v3`) on the same block and network: latent
+attention with a rotary key in every layer, positions counted within each
+document. All share the feature and label specs, the jitted init, Adam and
+the packing contract; the hybrid model has a block and a network of its
+own, because nothing of its layer arguments is the others'.
 
 The packing contract (docs/SEQUENCE_MODELS.md): one example is one
 sequence of `sequence_length` positions holding whole documents back to
@@ -304,9 +306,10 @@ class HybridSequenceLMModel(_PackedLMModel):
         )
 
 
-#: What a Kimi-Linear block keeps of its forward for the backward pass, by
-#: the kind of its mixer and of its feed-forward (`checkpoint_name`s; the
-#: rest of the block is recomputed). "kda_out" is the delta rule's output
+#: What a `KimiLinearBlock` (Kimi-Linear's, and DeepSeek-V3's with every
+#: mixer "mla") keeps of its forward for the backward pass, by the kind of
+#: its mixer and of its feed-forward (`checkpoint_name`s; the rest of the
+#: block is recomputed). "kda_out" is the delta rule's output
 #: (positions x 4,096 x 2 bytes a layer): with it kept the rule is not run
 #: a third time, since it recomputes itself from its inputs for its own
 #: backward (layers/kda.py). The others are wide products that are plain
@@ -334,16 +337,19 @@ class KimiLinearBlock(nn.Module):
     mixer: str
     ffn: str
     heads: int
-    kda_heads: int
-    kda_head_dim: int
-    kda_conv: int
-    kda_chunk: int
     qk_nope_dim: int
     qk_rope_dim: int
     v_dim: int
     kv_rank: int
     mlp_dim: int
     experts: dict
+    # The KDA mixer's sizes: read by "kda" layers alone.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    kda_chunk: int = 0
+    # Latent attention's rotary base; None: no positions (`mla_use_nope`).
+    rope_theta: Optional[float] = None
     epsilon: float = 1e-5
     dtype: Optional[jnp.dtype] = None
 
@@ -362,7 +368,7 @@ class KimiLinearBlock(nn.Module):
                 num_heads=self.heads, qk_nope_dim=self.qk_nope_dim,
                 qk_rope_dim=self.qk_rope_dim, v_dim=self.v_dim,
                 kv_rank=self.kv_rank, epsilon=self.epsilon, dtype=self.dtype,
-                kernel_init=init, name="mixer",
+                kernel_init=init, rope_theta=self.rope_theta, name="mixer",
             )(normed, segment_ids)
         else:
             raise ValueError(f"no mixer {self.mixer!r}")
@@ -444,7 +450,50 @@ class _KimiLinearLMNet(nn.Module):
         }
 
 
-class KimiLinearLMModel(_PackedLMModel):
+def _refuse(unsupported: dict):
+    """Raises for the keys whose value says True: settings of the family
+    that this program does not compute."""
+    if any(unsupported.values()):
+        raise ValueError(
+            "not supported: " + ", ".join(k for k, bad in unsupported.items() if bad)
+        )
+
+
+def _held_experts(held, router_experts, first_expert, **layer):
+    """`RoutedExperts`' arguments for a share of `held` experts from
+    `first_expert` on of the `router_experts` (None: all) that the router
+    scores."""
+    router_experts = router_experts or held
+    if first_expert + held > router_experts:
+        raise ValueError(
+            f"experts {first_expert}..{first_expert + held} held of {router_experts}"
+        )
+    return flax.core.FrozenDict(
+        num_experts=held, router_experts=router_experts,
+        first_expert=first_expert, **layer,
+    )
+
+
+class _RoutedLMModel(_PackedLMModel):
+    """What the models over `KimiLinearBlock` share: the network, and the
+    routed layers' counts in a step's metrics (`RoutedExperts.COUNT_NAMES`,
+    summed over layers). A subclass sets `_vocab_size`, `_hidden_size`,
+    `_layers` ((mixer, ffn) a layer), `_block` and `_epsilon`."""
+
+    _COUNT_KEYS = _PackedLMModel._COUNT_KEYS + RoutedExperts.COUNT_NAMES
+
+    def create_network(self) -> nn.Module:
+        return _KimiLinearLMNet(
+            vocab_size=self._vocab_size,
+            hidden_size=self._hidden_size,
+            layers=self._layers,
+            block=self._block,
+            epsilon=self._epsilon,
+            dtype=self._dtype,
+        )
+
+
+class KimiLinearLMModel(_RoutedLMModel):
     """Next-token training of a KDA / latent-attention model with routed
     experts on packed documents.
 
@@ -465,8 +514,6 @@ class KimiLinearLMModel(_PackedLMModel):
     `tokens`, `pad_tokens` and the routed layers' counts summed over layers
     (`RoutedExperts.COUNT_NAMES`).
     """
-
-    _COUNT_KEYS = _PackedLMModel._COUNT_KEYS + RoutedExperts.COUNT_NAMES
 
     def __init__(
         self,
@@ -507,19 +554,14 @@ class KimiLinearLMModel(_PackedLMModel):
         super().__init__(
             sequence_length, learning_rate, adam_b1, adam_b2, adam_eps, **kwargs
         )
-        unsupported = {
+        _refuse({
             "q_lora_rank": q_lora_rank is not None,
             "mla_use_nope=False (rotary)": not mla_use_nope,
             "moe_renormalize=False": not moe_renormalize,
             "moe_router_activation_func": moe_router_activation_func != "sigmoid",
             "expert groups": (num_expert_group, topk_group) != (1, 1),
             "tie_word_embeddings": tie_word_embeddings,
-        }
-        if any(unsupported.values()):
-            raise ValueError(
-                "not supported: "
-                + ", ".join(k for k, bad in unsupported.items() if bad)
-            )
+        })
         linear = dict(linear_attn_config or {
             "kda_layers": [1, 2, 3], "full_attn_layers": [4], "num_heads": 4,
             "head_dim": 16, "short_conv_kernel_size": 4,
@@ -534,12 +576,6 @@ class KimiLinearLMModel(_PackedLMModel):
                 raise ValueError(f"linear_attn_config names no mixer for layer {number}")
             layers.append(
                 (mixer, "dense" if number <= first_k_dense_replace else "moe")
-            )
-        router_experts = router_experts or num_experts
-        if first_expert + num_experts > router_experts:
-            raise ValueError(
-                f"experts {first_expert}..{first_expert + num_experts} held of "
-                f"{router_experts}"
             )
         self._vocab_size = vocab_size
         self._hidden_size = hidden_size
@@ -556,21 +592,124 @@ class KimiLinearLMModel(_PackedLMModel):
             v_dim=v_head_dim,
             kv_rank=kv_lora_rank,
             mlp_dim=intermediate_size,
-            experts=flax.core.FrozenDict(
-                num_experts=num_experts, router_experts=router_experts,
-                first_expert=first_expert, hidden_dim=moe_intermediate_size,
+            experts=_held_experts(
+                num_experts, router_experts, first_expert,
+                hidden_dim=moe_intermediate_size,
                 num_selected=num_experts_per_token,
                 shared_experts=num_shared_experts,
                 scaling=routed_scaling_factor,
             ),
         )
 
-    def create_network(self) -> nn.Module:
-        return _KimiLinearLMNet(
-            vocab_size=self._vocab_size,
-            hidden_size=self._hidden_size,
-            layers=self._layers,
-            block=self._block,
-            epsilon=self._epsilon,
-            dtype=self._dtype,
+
+class DeepseekV3LMModel(_RoutedLMModel):
+    """Next-token training of a DeepSeek-V3-style model (`model_type:
+    deepseek_v3`) on packed documents: latent attention with a decoupled
+    rotary key in every layer, a dense SwiGLU of `intermediate_size` in the
+    first `first_k_dense_replace` layers, and in the others sigmoid-routed
+    experts of `moe_intermediate_size` (`noaux_tc`: a selection-only bias,
+    the chosen scores renormalised and scaled by `routed_scaling_factor`)
+    beside `n_shared_experts` shared ones; an untied head.
+
+    Constructor arguments are the keys of the family's `config.json`, read
+    by name. **`n_routed_experts` is what this chip holds** of a layer's
+    `router_experts` (None: all), from `first_expert` on, as in
+    `KimiLinearLMModel`. Latent attention turns the `qk_rope_head_dim`
+    channels of queries and of the shared key by interleaved pairs
+    (`rope_interleave`) at base `rope_theta`, each position at its offset
+    within its document (docs/SEQUENCE_MODELS.md). Settings it does not
+    compute are refused: a low-rank query (`q_lora_rank`), `rope_scaling`,
+    pairs not interleaved, a `scoring_func` other than sigmoid, a
+    `topk_method` other than `noaux_tc`, `norm_topk_prob` false, expert
+    groups, `moe_layer_freq` other than 1, an activation other than SiLU,
+    attention biases, a tied head.
+
+    `KimiLinearLMModel`'s block and network with every mixer latent
+    attention: the same recomputation policy and the same step metrics.
+    """
+
+    def __init__(
+        self,
+        vocab_size: int = 256,
+        hidden_size: int = 64,
+        intermediate_size: int = 128,
+        num_hidden_layers: int = 3,
+        num_attention_heads: int = 4,
+        first_k_dense_replace: int = 1,
+        moe_layer_freq: int = 1,
+        n_routed_experts: int = 8,
+        router_experts: Optional[int] = None,
+        first_expert: int = 0,
+        num_experts_per_tok: int = 2,
+        n_shared_experts: int = 2,
+        moe_intermediate_size: int = 32,
+        routed_scaling_factor: float = 1.0,
+        scoring_func: str = "sigmoid",
+        topk_method: str = "noaux_tc",
+        norm_topk_prob: bool = True,
+        n_group: int = 1,
+        topk_group: int = 1,
+        kv_lora_rank: int = 32,
+        q_lora_rank: Optional[int] = None,
+        qk_nope_head_dim: int = 16,
+        qk_rope_head_dim: int = 8,
+        qk_head_dim: Optional[int] = None,
+        v_head_dim: int = 16,
+        rope_theta: float = 10000.0,
+        rope_interleave: bool = True,
+        rope_scaling: Optional[dict] = None,
+        rms_norm_eps: float = 1e-6,
+        hidden_act: str = "silu",
+        attention_bias: bool = False,
+        tie_word_embeddings: bool = False,
+        sequence_length: int = 64,
+        learning_rate: float = 3e-4,
+        adam_b1: float = 0.9,
+        adam_b2: float = 0.95,
+        adam_eps: float = 1e-8,
+        **kwargs,
+    ):
+        super().__init__(
+            sequence_length, learning_rate, adam_b1, adam_b2, adam_eps, **kwargs
+        )
+        _refuse({
+            "q_lora_rank": q_lora_rank is not None,
+            "rope_scaling": rope_scaling is not None,
+            "rope_interleave=False": not rope_interleave,
+            "scoring_func": scoring_func != "sigmoid",
+            "topk_method": topk_method != "noaux_tc",
+            "norm_topk_prob=False": not norm_topk_prob,
+            "expert groups": (n_group, topk_group) != (1, 1),
+            "moe_layer_freq": moe_layer_freq != 1,
+            "hidden_act": hidden_act != "silu",
+            "attention_bias": attention_bias,
+            "tie_word_embeddings": tie_word_embeddings,
+        })
+        if qk_head_dim not in (None, qk_nope_head_dim + qk_rope_head_dim):
+            raise ValueError(
+                f"qk_head_dim {qk_head_dim} is not qk_nope_head_dim + "
+                f"qk_rope_head_dim = {qk_nope_head_dim + qk_rope_head_dim}"
+            )
+        self._vocab_size = vocab_size
+        self._hidden_size = hidden_size
+        self._layers = tuple(
+            ("mla", "dense" if number <= first_k_dense_replace else "moe")
+            for number in range(1, num_hidden_layers + 1)
+        )
+        self._epsilon = rms_norm_eps
+        self._block = dict(
+            heads=num_attention_heads,
+            qk_nope_dim=qk_nope_head_dim,
+            qk_rope_dim=qk_rope_head_dim,
+            v_dim=v_head_dim,
+            kv_rank=kv_lora_rank,
+            rope_theta=float(rope_theta),
+            mlp_dim=intermediate_size,
+            experts=_held_experts(
+                n_routed_experts, router_experts, first_expert,
+                hidden_dim=moe_intermediate_size,
+                num_selected=num_experts_per_tok,
+                shared_experts=n_shared_experts,
+                scaling=routed_scaling_factor,
+            ),
         )

@@ -1,5 +1,5 @@
 """What the files of sequence-model tests share: a packing of documents
-into two rows, a tiny model of each family and a batch (ISSUE 28, 32)."""
+into two rows, a tiny model of each family and a batch."""
 
 import importlib.util
 import json
@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tensor2robot_tpu.models.sequence_lm_models import (
+    DeepseekV3LMModel,
     HybridSequenceLMModel,
     KimiLinearLMModel,
 )
@@ -98,17 +99,45 @@ def kimi_model(**overrides):
     return KimiLinearLMModel(**{**KIMI_TINY, **overrides})
 
 
+def _reference(name, tiny, extra=()):
+    """(the benchmark's plain reference of configuration `name`, its
+    configuration with the model keys it shares with `tiny` taken from it)."""
+    path = os.path.join(REPO, "benchmark", "reference", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"{name}_reference_for_tests", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    with open(os.path.join(REPO, "benchmark", "configs", f"{name}.json")) as f:
+        config = json.load(f)
+    keys = set(config["model"]) | set(extra)
+    config["model"] = {
+        **config["model"], **{k: v for k, v in tiny.items() if k in keys},
+    }
+    return module, config
+
+
 def kimi_reference():
     """(the benchmark's plain reference of the Kimi-Linear share, its
     configuration at `KIMI_TINY`'s sizes)."""
-    path = os.path.join(REPO, "benchmark", "reference", "kimi_linear_48b_a3b_s1.py")
-    spec = importlib.util.spec_from_file_location("kimi_reference_for_tests", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    with open(os.path.join(REPO, "benchmark", "configs", "kimi_linear_48b_a3b_s1.json")) as f:
-        config = json.load(f)
-    keys = set(config["model"]) | {"kda_chunk_size"}
-    config["model"] = {
-        **config["model"], **{k: v for k, v in KIMI_TINY.items() if k in keys},
-    }
-    return module, config
+    return _reference("kimi_linear_48b_a3b_s1", KIMI_TINY, {"kda_chunk_size"})
+
+
+#: Kanana-2-30B-A3B's pattern at tiny widths: five latent-attention layers,
+#: the first dense, 4 of 16 experts held in the others, 4 a token, 2 shared.
+DEEPSEEK_TINY = dict(
+    vocab_size=96, hidden_size=64, intermediate_size=128, num_hidden_layers=5,
+    num_attention_heads=4, first_k_dense_replace=1, n_routed_experts=4,
+    router_experts=16, first_expert=0, num_experts_per_tok=4, n_shared_experts=2,
+    moe_intermediate_size=32, routed_scaling_factor=2.448, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, qk_head_dim=24, v_head_dim=16,
+    rope_theta=1000000, sequence_length=SEQ, device_type="cpu",
+)
+
+
+def deepseek_model(**overrides):
+    return DeepseekV3LMModel(**{**DEEPSEEK_TINY, **overrides})
+
+
+def kanana_reference():
+    """(the benchmark's plain reference of the Kanana-2-30B-A3B share, its
+    configuration at `DEEPSEEK_TINY`'s sizes)."""
+    return _reference("kanana_2_30b_a3b_s1", DEEPSEEK_TINY)

@@ -45,6 +45,9 @@ def trained():
     model = MockT2RModel(device_type="cpu")
     compiled = train_eval.CompiledModel(model, donate_state=False)
     batch = compiled.shard_batch(batch_of(model, 8))
+    # An earlier test of the process may have built these very programs;
+    # with jax's in-memory caches emptied, the init and the first step build.
+    jax.clear_caches()
     mark = time.time_ns()
     state = compiled.init_state(jax.random.PRNGKey(0), batch)
     (init,) = spans_since(mark, "train.init_state")
