@@ -51,6 +51,7 @@ from tensor2robot_tpu.layers.transformer import (
 )
 from tensor2robot_tpu.models import optimizers
 from tensor2robot_tpu.models.abstract_model import MODE_TRAIN, FlaxT2RModel
+from tensor2robot_tpu.ops.flash_attention import ATTENTION_RESIDUALS
 from tensor2robot_tpu.specs import ExtendedTensorSpec, TensorSpecStruct
 
 
@@ -64,11 +65,16 @@ KEPT_RESIDUALS = (
     "mlp_up",  # SwiGLU's W_up x: the same
     # Mamba-2's [z, xBC, dt]: width 2 x inner + 2 x groups x state + heads
     "mamba2_in_proj",
+    # The attention kernel's output and log-sum-exp (ops/flash_attention):
+    # positions x heads x (head width x 2 + 4) bytes, 35 MB at 8,192
+    # positions and 32 heads of 64.
+    ATTENTION_RESIDUALS,
 )
 
 #: A block whose forward is recomputed in the backward pass, but for the
-#: three products above: norms, convolution, scans, gated norm and the
-#: attention mixer run a second time.
+#: four residuals above: norms, convolution, scans, gated norm and the
+#: attention mixer's projections run a second time; the attention kernel's
+#: forward does not.
 _RematBlock = nn.remat(
     HybridBlock,
     policy=jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS),
@@ -312,14 +318,18 @@ class HybridSequenceLMModel(_PackedLMModel):
 #: block is recomputed). "kda_out" is the delta rule's output
 #: (positions x 4,096 x 2 bytes a layer): with it kept the rule is not run
 #: a third time, since it recomputes itself from its inputs for its own
-#: backward (layers/kda.py). The others are wide products that are plain
-#: matrix work to recompute, as far as the chip has room beside 7.2 GB of
-#: state and a KDA layer's backward: the KDA layers' [q, k, v] product
-#: (0.4 GB a layer at 16,384 positions) and latent attention's expanded
-#: keys and values (a rank-512 product) do not fit, and carry no name.
+#: backward (layers/kda.py). `ATTENTION_RESIDUALS` are the attention
+#: kernel's output and log-sum-exp (positions x heads x (v_dim x 2 + 4)
+#: bytes, 136 MB a layer at 16,384 positions and 32 heads of 128): with them
+#: kept the backward does not run the forward kernel again. The others are
+#: wide products that are plain matrix work to recompute, as far as the
+#: chip has room beside 7.2 GB of state and a KDA layer's backward: the KDA
+#: layers' [q, k, v] product (0.4 GB a layer at 16,384 positions) and latent
+#: attention's expanded keys and values (a rank-512 product) do not fit, and
+#: carry no name.
 KIMI_KEPT_RESIDUALS = {
     "kda": ("kda_out",),
-    "mla": ("mla_q_proj",),
+    "mla": ("mla_q_proj", ATTENTION_RESIDUALS),
     "dense": ("mlp_gate", "mlp_up"),    # the leading layers' SwiGLU
     "moe": ("mlp_gate", "mlp_up"),      # the shared expert's
 }

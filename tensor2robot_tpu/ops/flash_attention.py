@@ -247,6 +247,14 @@ SEGMENT_KERNEL_BLOCKS = dict(
 SEGMENT_FUSED_BACKWARD_BYTES = 1 << 30
 SEGMENT_DQ_BLOCKS = dict(block_q_dq=1024, block_kv_dq=1024)
 
+#: The `checkpoint_name` of the kernel path's residuals: its output and its
+#: log-sum-exp, the two arrays its backward kernels read beside q, k and v.
+#: A `jax.checkpoint` policy that saves the name keeps them, and the
+#: backward pass then does not run the forward kernel again to rebuild them;
+#: under any other policy, or none, the name does nothing. The einsum path
+#: has no such residuals and carries no name.
+ATTENTION_RESIDUALS = "attention_out"
+
 
 def segment_attention(
     q: jax.Array,
@@ -347,7 +355,8 @@ def _segment_kernel(q, k, v, segment_ids, scale, interpret=False):
     positions and the two id vectors; tiles above the diagonal are skipped.
     The kernel takes no scale, so the queries carry it: exact for a power
     of two (granite's 1/64, 64 ** -0.5), else one more rounding of the
-    scaled query to its dtype, from float32."""
+    scaled query to its dtype, from float32. The output and the log-sum-exp
+    carry the name `ATTENTION_RESIDUALS`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
         splash_attention_mask as splash_mask,
@@ -368,6 +377,7 @@ def _segment_kernel(q, k, v, segment_ids, scale, interpret=False):
     kernel = splash.make_splash_mqa_single_device(
         splash_mask.MultiHeadMask([splash_mask.CausalMask((seq, seq))] * group),
         block_sizes=splash.BlockSizes(**backward, **SEGMENT_KERNEL_BLOCKS),
+        residual_checkpoint_name=ATTENTION_RESIDUALS,
         interpret=interpret,
     )
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)

@@ -286,7 +286,9 @@ def _slab(rows, num_selected, start, x, pair_weights, w_gate, w_up, w_down,
         ).astype(x.dtype)
         out = grouped(hidden, w_down)
     with jax.named_scope("moe/combine"):
-        out = jnp.where(valid, out * pair_weights[pair][:, None], 0.0)
+        # Zeroed before the weights meet them: the weights' gradient is each
+        # row times its cotangent, and 0 times an undefined row need not be 0.
+        out = jnp.where(valid, out, 0.0) * pair_weights[pair][:, None]
         return jnp.zeros(x.shape, jnp.float32).at[token].add(out)
 
 

@@ -1,10 +1,13 @@
 """What the files of sequence-model tests share: a packing of documents
 into two rows, a tiny model of each family and a batch."""
 
+import functools
 import importlib.util
 import json
 import os
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -13,6 +16,7 @@ from tensor2robot_tpu.models.sequence_lm_models import (
     HybridSequenceLMModel,
     KimiLinearLMModel,
 )
+from tensor2robot_tpu.ops import flash_attention
 from tensor2robot_tpu.specs import TensorSpecStruct
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -39,6 +43,23 @@ def spans(row):
 
 def one_segment(batch=2, seq=SEQ):
     return jnp.ones((batch, seq), jnp.int32)
+
+
+def kernel_path_names():
+    """The `checkpoint_name`s in the gradient of the attention kernel path
+    (`flash_attention._segment_kernel`), traced at bfloat16 and a length
+    every tile divides: the tiny models take the einsum path, which names
+    nothing."""
+    seq = max(flash_attention.SEGMENT_KERNEL_BLOCKS.values())
+    q = jax.ShapeDtypeStruct((1, seq, 2, 128), jnp.bfloat16)
+    ids = jnp.ones((1, seq), jnp.int32)
+    attend = functools.partial(
+        flash_attention._segment_kernel, segment_ids=ids, scale=0.125)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2),
+    ))(q, q, q))
+    return set(re.findall(r"name\[name=(\w+)\]", text))
 
 
 def model(**overrides):

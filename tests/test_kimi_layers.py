@@ -818,6 +818,38 @@ def test_routed_experts_are_the_masked_dense_share(row_buffer):
         )
 
 
+@pytest.mark.parametrize("row_buffer", [None, 16, 7])
+def test_rows_a_grouped_product_leaves_undefined_reach_no_gradient(
+    monkeypatch, row_buffer
+):
+    """On the chip a grouped product leaves the rows behind its last group
+    undefined, and they may hold NaN: here they do. Output and gradients
+    are those of a product that zeroes them, as the CPU's does."""
+    p, bias = _experts(seed=3), jnp.zeros((16,))
+
+    def both():
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(
+                lambda p: jnp.sum(jnp.sin(_share(p, bias, 4, 4, row_buffer=row_buffer)[0]))
+            )(p)
+
+    want = both()
+    ragged_dot = jax.lax.ragged_dot
+
+    def undefined_behind(lhs, rhs, group_sizes, **kwargs):
+        out = ragged_dot(lhs, rhs, group_sizes, **kwargs)
+        behind = jnp.arange(lhs.shape[0]) >= jnp.sum(group_sizes)
+        return jnp.where(behind[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", undefined_behind)
+    got = both()
+    assert float(got[0]) == float(want[0])
+    for name in p:
+        assert bool(jnp.all(jnp.isfinite(got[1][name]))), name
+        np.testing.assert_array_equal(
+            np.asarray(got[1][name]), np.asarray(want[1][name]), err_msg=name)
+
+
 @pytest.mark.parametrize("row_buffer", [None, 32, 5])
 def test_no_token_is_dropped_when_every_token_goes_to_held_experts(row_buffer):
     """A selection bias that sends all four choices of every token to the

@@ -4,6 +4,9 @@ packed documents against the same documents run alone, segment-masked
 attention, RMSNorm, SwiGLU and the chunked loss. CPU, tiny sizes, float32.
 The model and the trainer: test_sequence_lm_model.py."""
 
+import re
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -367,6 +370,94 @@ def test_segment_attention_takes_the_kernel_on_a_tpu_only_and_only_where_it_tile
         args = [x[:, :cut].astype(dtype) for x in (q, k, v)]
         jaxpr = str(jax.make_jaxpr(attend)(*args, segments[:, :cut]))
         assert "platform_index" not in jaxpr, (cut, dtype)
+
+
+# -- the kernel's residuals across a block's recomputation ----------------------------
+
+
+def _rematted_attention_block(kind, keep):
+    """(block, its arguments) of one attention layer at a size the kernel
+    path tiles (bfloat16, 1,024 positions), recomputed in the backward pass
+    as its model does ("latent": a Kimi block with the dense feed-forward,
+    "grouped": the hybrid model's attention block), or under the same
+    policy without the kernel's residual name."""
+    if kind == "latent":
+        names = (sequence_lm_models.KIMI_KEPT_RESIDUALS["mla"]
+                 + sequence_lm_models.KIMI_KEPT_RESIDUALS["dense"])
+        block = sequence_lm_models._remat_kimi_block("mla", "dense")
+        cls = sequence_lm_models.KimiLinearBlock
+        fields = dict(
+            mixer="mla", ffn="dense", heads=4, qk_nope_dim=32, qk_rope_dim=16,
+            v_dim=32, kv_rank=32, mlp_dim=64, experts={}, rope_theta=1e4,
+        )
+    else:
+        names = sequence_lm_models.KEPT_RESIDUALS
+        block = sequence_lm_models._RematBlock
+        cls = HybridBlock
+        fields = dict(
+            layer_type="attention", num_heads=4, num_kv_heads=2, head_dim=16,
+            attention_multiplier=0.25, mlp_dim=64, mamba_heads=2,
+            mamba_head_dim=16, mamba_state=8,
+        )
+    if not keep:
+        block = nn.remat(cls, policy=jax.checkpoint_policies.save_only_these_names(
+            *(n for n in names if n != flash_lib.ATTENTION_RESIDUALS)))
+    seq = max(flash_lib.SEGMENT_KERNEL_BLOCKS.values())
+    x = jnp.asarray(np.random.RandomState(2).randn(2, seq, 64), jnp.float32)
+    segments = jnp.asarray(_segments(((300, 500, 224), (1000,)), seq))
+    return block(dtype=jnp.bfloat16, **fields), x, segments
+
+
+@pytest.mark.parametrize("keep", [True, False], ids=["kept", "name_left_out"])
+@pytest.mark.parametrize("kind", ["latent", "grouped"])
+def test_a_rematted_attention_block_runs_the_forward_kernel_once(kind, keep):
+    """Lowered for a TPU: with the kernel's output and log-sum-exp kept, the
+    backward pass reads them and has no forward kernel of its own; under a
+    policy that leaves the name out it runs the forward again."""
+    assert flash_lib.ATTENTION_RESIDUALS in sequence_lm_models.KEPT_RESIDUALS
+    assert flash_lib.ATTENTION_RESIDUALS in sequence_lm_models.KIMI_KEPT_RESIDUALS["mla"]
+    block, x, segments = _rematted_attention_block(kind, keep)
+    params = block.init(jax.random.PRNGKey(0), x, segments)
+
+    def loss(params, x):
+        out = block.apply(params, x, segments)
+        out = out[0] if kind == "latent" else out
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
+        params, x).lower(lowering_platforms=("tpu",)).as_text()
+    kernels = re.findall(r'kernel_name = "(splash_\w+)"', text)
+    forwards = [name for name in kernels if name.startswith("splash_mqa_fwd")]
+    assert len(forwards) == (1 if keep else 2), kernels
+    # One backward kernel either way (dq, dk and dv fused at this size).
+    assert len(kernels) - len(forwards) == 1
+    assert text.count("tpu_custom_call") == len(kernels)
+
+
+def test_the_kept_residuals_give_the_gradients_of_the_recomputed_ones(kernel_tiles):
+    """The interpreted kernel under a policy that keeps its named residuals
+    and under one that rebuilds them: the same q, k and v gradients."""
+    q, k, v = _qkv(8, 2, jnp.bfloat16)
+    segments = _packing("several_documents")
+    weight = jnp.asarray(np.random.RandomState(4).randn(*q.shape), jnp.float32)
+
+    def grads(names):
+        attend = jax.checkpoint(
+            lambda q, k, v: flash_lib._segment_kernel(
+                q, k, v, segments, 0.25, interpret=True),
+            policy=jax.checkpoint_policies.save_only_these_names(*names),
+        )
+        return jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * weight),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    kept = grads((flash_lib.ATTENTION_RESIDUALS,))
+    rebuilt = grads(())
+    for name, a, b in zip(("dq", "dk", "dv"), kept, rebuilt):
+        assert a.dtype == jnp.bfloat16, name
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
 
 
 def test_attention_layer_gives_the_same_through_kernel_and_einsum(

@@ -23,6 +23,7 @@ from tests.sequence_lm_fixtures import (
     REPO as _REPO,
     SEQ,
     batch as _batch,
+    kernel_path_names,
     loss_fn as _loss_fn,
     model as _model,
     segments as _segments,
@@ -179,10 +180,13 @@ def test_the_policy_saves_three_products_a_block_and_nothing_else(block_variants
 
 
 def _checkpoint_names_in_source():
+    """The names of the layer files' `checkpoint_name` calls, and the one
+    the attention kernel's call gives its residuals."""
     from tensor2robot_tpu.layers import kda, mamba2, transformer
+    from tensor2robot_tpu.ops import flash_attention
 
     names = []
-    for module in (mamba2, transformer, kda):
+    for module in (mamba2, transformer, kda, flash_attention):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
@@ -192,6 +196,10 @@ def _checkpoint_names_in_source():
             if called == "checkpoint_name":
                 assert isinstance(node.args[1], ast.Constant), ast.dump(node)
                 names.append(node.args[1].value)
+            for keyword in node.keywords:
+                if keyword.arg == "residual_checkpoint_name":
+                    assert isinstance(keyword.value, ast.Name), ast.dump(node)
+                    names.append(getattr(module, keyword.value.id))
     return names
 
 
@@ -202,16 +210,20 @@ def test_every_kept_name_is_emitted_and_every_emitted_name_is_kept(block_variant
     )
 
     assert len(set(KEPT_RESIDUALS)) == len(KEPT_RESIDUALS)
-    # The layer files name what either model keeps, and nothing else.
+    # The layer files and the attention kernel's call name what either
+    # model keeps, and nothing else.
     kimi = {name for names in KIMI_KEPT_RESIDUALS.values() for name in names}
     assert sorted(_checkpoint_names_in_source()) == sorted(
         set(KEPT_RESIDUALS) | kimi)
-    # And the tiny model's step really passes through each of them.
+    # And the step really passes through each of them: the tiny model's
+    # through the layers' names, the kernel path through its residuals'.
     emitted = {
         eqn.params["name"] for eqn, _ in _equations(block_variants["plain"][2].jaxpr)
         if eqn.primitive.name == "name"
     }
-    assert emitted == set(KEPT_RESIDUALS)
+    kernel = kernel_path_names()
+    assert kernel == {"attention_out"}
+    assert emitted == set(KEPT_RESIDUALS) - kernel
 
 
 def _nameless(monkeypatch):
