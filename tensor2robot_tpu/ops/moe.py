@@ -13,7 +13,10 @@ dropped (contribute zero), the standard capacity-factor contract.
 A layer is told which experts it holds (`held`), scores and chooses over all
 of them, sorts the (token, choice) pairs routed to its own by expert and
 runs one grouped product a matrix over the sorted rows. That is what one
-chip of an expert-parallel deployment computes, without the exchange.
+chip of an expert-parallel deployment computes, without the exchange. Rows
+go out to the experts through the sorted order and come back to their
+tokens through its inverse, both as gathers, forward and backward: the
+layer holds no scatter.
 
 Pure functions here; `layers.moe.MoEBlock` and `layers.moe.RoutedExperts`
 are the flax wrappers.
@@ -177,13 +180,17 @@ def sigmoid_top_k(x, router_kernel, selection_bias, num_selected, scaling):
     """(ids [T, k], weights [T, k]) of a sigmoid router with a selection-only
     bias: `s = sigmoid(x W_r)` in float32, the k experts with the largest `s
     + bias`, weights `scaling * s / sum of the chosen s` (the bias chooses
-    and does not weigh)."""
+    and does not weigh). The chosen scores are read through a one-hot, whose
+    transpose is a product and not a scatter."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
     _, ids = jax.lax.top_k(scores + selection_bias.astype(jnp.float32), num_selected)
-    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    chosen = jnp.sum(
+        scores[:, None, :] * jax.nn.one_hot(ids, scores.shape[-1], dtype=scores.dtype),
+        axis=-1,
+    )
     return ids, scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
 
@@ -231,6 +238,12 @@ def routed_experts(
     Returns (y [T, F] float32, {"routed_rows": pairs routed to held experts,
     "max_expert_rows": the fullest held expert's, and with `expert_counts`
     "expert_counts": float32 [E_all]}).
+
+    Rows go out to the experts through the sorted order (`order[r]`: the
+    pair at row r) and come back to their tokens through its inverse
+    (`pos[t, j]`: the row of token t's j-th choice), both as gathers, forward
+    and backward: the layer holds no scatter. `pos` and the experts' sizes
+    are ranks and counts from a one-hot of the pairs' experts.
     """
     tokens, features = x.shape
     first, count = held
@@ -247,23 +260,36 @@ def routed_experts(
             x, router_kernel, selection_bias, num_selected, scaling
         )
     with jax.named_scope("moe/dispatch"):
-        local = ids.reshape(pairs) - first
+        local = ids - first
         # Held pairs first, by expert; the others behind them.
         key = jnp.where((local >= 0) & (local < count), local, count)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        order = jnp.argsort(key.reshape(pairs), stable=True).astype(jnp.int32)
         order = jnp.pad(order, (0, slabs * rows - pairs))
-    y = _sorted_experts(
-        rows, num_selected, x, weights.reshape(pairs), w_gate, w_up, w_down,
-        order, sizes,
-    )
+        pos, sizes = _sorted_positions(key, count + 1)
+        sizes = sizes[:count]
+    y = _sorted_experts(rows, x, weights, w_gate, w_up, w_down, order, pos, sizes)
     counts = {"routed_rows": jnp.sum(sizes), "max_expert_rows": jnp.max(sizes)}
     if expert_counts:
         with jax.named_scope("moe/bias_update"):
-            counts["expert_counts"] = jnp.zeros(
-                (router_kernel.shape[1],), jnp.float32
-            ).at[ids.reshape(pairs)].add(1.0)
+            counts["expert_counts"] = jnp.sum(
+                jax.nn.one_hot(ids, router_kernel.shape[1], dtype=jnp.float32),
+                axis=(0, 1),
+            )
     return y, counts
+
+
+def _sorted_positions(key, buckets):
+    """(pos [T, k] int32, sizes [buckets] int32) of `key` [T, k]: `pos[t, j]`
+    is where pair t * k + j lands in the stable sort of the flattened keys,
+    the inverse of that sort's order, counted without a scatter: the pairs of
+    a bucket before it are those of earlier tokens (a cumulative sum over
+    the tokens of a one-hot) and those of its own token's earlier choices."""
+    hot = (key[..., None] == jnp.arange(buckets)).astype(jnp.int32)   # [T, k, B]
+    per_token = jnp.sum(hot, axis=1)
+    sizes = jnp.sum(per_token, axis=0)
+    before = (jnp.cumsum(sizes) - sizes) + jnp.cumsum(per_token, axis=0) - per_token
+    within = jnp.cumsum(hot, axis=1) - hot
+    return jnp.sum(hot * (before[:, None, :] + within), axis=-1), sizes
 
 
 @jax.custom_vjp
@@ -294,10 +320,9 @@ def _balance_bwd(counts, g):
 balance_bias_by_counts.defvjp(_balance_fwd, _balance_bwd)
 
 
-def _slab(rows, num_selected, start, x, pair_weights, w_gate, w_up, w_down,
-          order, sizes):
-    """Rows [start, start + rows) of the sorted pairs through their experts,
-    weighted and added into [T, F] float32."""
+def _dispatch(rows, start, x, order, sizes, num_selected):
+    """Rows [start, start + rows) of the sorted pairs: (pair [rows], token
+    [rows], group sizes [E] of the slab, valid [rows, 1], taken [rows, F])."""
     ends = jnp.cumsum(sizes)
     with jax.named_scope("moe/dispatch"):
         pair = jax.lax.dynamic_slice(order, (start,), (rows,))
@@ -309,7 +334,12 @@ def _slab(rows, num_selected, start, x, pair_weights, w_gate, w_up, w_down,
         # forward and backward: they are zeroed on the way in (so that no
         # gradient comes back through them) and on the way out.
         valid = ((start + jnp.arange(rows)) < ends[-1])[:, None]
-        taken = jnp.where(valid, x[token], 0)
+        return pair, token, group, valid, jnp.where(valid, x[token], 0)
+
+
+def _experts(group, valid, taken, w_gate, w_up, w_down):
+    """The slab's rows through their experts' SwiGLU, float32 [rows, F], the
+    rows behind the last group zeroed."""
     with jax.named_scope("moe/experts"):
         def grouped(a, w):
             return jax.lax.ragged_dot(
@@ -321,30 +351,96 @@ def _slab(rows, num_selected, start, x, pair_weights, w_gate, w_up, w_down,
 
         hidden = (
             jax.nn.silu(grouped(taken, w_gate)) * grouped(taken, w_up)
-        ).astype(x.dtype)
+        ).astype(taken.dtype)
         out = grouped(hidden, w_down)
     with jax.named_scope("moe/combine"):
         # Zeroed before the weights meet them: the weights' gradient is each
         # row times its cotangent, and 0 times an undefined row need not be 0.
-        out = jnp.where(valid, out, 0.0) * pair_weights[pair][:, None]
-        return jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+        return jnp.where(valid, out, 0.0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _sorted_experts(rows, num_selected, x, pair_weights, w_gate, w_up, w_down,
-                    order, sizes):
+def _rows_of(pos, start, rows, routed):
+    """(row [T, k] within the slab, clipped into it; held [T, k]: the pair's
+    row is in the slab and in front of `routed`)."""
+    at = pos - start
+    return jnp.clip(at, 0, rows - 1), (at >= 0) & (at < rows) & (pos < routed)
+
+
+#: Rows a step of `_to_tokens`' loop gathers: one block of the sorted tokens.
+TOKEN_BLOCK = 1024
+
+
+def _to_tokens(values, row, held, weights):
+    """float32 [T, F]: `sum_j weights[t, j] * values[row[t, j]]` over the
+    choices `held`, by gathers whose rows follow the pairs held, not T x k.
+
+    The tokens are ordered by how many of their choices are held, most
+    first, so that those with an i-th held choice are the first n_i; the
+    i-th held choices go a block of `TOKEN_BLOCK` of those tokens at a time,
+    each gathered block added to its own block of the sorted tokens (no two
+    rows of a block meet), and one gather of [T, F] puts the tokens back in
+    their order."""
+    tokens, k = row.shape
+    block = min(TOKEN_BLOCK, tokens)
+    padded = -(-tokens // block) * block
+    count = jnp.sum(held, axis=1, dtype=jnp.int32)
+    # The i-th held choice of a token: its row and weight, [T, i].
+    nth = held[:, :, None] & (
+        (jnp.cumsum(held, axis=1, dtype=jnp.int32) - 1)[:, :, None] == jnp.arange(k))
+    level_row = jnp.sum(jnp.where(nth, row[:, :, None], 0), axis=1)
+    level_weight = jnp.sum(jnp.where(nth, weights[:, :, None], 0.0), axis=1)
+    place, sizes = _sorted_positions((k - count)[:, None], k + 1)
+    first = jnp.argsort(k - count, stable=True)
+    # Tokens with more than i held choices, and the blocks each level takes.
+    reach = jnp.cumsum(sizes)[k - 1 - jnp.arange(k)]
+    blocks = jnp.cumsum(-(-reach // block))
+    pad = lambda a: jnp.pad(a[first].T, ((0, 0), (0, padded - tokens)))
+    sources, scale = pad(level_row), pad(level_weight)
+
+    def body(carry):
+        step, acc = carry
+        level = jnp.sum(blocks <= step)
+        at = (step - jnp.where(level > 0, blocks[level - 1], 0)) * block
+        got = values[jax.lax.dynamic_slice(sources, (level, at), (1, block))[0]]
+        got = got.astype(jnp.float32) * jax.lax.dynamic_slice(
+            scale, (level, at), (1, block))[0][:, None]
+        got = jnp.where(((at + jnp.arange(block)) < reach[level])[:, None], got, 0.0)
+        return step + 1, jax.lax.dynamic_update_slice(
+            acc, jax.lax.dynamic_slice(acc, (at, 0), got.shape) + got, (at, 0))
+
+    _, acc = jax.lax.while_loop(
+        lambda carry: carry[0] < blocks[-1], body,
+        (jnp.int32(0), jnp.zeros((padded, values.shape[1]), jnp.float32)),
+    )
+    return acc[place[:, 0]]
+
+
+def _slab(rows, start, x, weights, w_gate, w_up, w_down, order, pos, sizes):
+    """Rows [start, start + rows) of the sorted pairs through their experts,
+    weighted and gathered back to their tokens, float32 [T, F]."""
+    _, _, group, valid, taken = _dispatch(
+        rows, start, x, order, sizes, pos.shape[1])
+    out = _experts(group, valid, taken, w_gate, w_up, w_down)
+    with jax.named_scope("moe/combine"):
+        return _to_tokens(out, *_rows_of(pos, start, rows, jnp.sum(sizes)), weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sorted_experts(rows, x, weights, w_gate, w_up, w_down, order, pos, sizes):
     """sum of `_slab` over as many slabs of `rows` sorted pairs as the
     routing filled: a loop whose trip count the data decides, forward and
     backward, so that a step computes the rows it routed and no more, and
-    holds one slab's intermediates whatever their number."""
+    holds one slab's intermediates whatever their number. The backward is
+    written out: each gather's transpose is a gather the other way (through
+    `pos` where the forward read through `order`, and back), so that no
+    scatter-add is left for autodiff to make; the products' own transposes
+    are jax's."""
     routed = jnp.sum(sizes)
 
     def body(carry):
         start, y = carry
         return start + rows, y + _slab(
-            rows, num_selected, start, x, pair_weights, w_gate, w_up, w_down,
-            order, sizes,
-        )
+            rows, start, x, weights, w_gate, w_up, w_down, order, pos, sizes)
 
     return jax.lax.while_loop(
         lambda carry: carry[0] < routed, body,
@@ -352,29 +448,38 @@ def _sorted_experts(rows, num_selected, x, pair_weights, w_gate, w_up, w_down,
     )[1]
 
 
-def _sorted_experts_fwd(rows, num_selected, *operands):
-    return _sorted_experts(rows, num_selected, *operands), operands
+def _sorted_experts_fwd(rows, *operands):
+    return _sorted_experts(rows, *operands), operands
 
 
-def _sorted_experts_bwd(rows, num_selected, operands, dy):
-    x, pair_weights, w_gate, w_up, w_down, order, sizes = operands
-    floats = (x, pair_weights, w_gate, w_up, w_down)
+def _sorted_experts_bwd(rows, operands, dy):
+    x, weights, w_gate, w_up, w_down, order, pos, sizes = operands
+    floats = (x, weights, w_gate, w_up, w_down)
     routed = jnp.sum(sizes)
+    pair_weights = weights.reshape(-1)
 
     def body(carry):
-        start, grads = carry
-        _, pull = jax.vjp(
-            lambda *f: _slab(rows, num_selected, start, *f, order, sizes), *floats
-        )
-        return start + rows, tuple(
-            g + d.astype(g.dtype) for g, d in zip(grads, pull(dy))
-        )
+        start, (dx, dw, *d_matrices) = carry
+        pair, token, group, valid, taken = _dispatch(
+            rows, start, x, order, sizes, pos.shape[1])
+        out, pull = jax.vjp(
+            functools.partial(_experts, group, valid), taken, w_gate, w_up, w_down)
+        with jax.named_scope("moe/combine"):
+            row, held = _rows_of(pos, start, rows, routed)
+            d_rows = dy[token]
+            dw = dw + jnp.where(held, jnp.sum(d_rows * out, axis=-1)[row], 0.0)
+            d_out = jnp.where(valid, d_rows * pair_weights[pair][:, None], 0.0)
+        d_taken, *d_slab = pull(d_out)
+        with jax.named_scope("moe/dispatch"):
+            dx = dx + _to_tokens(d_taken, row, held, jnp.ones(held.shape, jnp.float32))
+        return start + rows, (dx, dw) + tuple(
+            g + d.astype(g.dtype) for g, d in zip(d_matrices, d_slab))
 
     _, grads = jax.lax.while_loop(
         lambda carry: carry[0] < routed, body,
         (jnp.int32(0), tuple(jnp.zeros(f.shape, jnp.float32) for f in floats)),
     )
-    return tuple(g.astype(f.dtype) for g, f in zip(grads, floats)) + (None, None)
+    return tuple(g.astype(f.dtype) for g, f in zip(grads, floats)) + (None,) * 3
 
 
 _sorted_experts.defvjp(_sorted_experts_fwd, _sorted_experts_bwd)

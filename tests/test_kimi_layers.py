@@ -6,9 +6,10 @@ Pallas kernels (ISSUE 33, 34) and the kernels of the walk over the chunks
 interpret mode against the XLA forms, the choice between them, and the rule
 as one jitted function that every KDA layer of a step program shares; latent attention's widths through
 `segment_attention`, kernel and einsum; routed experts that drop no token
-whatever the imbalance and whose shares add up to the uncut layer. The model
-itself is in tests/test_kimi_linear.py. CPU, tiny sizes, float32 but for the
-kernels' operands."""
+whatever the imbalance, whose shares add up to the uncut layer and whose
+program holds no scatter. The model itself is in tests/test_kimi_linear.py.
+CPU, tiny sizes, float32 but for the kernels' operands and the experts'
+bfloat16 case."""
 
 import functools
 
@@ -800,9 +801,18 @@ def _share(p, bias, first, count, k=4, scaling=2.446, row_buffer=None):
     )
 
 
-@pytest.mark.parametrize("row_buffer", [None, 16, 7])
-def test_routed_experts_are_the_masked_dense_share(row_buffer):
+@pytest.mark.parametrize("row_buffer,dtype", [
+    *(pytest.param(b, jnp.float32, id=str(b)) for b in (None, 16, 7)),
+    *(pytest.param(b, jnp.bfloat16, id=f"bfloat16-{b}") for b in (None, 16, 7)),
+])
+def test_routed_experts_are_the_masked_dense_share(row_buffer, dtype):
+    """Float32 tokens to float32 rounding; bfloat16 tokens (the experts'
+    products then run in bfloat16, the combine and the tokens' gradient sum
+    in float32) to a few roundings of bfloat16 of the largest value, against
+    the dense share in float32 of the same bfloat16 tokens: the routing is
+    the same, since the router reads the tokens in float32."""
     p, bias = _experts(), jnp.zeros((16,))
+    p["x"] = p["x"].astype(dtype)
     with jax.default_matmul_precision("highest"):
         y, counts = _share(p, bias, 4, 4, row_buffer=row_buffer)
         want = _dense_share(p, bias, 4, 4, 4, 2.446)
@@ -810,12 +820,84 @@ def test_routed_experts_are_the_masked_dense_share(row_buffer):
             lambda p: jnp.sum(jnp.sin(_share(p, bias, 4, 4, row_buffer=row_buffer)[0])))(p)
         wanted = jax.grad(
             lambda p: jnp.sum(jnp.sin(_dense_share(p, bias, 4, 4, 4, 2.446))))(p)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-6)
+    assert y.dtype == jnp.float32 and got["x"].dtype == dtype
+    close = lambda a, b, atol, **kw: np.testing.assert_allclose(
+        np.asarray(a, np.float32), np.asarray(b, np.float32), atol=atol, **kw)
+    if dtype == jnp.float32:
+        close(y, want, 2e-6)
+        tolerance = dict.fromkeys(p, 2e-5)
+    else:
+        eps = float(jnp.finfo(jnp.bfloat16).eps)
+        close(y, want, 4 * eps * float(jnp.max(jnp.abs(want))))
+        tolerance = {name: 4 * eps * float(jnp.max(jnp.abs(wanted[name].astype(jnp.float32))))
+                     for name in p}
     assert 0 < int(counts["routed_rows"]) < 64 * 4
     for name in p:
-        np.testing.assert_allclose(
-            np.asarray(got[name]), np.asarray(wanted[name]), atol=2e-5, err_msg=name
+        close(got[name], wanted[name], tolerance[name], err_msg=name)
+
+
+@pytest.mark.parametrize("row_buffer", [None, 16, 7])
+def test_the_routed_layer_holds_no_scatter(row_buffer):
+    """The layer's forward and backward, the bias rule's counts among them,
+    lowered: rows go to the experts through the sorted order and come back
+    through its inverse as gathers, and no op of the program is a scatter.
+    The scatter-add form before held seven here: the experts' sizes, the
+    combine and its recomputation in the backward, the pair weights'
+    gradient, the tokens' gradient (summed in the tokens' dtype), the
+    router's chosen scores' gradient, and the counts."""
+    p, bias = _experts(), jnp.zeros((16,))
+
+    def loss(p):
+        held = slice(4, 8)
+        y, counts = moe_ops.routed_experts(
+            p["x"], p["router"], bias, p["gate"][held], p["up"][held], p["down"][held],
+            held=(4, 4), num_selected=4, scaling=2.446, row_buffer=row_buffer,
+            expert_counts=True,
         )
+        return jnp.sum(jnp.sin(y)), counts
+
+    text = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(p).as_text()
+    assert "stablehlo.sort" in text and "stablehlo.gather" in text
+    assert "scatter" not in text
+
+
+@pytest.mark.parametrize("block", [1, 5, 16])
+@pytest.mark.parametrize("first,selection", [(4, 0.0), (0, 10.0)])
+def test_tokens_come_back_a_block_of_sorted_tokens_at_a_time(monkeypatch, block, first, selection):
+    """Blocks that split a level, that leave a ragged last block of the 64
+    tokens, and of one token; with the routing as the router has it and with
+    every choice of every token held (a level for each of the four)."""
+    monkeypatch.setattr(moe_ops, "TOKEN_BLOCK", block)
+    p = _experts(seed=1)
+    bias = jnp.where(jnp.arange(16) < 4, selection, 0.0)
+    with jax.default_matmul_precision("highest"):
+        y, _ = _share(p, bias, first, 4, row_buffer=16)
+        want = _dense_share(p, bias, first, 4, 4, 2.446)
+        got = jax.grad(
+            lambda p: jnp.sum(jnp.sin(_share(p, bias, first, 4, row_buffer=16)[0])))(p)
+        wanted = jax.grad(
+            lambda p: jnp.sum(jnp.sin(_dense_share(p, bias, first, 4, 4, 2.446))))(p)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-6)
+    for name in p:
+        np.testing.assert_allclose(
+            np.asarray(got[name]), np.asarray(wanted[name]), atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("seed,tokens,k,buckets", [
+    (0, 64, 4, 5), (1, 37, 8, 17), (2, 5, 6, 3), (3, 128, 2, 2),
+])
+def test_positions_are_the_inverse_of_the_stable_sort(seed, tokens, k, buckets):
+    """A token's held buckets differ (the router's choices do) and the last
+    bucket, the pairs held elsewhere, repeats within a token."""
+    rng = np.random.RandomState(seed)
+    ids = np.stack([rng.permutation(max(k, buckets + 3))[:k] for _ in range(tokens)])
+    key = jnp.asarray(np.minimum(ids, buckets - 1), jnp.int32)
+    pos, sizes = moe_ops._sorted_positions(key, buckets)
+    order = np.argsort(np.asarray(key).reshape(-1), kind="stable")
+    assert pos.dtype == jnp.int32 and pos.shape == (tokens, k)
+    np.testing.assert_array_equal(order[np.asarray(pos).reshape(-1)], np.arange(tokens * k))
+    np.testing.assert_array_equal(
+        np.asarray(sizes), np.bincount(np.asarray(key).reshape(-1), minlength=buckets))
 
 
 @pytest.mark.parametrize("row_buffer", [None, 16, 7])
